@@ -84,15 +84,15 @@ def config2(quick):
 
 
 def config3(quick):
-    """Headline shape, scaled down — `python bench.py` is the real run."""
-    import subprocess
+    """Headline shape, scaled down — `python bench.py` is the real run.
+    In this process: a child would need the chip this process holds."""
+    import bench
+    import jax
 
-    cmd = [sys.executable, str(pathlib.Path(__file__).parent.parent / "bench.py"),
-           "--quick"]
-    if "--cpu" in sys.argv:
-        cmd.append("--cpu")
-    r = subprocess.run(cmd, capture_output=True, text=True, timeout=1200)
-    line = json.loads(r.stdout.strip().splitlines()[-1])
+    argv = ["--quick"] + (["--cpu"] if "--cpu" in sys.argv else [])
+    line = bench.run_headline(
+        bench.build_parser().parse_args(argv), jax.devices()[0]
+    )
     out("3", "headline (bench.py --quick)", line["value"],
         {"note": "full run: python bench.py"})
 

@@ -418,6 +418,14 @@ def test_snapshot_io_fault_shape(tmp_path):
 
 
 def test_health_route_reports_state_machine():
+    """GET /health: the failure-domain state, then the device."""
+    import jax
+
+    from throttlecrab_tpu.runtime import (
+        device_info,
+        health_suffix,
+        parse_health,
+    )
     from throttlecrab_tpu.server.http import HttpTransport
 
     arm("launch:persistent")
@@ -437,8 +445,11 @@ def test_health_route_reports_state_machine():
         return ok_body, degraded_body
 
     ok_body, degraded_body = run(main())
-    assert ok_body == (200, b"OK", "text/plain")
-    assert degraded_body == (200, b"degraded", "text/plain")
+    device = (" " + health_suffix()).encode()
+    assert ok_body == (200, b"OK" + device, "text/plain")
+    assert degraded_body == (200, b"degraded" + device, "text/plain")
+    assert parse_health(ok_body[1].decode()) == device_info()
+    assert device_info()["platform"] == jax.devices()[0].platform
 
 
 def test_supervisor_state_helper_walks_wrappers():

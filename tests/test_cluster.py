@@ -249,7 +249,7 @@ def wait_healthy(proc, port, deadline_s=120):
             with urllib.request.urlopen(
                 f"http://127.0.0.1:{port}/health", timeout=1
             ) as r:
-                assert r.read() == b"OK"
+                assert r.read().startswith(b"OK device=")
                 return
         except Exception:
             time.sleep(0.5)

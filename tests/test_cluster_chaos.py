@@ -532,6 +532,9 @@ def test_wire_window_fast_path_feeds_replication():
     try:
         a.join_cluster()
         b.join_cluster()
+        # The fast path refuses a window while a join's handoff is
+        # still pending.
+        settle_handoffs(a, b)
         ring = a.cl.ring
         keys = [
             b"ww:%d" % i for i in range(6000)
@@ -577,6 +580,7 @@ def test_takeover_traffic_replicates_to_live_successor():
     try:
         for n in (a, b, c):
             n.join_cluster()
+        settle_handoffs(a, b, c)
         ring = a.cl.ring
         # A key owned by C whose failover target (exclude C) is A.
         hot = next(

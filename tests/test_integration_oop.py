@@ -19,6 +19,8 @@ import urllib.request
 
 import pytest
 
+from throttlecrab_tpu.runtime import parse_health
+
 HTTP_PORT = 28080
 GRPC_PORT = 28070
 REDIS_PORT = 28060
@@ -55,7 +57,10 @@ def wait_health(proc, http_port, deadline_s=120):
             with urllib.request.urlopen(
                 f"http://127.0.0.1:{http_port}/health", timeout=1
             ) as r:
-                assert r.read() == b"OK"
+                body = r.read().decode()
+            assert body.startswith("OK ")
+            # The server names the device it computes on.
+            assert parse_health(body)["platform"] == "cpu"
             return
         except Exception as e:  # noqa: BLE001 - retry until deadline
             last_err = e

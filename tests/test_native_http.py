@@ -11,6 +11,7 @@ from throttlecrab_tpu.native import (
     wire_available,
     wire_build_error,
 )
+from throttlecrab_tpu.runtime import health_suffix
 from throttlecrab_tpu.server.metrics import Metrics
 from throttlecrab_tpu.tpu.limiter import TpuRateLimiter
 
@@ -102,7 +103,7 @@ def test_native_http_health_and_metrics():
         await transport.start()
         port = transport.bound_port
         status, raw = await http_request(port, "GET", "/health")
-        assert (status, raw) == (200, b"OK")
+        assert (status, raw) == (200, b"OK " + health_suffix().encode())
         # Generate some traffic, then wait for the 1s metrics refresh.
         body = {"key": "m", "max_burst": 1, "count_per_period": 1,
                 "period": 60}
@@ -117,6 +118,24 @@ def test_native_http_health_and_metrics():
         await transport.stop()
 
     asyncio.run(main())
+
+
+def test_native_http_drain_health_keeps_device():
+    """Draining flips the /health state at once and still reports the
+    device, so a balancer reading the body never loses it."""
+    async def main():
+        transport, _ = make_transport()
+        await transport.start()
+        await transport.drain()
+        status, raw = await http_request(
+            transport.bound_port, "GET", "/health"
+        )
+        await transport.stop()
+        return status, raw
+
+    assert asyncio.run(main()) == (
+        200, b"draining " + health_suffix().encode()
+    )
 
 
 def test_native_http_error_shapes():

@@ -385,6 +385,7 @@ def test_stop_wakes_parked_driver_promptly():
 def test_native_http_health_reflects_supervisor_state():
     """The native HTTP wire layer serves /health from the pushed
     failure-domain state, not a hardcoded OK."""
+    from throttlecrab_tpu.runtime import health_suffix
     from throttlecrab_tpu.server.native_http import NativeHttpTransport
     from throttlecrab_tpu.server.supervisor import SupervisedLimiter
 
@@ -427,5 +428,6 @@ def test_native_http_health_reflects_supervisor_state():
             await transport.stop()
 
     ok_body, degraded_body = asyncio.run(main())
-    assert ok_body == b"OK"
-    assert degraded_body == b"degraded"
+    device = " " + health_suffix()
+    assert ok_body == b"OK" + device.encode()
+    assert degraded_body == b"degraded" + device.encode()

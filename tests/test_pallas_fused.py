@@ -276,25 +276,15 @@ def test_scratch_tail_takes_suppressed_writes():
 
 def test_insight_coexists_no_downgrade_warning(monkeypatch, caplog):
     """THROTTLECRAB_PALLAS_FUSED=1 + insight: the width-polymorphic
-    kernel carries the 6-wide rows natively, so enable_insight must NOT
-    emit the legacy downgrade warning — while a legacy-only
-    THROTTLECRAB_PALLAS=1 configuration still warns."""
+    kernel carries the 6-wide rows natively, so enable_insight warns
+    about nothing."""
     from throttlecrab_tpu.tpu.table import BucketTable
 
-    monkeypatch.setenv("THROTTLECRAB_PALLAS", "1")
     monkeypatch.setenv("THROTTLECRAB_PALLAS_FUSED", "1")
     with caplog.at_level(logging.WARNING, logger="throttlecrab.table"):
-        BucketTable(64, insight=True)
-    assert not [
-        r for r in caplog.records if "disable" in r.getMessage()
-    ], "fused path must not warn about an insight downgrade"
-    caplog.clear()
-    monkeypatch.delenv("THROTTLECRAB_PALLAS_FUSED")
-    with caplog.at_level(logging.WARNING, logger="throttlecrab.table"):
-        BucketTable(64, insight=True)
-    assert [
-        r for r in caplog.records if "legacy Pallas DMA" in r.getMessage()
-    ], "legacy-only configuration must keep warning"
+        table = BucketTable(64, insight=True)
+    assert not caplog.records
+    assert table._packed_launch()[0] is pf.gcra_scan_packed_fused_ins
 
 
 def test_env_parse_matches_config_bool(monkeypatch):

@@ -702,7 +702,8 @@ def test_run_server_checkpoint_lifecycle_off_the_loop(tmp_path):
         assert body["remaining"] == expect_remaining
         # /health carries the checkpoint age only when armed.
         health = await _get("/health")
-        assert health.startswith(b"OK checkpoint_age_s=")
+        assert health.startswith(b"OK device=")
+        assert b" checkpoint_age_s=" in health
         os.kill(os.getpid(), signal.SIGINT)
         await asyncio.wait_for(task, timeout=60)
 
@@ -804,7 +805,8 @@ def _wait_ck_health(proc, port, deadline_s=120):
             ) as r:
                 body = r.read()
             # Durability armed: the age suffix rides the OK body.
-            assert body.startswith(b"OK checkpoint_age_s="), body
+            assert body.startswith(b"OK device="), body
+            assert b" checkpoint_age_s=" in body, body
             return
         except (OSError, AssertionError):
             time.sleep(0.25)

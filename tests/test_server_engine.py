@@ -11,6 +11,7 @@ import asyncio
 
 import pytest
 
+from throttlecrab_tpu.runtime import health_suffix
 from throttlecrab_tpu.server.engine import BatchingEngine, ThrottleError
 from throttlecrab_tpu.server.metrics import Metrics
 from throttlecrab_tpu.server.types import ThrottleRequest
@@ -188,8 +189,7 @@ def test_cleanup_policy_sweeps_between_batches():
 def test_shutdown_resolves_inflight_futures_when_final_flush_raises():
     """Drain-correct shutdown: even when the final flush's launch
     raises, every in-flight future must resolve (ThrottleError), never
-    hang — a stuck shutdown is the wedge this repo's round-5 verdict
-    documents."""
+    hang."""
 
     async def main():
         engine, _ = make_engine(batch_size=4096, max_linger_us=10_000_000)
@@ -253,7 +253,9 @@ def test_post_shutdown_requests_have_defined_status_per_transport():
     status, payload, health, resp = run(main())
     assert status == 500
     assert "shut down" in json.loads(payload)["error"]
-    assert health == (200, b"shutdown", "text/plain")
+    assert health == (
+        200, b"shutdown " + health_suffix().encode(), "text/plain"
+    )
     assert isinstance(resp, Error)
     assert resp.value.startswith("ERR") and "shut down" in resp.value
 

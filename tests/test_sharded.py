@@ -149,3 +149,20 @@ def test_table_grow_preserves_state(mesh):
     # State must survive the reallocation: the key is still exhausted.
     allowed, _ = lim.rate_limit("grow-key", 3, 10, 3600, 1, T0 + 1)
     assert not allowed
+
+
+def test_shard_inputs_land_on_their_own_devices(limiter):
+    """Each shard's [1, ...] slice of a stacked input goes straight to
+    that shard's device, and the table spans every mesh device."""
+    table = limiter.table
+    D = table.n_shards
+    (x,) = table._put_shards((np.arange(D * 16).reshape(D, 16), np.int32))
+    mesh_devices = set(table.mesh.devices.flat)
+    assert x.sharding.device_set == mesh_devices
+    for shard in x.addressable_shards:
+        assert shard.data.shape == (1, 16)
+        row = shard.index[0].start
+        np.testing.assert_array_equal(
+            np.asarray(shard.data)[0], np.arange(16) + 16 * row
+        )
+    assert table.state.sharding.device_set == mesh_devices

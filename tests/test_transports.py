@@ -10,6 +10,7 @@ included (`tests/integration/multi_transport.rs:159-225`).
 import asyncio
 import json
 
+from throttlecrab_tpu.runtime import health_suffix
 from throttlecrab_tpu.server.engine import BatchingEngine
 from throttlecrab_tpu.server.http import HttpTransport
 from throttlecrab_tpu.server.metrics import Metrics
@@ -80,7 +81,7 @@ def test_http_throttle_health_metrics():
             allowed.append(json.loads(raw)["allowed"])
 
         status, raw = await http_request(port, "GET", "/health")
-        assert (status, raw) == (200, b"OK")
+        assert (status, raw) == (200, b"OK " + health_suffix().encode())
 
         status, raw = await http_request(port, "GET", "/metrics")
         assert status == 200

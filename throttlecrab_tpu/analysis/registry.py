@@ -4,7 +4,7 @@ Two user-facing name surfaces accrete silently:
 
   * **Knobs** — every ``THROTTLECRAB_*`` environment variable the
     package reads (the ``server/config.py`` ``_SPEC`` table plus ad-hoc
-    ``os.environ`` reads like ``THROTTLECRAB_PALLAS``) must be
+    ``os.environ`` reads like ``THROTTLECRAB_PALLAS_FUSED``) must be
     documented in README.md or ARCHITECTURE.md.  An undocumented knob
     is operationally invisible — deployments can't set what they can't
     find (``knob-undocumented``).

@@ -1,9 +1,8 @@
 """Deterministic fault injection for the five real failure surfaces.
 
-VERDICT.md round 5 documents the project's dominant operational failure:
-the device going away mid-claim (`UNAVAILABLE`), with no way to test the
-serving stack's reaction because nothing could *produce* that failure on
-demand.  This module is that missing tool: a registry of injection
+The serving stack's dominant operational failure is the device going
+away (`UNAVAILABLE`), and testing its reaction needs a way to *produce*
+that failure on demand.  This module is that missing tool: a registry of injection
 points threaded through the real failure surfaces —
 
   * ``launch``   — a device kernel launch (dispatch) fails,

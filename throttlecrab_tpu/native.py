@@ -27,6 +27,32 @@ _lib: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
 
 
+def _host_key() -> bytes:
+    """What makes a build host-specific: the CPU (model and feature
+    flags, which -march=native compiles for), the machine and the
+    compiler.  A working tree copied to another host rebuilds instead
+    of loading a library compiled for this one."""
+    import platform
+
+    cpu = b""
+    try:
+        with open("/proc/cpuinfo", "rb") as f:
+            for line in f:
+                if line.startswith((b"model name", b"flags", b"Features")):
+                    cpu += line
+                elif not line.strip() and cpu:
+                    break  # the first processor's block is enough
+    except OSError:
+        cpu = platform.processor().encode()
+    try:
+        cxx = subprocess.run(
+            ["g++", "--version"], capture_output=True, timeout=30
+        ).stdout
+    except (OSError, subprocess.SubprocessError):
+        cxx = b""
+    return cpu + platform.machine().encode() + cxx
+
+
 def _compile(src: Path, out: Path, extra=()) -> Optional[str]:
     """Build a shared library if stale; returns an error string or None.
 
@@ -42,7 +68,7 @@ def _compile(src: Path, out: Path, extra=()) -> Optional[str]:
         "THROTTLECRAB_NATIVE_CFLAGS", "-O3 -march=native"
     ).split()
     digest = hashlib.sha256(
-        src.read_bytes() + " ".join(flags).encode()
+        src.read_bytes() + " ".join(flags).encode() + _host_key()
     ).hexdigest()
     stamp = out.with_suffix(out.suffix + ".sha256")
     if (
@@ -50,9 +76,12 @@ def _compile(src: Path, out: Path, extra=()) -> Optional[str]:
         or not stamp.exists()
         or stamp.read_text().strip() != digest
     ):
+        # Build beside the target and rename over it: processes that
+        # build at once (test workers) never load a half-written file.
+        tmp = out.with_name(f".{out.name}.{os.getpid()}")
         cmd = [
             "g++", *flags, "-std=c++17", "-shared",
-            "-fPIC", str(src), "-o", str(out), *extra,
+            "-fPIC", str(src), "-o", str(tmp), *extra,
         ]
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
@@ -63,7 +92,10 @@ def _compile(src: Path, out: Path, extra=()) -> Optional[str]:
             return f"{src.name} failed to compile:\n{stderr[-2000:]}"
         except subprocess.SubprocessError as e:
             return f"{src.name} build error: {e}"
-        stamp.write_text(digest)
+        os.replace(tmp, out)
+        stamp_tmp = stamp.with_name(f".{stamp.name}.{os.getpid()}")
+        stamp_tmp.write_text(digest)
+        os.replace(stamp_tmp, stamp)
     return None
 
 

@@ -46,10 +46,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.4.35 exposes shard_map at the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from ..core.errors import InternalError
 from ..tpu.kernel import (
@@ -153,6 +150,8 @@ class ShardedBucketTable(HwmMarksMixin):
         self.tenant_slots = int(tenant_slots)
         self.width = INS_WIDTH if self.insight else 4
         self.sharding = NamedSharding(mesh, P(AXIS, None, None))
+        self.row_sharding = NamedSharding(mesh, P(AXIS))
+        self.replicated = NamedSharding(mesh, P())
         rows = capacity_per_shard + self.SCRATCH
         self.state = jax.device_put(
             self._host_empty(self.n_shards, rows, self.width), self.sharding
@@ -290,11 +289,19 @@ class ShardedBucketTable(HwmMarksMixin):
             # shard_map has no replication rule for pallas_call; the
             # fused body's outputs follow the same specs as the XLA
             # body's, so skipping the check is sound.
-            **({"check_rep": False} if fused else {}),
+            **({"check_vma": False} if fused else {}),
         )
         fn = jax.jit(mapped, donate_argnums=(0,))
         self._step_cache[key] = fn
         return fn
+
+    def _put_shards(self, *arrays):
+        """Stacked [D, ...] host inputs, each shard's slice sent straight
+        to its own device (not staged on the default device first)."""
+        return [
+            jax.device_put(np.asarray(x, dtype), self.row_sharding)
+            for x, dtype in arrays
+        ]
 
     def check_batch(
         self,
@@ -325,19 +332,17 @@ class ShardedBucketTable(HwmMarksMixin):
         step = self._step(with_degen, compact)
         args = [
             self.state,
-            jnp.asarray(slots, jnp.int32),
-            jnp.asarray(rank, jnp.int32),
-            jnp.asarray(is_last, bool),
-            jnp.asarray(emission, jnp.int64),
-            jnp.asarray(tolerance, jnp.int64),
-            jnp.asarray(quantity, jnp.int64),
-            jnp.asarray(valid, bool),
-            jnp.asarray(now_ns, jnp.int64),
+            *self._put_shards(
+                (slots, np.int32), (rank, np.int32), (is_last, bool),
+                (emission, np.int64), (tolerance, np.int64),
+                (quantity, np.int64), (valid, bool),
+            ),
+            jax.device_put(np.asarray(now_ns, np.int64), self.replicated),
         ]
         if self.tenant_slots:
             if tenant is None:
                 tenant = np.zeros(slots.shape, np.int32)
-            args.append(jnp.asarray(tenant, jnp.int32))
+            args.extend(self._put_shards((tenant, np.int32)))
             self.state, out, counters, tcounts = step(*args)
         else:
             self.state, out, counters = step(*args)
@@ -460,11 +465,35 @@ class ShardedBucketTable(HwmMarksMixin):
             out_specs=tuple(out_specs),
             # No shard_map replication rule exists for pallas_call; the
             # fused body's outputs follow the XLA body's specs exactly.
-            **({"check_rep": False} if fused else {}),
+            **({"check_vma": False} if fused else {}),
         )
         fn = jax.jit(mapped, donate_argnums=(0,))
         self._step_cache[key] = fn
         return fn
+
+    def compile_launch(self, depth: int, batch: int, *, with_degen, compact):
+        """Compile, without running, the scan step check_many launches
+        for `depth` sub-batches of `batch` lanes per shard (the boot
+        gate of pallas_fused.require_compiles)."""
+        lanes = (self.n_shards, depth, batch)
+
+        def lane(dtype):
+            return jax.ShapeDtypeStruct(lanes, dtype,
+                                        sharding=self.row_sharding)
+
+        args = [
+            jax.ShapeDtypeStruct(self.state.shape, self.state.dtype,
+                                 sharding=self.sharding),
+            lane(jnp.int32), lane(jnp.int32), lane(jnp.bool_),
+            lane(jnp.int64), lane(jnp.int64), lane(jnp.int64),
+            lane(jnp.bool_),
+            jax.ShapeDtypeStruct((depth,), jnp.int64,
+                                 sharding=self.replicated),
+        ]
+        if self.tenant_slots:
+            args.append(lane(jnp.int32))
+        step = self._scan_step(with_degen, compact)
+        return step.lower(*args).compile()
 
     def check_many(
         self,
@@ -496,19 +525,17 @@ class ShardedBucketTable(HwmMarksMixin):
         step = self._scan_step(with_degen, compact)
         args = [
             self.state,
-            jnp.asarray(slots, jnp.int32),
-            jnp.asarray(rank, jnp.int32),
-            jnp.asarray(is_last, bool),
-            jnp.asarray(emission, jnp.int64),
-            jnp.asarray(tolerance, jnp.int64),
-            jnp.asarray(quantity, jnp.int64),
-            jnp.asarray(valid, bool),
-            jnp.asarray(now_ns, jnp.int64),
+            *self._put_shards(
+                (slots, np.int32), (rank, np.int32), (is_last, bool),
+                (emission, np.int64), (tolerance, np.int64),
+                (quantity, np.int64), (valid, bool),
+            ),
+            jax.device_put(np.asarray(now_ns, np.int64), self.replicated),
         ]
         if self.tenant_slots:
             if tenant is None:
                 tenant = np.zeros(slots.shape, np.int32)
-            args.append(jnp.asarray(tenant, jnp.int32))
+            args.extend(self._put_shards((tenant, np.int32)))
             self.state, out, counters, tcounts = step(*args)
         else:
             self.state, out, counters = step(*args)

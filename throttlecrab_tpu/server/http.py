@@ -27,6 +27,7 @@ from .engine import (
     OverloadError,
     ThrottleError,
 )
+from ..runtime import health_suffix
 from .metrics import Metrics
 from .transport_base import ConnTrackingMixin
 from .types import ThrottleRequest
@@ -153,6 +154,8 @@ class HttpTransport(ConnTrackingMixin):
             # the traffic degraded mode exists to keep answering.
             state = self.engine.health_state()
             body = b"OK" if state == "ok" else state.encode()
+            # The device the limiter computes on rides every /health.
+            body += b" " + health_suffix().encode()
             ck = getattr(self.engine, "checkpointer", None)
             if ck is not None:
                 # Last-checkpoint age rides /health only when the

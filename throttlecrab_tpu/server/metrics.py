@@ -38,6 +38,10 @@ METRIC_NAMES = (
     "throttlecrab_requests_errors",
     "throttlecrab_top_denied_keys",
     "throttlecrab_tpu_device_launches",
+    # JAX compiles (throttlecrab_tpu/runtime.py): seconds spent compiling
+    # or fetching from the persistent cache, and the cache hits.
+    "throttlecrab_tpu_compile_seconds",
+    "throttlecrab_tpu_compile_cache_hits",
     "throttlecrab_tpu_batched_requests",
     "throttlecrab_tpu_max_batch_size",
     "throttlecrab_tpu_sweeps",
@@ -430,6 +434,22 @@ class Metrics:
             "Number of device kernel launches",
             "counter",
             self.device_launches,
+        )
+        from ..runtime import compile_stats
+
+        compiles = compile_stats()
+        metric(
+            "throttlecrab_tpu_compile_seconds",
+            "Seconds spent compiling programs or fetching them from the "
+            "persistent compile cache",
+            "counter",
+            compiles["seconds"],
+        )
+        metric(
+            "throttlecrab_tpu_compile_cache_hits",
+            "Programs fetched from the persistent compile cache",
+            "counter",
+            compiles["cache_hits"],
         )
         metric(
             "throttlecrab_tpu_batched_requests",

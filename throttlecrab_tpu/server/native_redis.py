@@ -29,6 +29,7 @@ import numpy as np
 
 from ..front import STATUS_OVERLOADED
 from ..native import get_wire_lib
+from ..runtime import health_suffix
 from ..tpu.limiter import (
     STATUS_DEADLINE,
     STATUS_INTERNAL,
@@ -137,6 +138,9 @@ class NativeRedisTransport:
     # ------------------------------------------------------------------ #
 
     async def start(self) -> None:
+        # Seed /health and /metrics before the listener is reachable:
+        # the wire layer's built-in body is a bare "OK" with no device.
+        self._push_metrics()
         rc = self._lib.ws_start(
             self._h, self.host.encode(), self.port, self.PROTOCOL
         )
@@ -171,9 +175,7 @@ class NativeRedisTransport:
         wire layer has no accept gate, so the health flip is the
         routing signal; stop() drops connections afterwards."""
         self._draining = True
-        if self.PROTOCOL == 1:
-            body = b"draining"
-            self._lib.ws_set_health(self._h, body, len(body))
+        self._push_metrics()
 
     async def stop(self) -> None:
         import asyncio
@@ -265,7 +267,6 @@ class NativeRedisTransport:
         asyncio engine's backlog path."""
         B = self.batch_size
         can_scan = hasattr(self.limiter, "rate_limit_many")
-        self._push_metrics()
         last_metrics = time.monotonic()
         while self._running:
             try:
@@ -772,6 +773,7 @@ class NativeRedisTransport:
         else:
             state = supervisor_state(self.limiter)
         body = b"OK" if state == "ok" else state.encode()
+        body += b" " + health_suffix().encode()
         if self.checkpointer is not None:
             # Last-checkpoint age rides /health only when durability is
             # armed (the bare "OK" body is a wire contract otherwise) —

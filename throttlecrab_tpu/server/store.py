@@ -30,6 +30,25 @@ def create_limiter(config):
         os.environ["THROTTLECRAB_PALLAS_FUSED"] = (
             "1" if config.pallas_fused else "0"
         )
+    limiter = _build_limiter(config)
+    if getattr(config, "pallas_fused", False):
+        # The knob must compile the served launches at boot: it never
+        # falls back to XLA or to interpret mode.
+        from ..tpu import pallas_fused
+
+        pallas_fused.require_compiles(
+            limiter.table,
+            _pow2(config.max_scan_depth),
+            _pow2(max(config.batch_size, limiter.MIN_PAD)),
+        )
+    return limiter
+
+
+def _pow2(n: int) -> int:
+    return 1 << (max(n, 1) - 1).bit_length()
+
+
+def _build_limiter(config):
     if config.shards > 1:
         from ..parallel.sharded import ShardedTpuRateLimiter, make_mesh
         from ..parallel.tenants import TenantRegistry

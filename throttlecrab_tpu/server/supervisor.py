@@ -1,9 +1,9 @@
 """Failure-domain supervision: retry, degrade, re-promote.
 
 The serving stack's single point of hardware failure is the device: a
-TPU claim dying surfaces as ``UNAVAILABLE``-shaped launch/fetch errors
-(VERDICT.md round 5), and before this module the engine's only answer
-was to fail the whole batch (`engine.py` launch except-branch).  The
+lost or failing device surfaces as ``UNAVAILABLE``-shaped launch/fetch
+errors, and before this module the engine's only answer was to fail
+the whole batch (`engine.py` launch except-branch).  The
 reference's actor survives because it never leaves the host; this is
 the TPU-native equivalent — a supervised launch path with an explicit
 state machine:

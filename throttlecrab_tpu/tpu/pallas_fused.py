@@ -14,16 +14,20 @@ in VPU registers, packs the wire outputs, and streams the surviving
 rows back with a second DMA ring at unique indices.  No intermediate
 ever round-trips HBM and the host dispatches ONE launch per window.
 
-This is a *different thesis* from the retired row-movement kernels in
-pallas_ops.py.  Those moved rows for a body that still ran as composed
-XLA — and the on-device ablation showed row movement within noise
-*inside one fused XLA computation*, so they were a no-go.  What that
-ablation never measured is the cost attacked here: the inter-op HBM
-round trips and the per-op dispatch overhead of the composed graph.
-Their hard-won lowering lessons carry forward regardless: every loop
-scalar is pinned to i32 (jax x64 makes Mosaic's scalar conversion
-helper recurse on i64 induction variables), and serving batches arrive
-padded to at least the ring depth (limiter MIN_PAD).
+The cost attacked here is the inter-op HBM round trips and the per-op
+dispatch overhead of the composed graph.  Every loop scalar is
+i32 and the kernel is traced with x64 off (Mosaic cannot lower i64
+scalars), and serving batches arrive padded to at least the ring depth
+(limiter MIN_PAD).
+
+Status on the chip: compiled for a TPU v5e, Mosaic still refuses the
+kernel.  The i64 literals, the reduce-to-scalar and the (1, B) output
+blocks were repaired; what remains is the layout of the body's 1-D
+[B] column vectors ("Invalid relayout" inside `_udiv64`), and the
+4-wide row DMAs into a (B, width) VMEM block ("slice shape along
+dimension 1 must be aligned to tiling (128)").
+THROTTLECRAB_PALLAS_FUSED=1 therefore fails the server at boot on a
+TPU (`require_compiles`).
 
 i64 math on 32-bit lanes
 ========================
@@ -57,10 +61,10 @@ counter psums are untouched.
 
 Enable with THROTTLECRAB_PALLAS_FUSED=1 (read per dispatch on the
 host, so the composed-XLA path stays the default and the kill switch).
-Off-TPU the kernel runs in interpret mode — bit-exact, which is what
+The kernel runs in interpret mode only where a caller asks
+(`INTERPRET`, set by the CPU test harness) — bit-exact, which is what
 the differential tests pin, but orders of magnitude slower than the
-compiled XLA path; interpret-mode numbers are excluded from benchmark
-measurement (docs/benchmark-results.md).
+compiled XLA path; an interpret-mode rate is never a measurement.
 """
 
 from __future__ import annotations
@@ -83,6 +87,12 @@ from .kernel import (
 
 RING = 16  # row DMAs kept in flight per direction (gather / scatter)
 
+#: Pallas interpret mode.  Never inferred from the backend: the kernel
+#: is compiled by Mosaic unless a caller asks otherwise, and only the
+#: CPU test harness does (tests/conftest.py sets this to True).
+INTERPRET = False
+
+_LANES = 128  # vector lane count: the per-step counter block's width
 _I32_MAX = (1 << 31) - 1
 _NS_PER_SEC = 1_000_000_000
 _SIGN = -(1 << 31)  # i32 sign bit, for the unsigned-compare bias trick
@@ -282,7 +292,7 @@ def _sat_mul_nonneg64(a, b):
 def _udiv64(num, den):
     """Unsigned 64 / 64 restoring long division on pairs; den >= 1
     (callers clamp).  64 shift-compare-subtract rounds in a fori_loop —
-    every loop scalar i32 (the pallas_ops lowering lesson).  Covers all
+    every loop scalar i32 (Mosaic cannot lower i64 scalars).  Covers all
     kernel quotients: both closed-form divisions take nonneg operands
     after their max(.., 0) guards, matching lax.div's trunc-toward-zero
     there, and the whole-second wire fields divide nonneg ns values."""
@@ -349,7 +359,7 @@ def _gcra_pairs(rows, packed, now, *, width, with_degen, compact):
       packed: i32[B, PACK_WIDTH] request rows (kernel.pack_requests).
       now:    scalar pair (the sub-batch server timestamp).
 
-    Returns (rows_out i32[B, width], outs, n_exp i32 scalar) where
+    Returns (rows_out i32[B, width], outs, n_exp i32[1, 1]) where
     `outs` is a tuple of i32 arrays per `compact`:
       False -> (lo[4, B], hi[4, B])   i64 ns planes, join outside
       True  -> (planes[4, B],)        exact i32 wire planes
@@ -575,7 +585,11 @@ def _gcra_pairs(rows, packed, now, *, width, with_degen, compact):
             ),
             jnp.stack([z, remaining_out[1], reset_out[1], retry_out[1]]),
         )
-    n_exp = jnp.sum(n_exp_mask, dtype=jnp.int32)
+    # A (1, 1) reduction, not a scalar: Mosaic lowers a reduce-to-scalar
+    # through jnp at lowering time, where x64 promotes it to i64.
+    n_exp = jnp.sum(
+        n_exp_mask.astype(jnp.int32)[None, :], axis=1, keepdims=True
+    )
     return rows_out, outs, n_exp
 
 
@@ -586,8 +600,8 @@ def _gcra_pairs(rows, packed, now, *, width, with_degen, compact):
 
 
 def _dma_ring(n, copy):
-    """Issue `n` row DMAs through a RING-deep in-flight window (the
-    pallas_ops start/wait/drain discipline, all scalars i32)."""
+    """Issue `n` row DMAs through a RING-deep in-flight window
+    (start/wait/drain, all scalars i32)."""
     i32 = jnp.int32
 
     def body(i, _):
@@ -634,8 +648,8 @@ def _make_kernel(B, width, with_degen, compact, n_out):
         )
         rows_out[:] = new_rows
         for ref, val in zip(outs_refs, outs):
-            ref[0] = val
-        nexp_ref[0, 0] = n_exp
+            ref[0] = val.reshape(ref.shape[1:])
+        nexp_ref[0] = jnp.broadcast_to(n_exp, (1, _LANES))
 
         def scopy(i):
             return pltpu.make_async_copy(
@@ -653,7 +667,8 @@ def _join64(lo, hi):
     )
 
 
-def fused_window(state, packed, now, *, with_degen=True, compact=False):
+def fused_window(state, packed, now, *, with_degen=True, compact=False,
+                 interpret=None):
     """Decide one K-deep window in ONE fused launch (traceable JAX).
 
     Semantically identical to kernel.gcra_scan_packed + the expired-hit
@@ -698,13 +713,13 @@ def fused_window(state, packed, now, *, with_degen=True, compact=False):
 
     if compact == "cur":
         out_shapes = [
-            jax.ShapeDtypeStruct((K, B), jnp.int32),
-            jax.ShapeDtypeStruct((K, B), jnp.int32),
+            jax.ShapeDtypeStruct((K, 1, B), jnp.int32),
+            jax.ShapeDtypeStruct((K, 1, B), jnp.int32),
         ]
-        out_block = pl.BlockSpec((1, B), lambda k, *_: (k, 0))
+        out_block = pl.BlockSpec((1, 1, B), lambda k, *_: (k, 0, 0))
     elif compact == "w32":
-        out_shapes = [jax.ShapeDtypeStruct((K, B), jnp.int32)]
-        out_block = pl.BlockSpec((1, B), lambda k, *_: (k, 0))
+        out_shapes = [jax.ShapeDtypeStruct((K, 1, B), jnp.int32)]
+        out_block = pl.BlockSpec((1, 1, B), lambda k, *_: (k, 0, 0))
     elif compact:
         out_shapes = [jax.ShapeDtypeStruct((K, 4, B), jnp.int32)]
         out_block = pl.BlockSpec((1, 4, B), lambda k, *_: (k, 0, 0))
@@ -726,9 +741,7 @@ def fused_window(state, packed, now, *, with_degen=True, compact=False):
         out_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
             *([out_block] * n_out),
-            pl.BlockSpec(
-                (1, 1), lambda k, *_: (k, 0), memory_space=pltpu.SMEM
-            ),
+            pl.BlockSpec((1, 1, _LANES), lambda k, *_: (k, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((B, width), jnp.int32),
@@ -737,26 +750,32 @@ def fused_window(state, packed, now, *, with_degen=True, compact=False):
             pltpu.SemaphoreType.DMA((RING,)),
         ],
     )
-    res = pl.pallas_call(
-        _make_kernel(B, width, with_degen, compact, n_out),
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct(state.shape, state.dtype),
-            *out_shapes,
-            jax.ShapeDtypeStruct((K, 1), jnp.int32),
-        ),
-        # Operand indices include the 2 scalar-prefetch args:
-        # 0 = gs, 1 = nows, 2 = packed, 3 = state -> state aliases
-        # output 0, so the table is updated in place launch after
-        # launch exactly like the donated XLA twins.
-        input_output_aliases={3: 0},
-        interpret=jax.default_backend() != "tpu",
-    )(gs, nows, packed, state)
+    # Trace the kernel body with x64 off: under x64 every Python-int
+    # literal becomes an i64 constant, and Mosaic's element-type
+    # conversion recurses on i64 scalars.  All kernel operands are i32.
+    with jax.enable_x64(False):
+        res = pl.pallas_call(
+            _make_kernel(B, width, with_degen, compact, n_out),
+            grid_spec=grid_spec,
+            out_shape=(
+                jax.ShapeDtypeStruct(state.shape, state.dtype),
+                *out_shapes,
+                jax.ShapeDtypeStruct((K, 1, _LANES), jnp.int32),
+            ),
+            # Operand indices include the 2 scalar-prefetch args:
+            # 0 = gs, 1 = nows, 2 = packed, 3 = state -> state aliases
+            # output 0, so the table is updated in place launch after
+            # launch exactly like the donated XLA twins.
+            input_output_aliases={3: 0},
+            interpret=INTERPRET if interpret is None else interpret,
+        )(gs, nows, packed, state)
     state = res[0]
-    nexp = res[-1][:, 0].astype(jnp.int64)
+    nexp = res[-1][:, 0, 0].astype(jnp.int64)
     if compact == "cur":
-        out = _join64(res[1], res[2])
-    elif compact == "w32" or compact:
+        out = _join64(res[1][:, 0], res[2][:, 0])
+    elif compact == "w32":
+        out = res[1][:, 0]
+    elif compact:
         out = res[1]
     else:
         out = _join64(res[1], res[2])
@@ -914,3 +933,30 @@ def gcra_batch_fused_ins(
         ins_counts, jnp.asarray(valid, bool), out, compact
     )
     return state, exp_acc + jnp.sum(nexp), ins_counts, out
+
+
+# The (with_degen, compact) launch variants a served window takes: the
+# certified w32 tier, and the general tier any window can fall back to.
+SERVED_VARIANTS = ((False, "w32"), (True, True))
+
+
+def require_compiles(table, depth: int, batch: int) -> None:
+    """Compile the table's served launches (single-device packed scan or
+    sharded scan step) with the fused kernel, or raise.
+
+    THROTTLECRAB_PALLAS_FUSED=1 asks for this kernel: a boot where it
+    does not compile fails here, instead of serving from XLA or from
+    interpret mode."""
+    kind = next(iter(table.state.sharding.device_set)).device_kind
+    for with_degen, compact in SERVED_VARIANTS:
+        try:
+            table.compile_launch(
+                depth, batch, with_degen=with_degen, compact=compact
+            )
+        except Exception as e:
+            raise RuntimeError(
+                "THROTTLECRAB_PALLAS_FUSED=1, but the fused kernel does "
+                f"not compile for {kind} at "
+                f"K={depth}, B={batch}, compact={compact!r}: "
+                f"{type(e).__name__}: {str(e)[:500]}"
+            ) from e
