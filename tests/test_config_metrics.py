@@ -10,6 +10,8 @@ import pytest
 from throttlecrab_tpu.server.config import Config, ConfigError
 from throttlecrab_tpu.server.metrics import (
     MAX_KEY_LENGTH,
+    METRIC_NAMES,
+    STAGE_CLOCKS,
     Metrics,
     TopDeniedKeys,
     escape_label_value,
@@ -117,6 +119,29 @@ def test_prometheus_export_names():
     ):
         assert name in text, name
     assert 'throttlecrab_top_denied_keys{key="bad-key",rank="1"} 1' in text
+
+
+@pytest.mark.parametrize("clock", STAGE_CLOCKS, ids=lambda c: c.name)
+def test_stage_clock_exports_as_an_unlabelled_counter(clock):
+    """Each host stage clock is a registered counter on /metrics, one
+    unlabelled sample that carries what its threads added."""
+
+    def sample():
+        text = Metrics().export_prometheus()
+        assert f"# TYPE {clock.name} counter" in text
+        values = [
+            float(line.partition(" ")[2])
+            for line in text.splitlines()
+            if line.partition(" ")[0] == clock.name
+        ]
+        assert len(values) == 1
+        return values[0]
+
+    assert clock.name in METRIC_NAMES
+    before = sample()
+    clock.add_ns(250_000_000)
+    # A collection in between may add to the GC clock, never take away.
+    assert before + 0.25 <= sample() < before + 1.25
 
 
 def test_cluster_metrics_export():

@@ -17,7 +17,7 @@ import sys
 from ..runtime import device_info, enable_compile_cache
 from .config import Config, ConfigError
 from .engine import BatchingEngine
-from .metrics import Metrics
+from .metrics import GC_SECONDS, Metrics
 from .store import (
     create_cleanup_policy,
     create_control,
@@ -372,6 +372,14 @@ async def run_server(config: Config) -> None:
         # cluster mode the device is serialized by the cluster's device
         # lock, and the control tick's sensor reads ride that hold.
         control.tick_lock = limiter.device_lock
+    from ..tpu import profiling
+
+    # Every collection in this process is a process.gc span and counts
+    # on /metrics; THROTTLECRAB_PROFILE_DIR traces the first launches
+    # of whichever transport launches first.
+    profiling.install_gc_span(GC_SECONDS)
+    if config.profile_dir:
+        profiling.capture_first_launches(config.profile_dir)
     engine = BatchingEngine(
         limiter,
         batch_size=config.batch_size,
@@ -379,7 +387,6 @@ async def run_server(config: Config) -> None:
         max_scan_depth=config.max_scan_depth,
         cleanup_policy=cleanup_policy,
         metrics=metrics,
-        profile_dir=config.profile_dir or None,
         front=front,
         insight=insight,
         control=control,
@@ -497,6 +504,7 @@ async def run_server(config: Config) -> None:
         except Exception:
             log.exception("drain failed; falling back to the kill path")
     await engine.shutdown()
+    profiling.stop_capture()
     if recorder is not None:
         # Finalize the trace: full mode flushes + closes its incremental
         # file so a recorded workload replays after a clean stop (ring

@@ -97,7 +97,8 @@ _SPEC = [
      "the kill switch; off-TPU the fused kernel runs in interpret "
      "mode: bit-exact but slow, for tests only"),
     ("profile_dir", "THROTTLECRAB_PROFILE_DIR", "", str,
-     "Directory for an xprof trace of the first launches (empty: off)"),
+     "Directory for an xprof trace of the process's first 50 launches, "
+     "on any transport (empty: off)"),
     # --- front tier (L3.5: exact deny cache + admission control) -------
     ("front_deny_cache", "THROTTLECRAB_FRONT_DENY_CACHE", 65536, int,
      "Deny-cache capacity in entries: provably exact repeat denials "

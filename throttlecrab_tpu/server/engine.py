@@ -45,6 +45,7 @@ from ..tpu.limiter import (
     STATUS_OK,
     STATUS_TENANT_QUOTA,
 )
+from ..tpu.profiling import launch_tick, span
 from .types import ThrottleRequest, ThrottleResponse
 
 __all__ = [
@@ -83,8 +84,6 @@ class BatchingEngine:
         cleanup_policy: Optional[CleanupPolicy] = None,
         metrics=None,
         now_fn=None,
-        profile_dir: Optional[str] = None,
-        profile_launches: int = 50,
         max_scan_depth: int = 16,
         front=None,
         insight=None,
@@ -185,10 +184,6 @@ class BatchingEngine:
         # Strong refs: the event loop only weakly references tasks, and a
         # GC'd flush task would strand its batch's futures forever.
         self._flush_tasks: set = set()
-        # Optional xprof capture of the first N launches (tpu/profiling.py).
-        self._profile_dir = profile_dir
-        self._profile_remaining = profile_launches if profile_dir else 0
-        self._profiling = False
 
     # ------------------------------------------------------------------ #
 
@@ -293,13 +288,11 @@ class BatchingEngine:
                 launched = None
                 if windows:
                     now_ns = self.now_fn()
-                    self._profile_tick()
+                    launch_tick()
                     dl_kw = self._deadline_many_kw(windows)
 
                     def do_dispatch(ws=windows, t=now_ns, dk=dl_kw):
-                        from ..tpu.profiling import annotate
-
-                        with self.limiter_lock, annotate("gcra_dispatch"):
+                        with self.limiter_lock, span("engine.dispatch"):
                             # Dispatch-order stamp for the deny cache:
                             # taken under the same lock that serializes
                             # device launches across transports, so seq
@@ -554,13 +547,11 @@ class BatchingEngine:
 
         now_ns = self.now_fn()
         loop = asyncio.get_running_loop()
-        self._profile_tick()
+        launch_tick()
         dl_kw = self._deadline_many_kw(windows)
 
         def launch():
-            from ..tpu.profiling import annotate
-
-            with self.limiter_lock, annotate("gcra_scan_decide"):
+            with self.limiter_lock, span("engine.scan_decide"):
                 seq = self.front.next_seq() if self.front else 0
                 return seq, self.limiter.rate_limit_many(
                     [
@@ -608,7 +599,7 @@ class BatchingEngine:
         requests = [r for r, _ in batch]
         now_ns = self.now_fn()
         loop = asyncio.get_running_loop()
-        self._profile_tick()
+        launch_tick()
         dl_kw = {}
         if self._limiter_takes_deadlines and any(
             r.deadline_ns is not None for r in requests
@@ -618,9 +609,7 @@ class BatchingEngine:
             }
 
         def launch():
-            from ..tpu.profiling import annotate
-
-            with self.limiter_lock, annotate("gcra_batch_decide"):
+            with self.limiter_lock, span("engine.batch_decide"):
                 seq = self.front.next_seq() if self.front else 0
                 return seq, self.limiter.rate_limit_batch(
                     [r.key for r in requests],
@@ -700,22 +689,6 @@ class BatchingEngine:
                         retry_after_ns=int(result.retry_after_ns[i]),
                     )
                 )
-
-    def _profile_tick(self) -> None:
-        """Start/stop the xprof capture window around the first N launches."""
-        if self._profile_remaining <= 0:
-            if self._profiling:
-                import jax.profiler
-
-                jax.profiler.stop_trace()
-                self._profiling = False
-            return
-        if not self._profiling:
-            import jax.profiler
-
-            jax.profiler.start_trace(self._profile_dir)
-            self._profiling = True
-        self._profile_remaining -= 1
 
     # ------------------------------------------------------------------ #
 
@@ -863,8 +836,3 @@ class BatchingEngine:
             self._flush_handle.cancel()
             self._flush_handle = None
         await self._flush()
-        if self._profiling:
-            import jax.profiler
-
-            jax.profiler.stop_trace()
-            self._profiling = False

@@ -20,6 +20,8 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional, Tuple
 
+from ..tpu.profiling import FETCH_SECONDS, KEYMAP_SECONDS, StageClock
+
 MAX_KEY_LENGTH = 256  # metrics.rs:21
 MAX_TRACKED_DENIED_KEYS = 10_000  # metrics.rs:119-121
 
@@ -72,6 +74,14 @@ METRIC_NAMES = (
     # Graceful lifecycle (drain + deadline shed, server/engine.py).
     "throttlecrab_tpu_drain_shed_total",
     "throttlecrab_tpu_deadline_shed_total",
+    # Host stage clocks (STAGE_CLOCKS below): the native driver's wait
+    # and busy time, its front-tier, keymap and fetch stages, GC passes.
+    "throttlecrab_tpu_wire_wait_seconds_total",
+    "throttlecrab_tpu_driver_busy_seconds_total",
+    "throttlecrab_tpu_front_seconds_total",
+    "throttlecrab_tpu_keymap_seconds_total",
+    "throttlecrab_tpu_fetch_seconds_total",
+    "throttlecrab_process_gc_seconds_total",
     # Insight tier (L3.75, insight/).
     "throttlecrab_tpu_insight_allowed_rate",
     "throttlecrab_tpu_insight_denied_rate",
@@ -97,6 +107,36 @@ METRIC_NAMES = (
     "throttlecrab_tpu_checkpoint_bytes",
     "throttlecrab_tpu_checkpoint_corrupt_skipped_total",
     "throttlecrab_tpu_checkpoint_recoveries_total",
+)
+
+# Host stage clocks (tpu/profiling.py's StageClock): seconds per stage of
+# the native driver threads and of the process, each written lock-free by
+# the thread that runs the stage.  Two native transports that run at once
+# (RESP and HTTP) each add their own driver thread's time, so wire wait
+# plus driver busy then grows at twice the wall clock.
+WIRE_WAIT_SECONDS = StageClock(
+    "throttlecrab_tpu_wire_wait_seconds_total",
+    "Native driver threads' time blocked in ws_next_batch for requests "
+    "(span wire.next_batch)",
+)
+DRIVER_BUSY_SECONDS = StageClock(
+    "throttlecrab_tpu_driver_busy_seconds_total",
+    "Native driver threads' time outside ws_next_batch: capture, front "
+    "tier, keymap, launch, fetch, replies and periodic work",
+)
+FRONT_SECONDS = StageClock(
+    "throttlecrab_tpu_front_seconds_total",
+    "Native driver time in the front tier (spans front.filter, "
+    "front.observe)",
+)
+GC_SECONDS = StageClock(
+    "throttlecrab_process_gc_seconds_total",
+    "Garbage-collector passes in the server process, in any thread "
+    "(span process.gc)",
+)
+STAGE_CLOCKS = (
+    WIRE_WAIT_SECONDS, DRIVER_BUSY_SECONDS, FRONT_SECONDS, KEYMAP_SECONDS,
+    FETCH_SECONDS, GC_SECONDS,
 )
 
 
@@ -560,6 +600,10 @@ class Metrics:
             "counter",
             self.deadline_shed,
         )
+        # Host stage clocks: process-wide, summed over the threads that
+        # ran each stage.
+        for clock in STAGE_CLOCKS:
+            metric(clock.name, clock.help, "counter", clock.seconds())
         # Fault injection (chaos runs): per-site fired counts from the
         # armed injector, so a soak can assert the fault actually fired.
         from ..faults import active_injector

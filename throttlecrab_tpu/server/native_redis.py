@@ -36,6 +36,8 @@ from ..tpu.limiter import (
     WireBatchResult,
     limiter_uses_bytes_keys,
 )
+from ..tpu.profiling import launch_tick, span
+from .metrics import DRIVER_BUSY_SECONDS, FRONT_SECONDS, WIRE_WAIT_SECONDS
 
 log = logging.getLogger("throttlecrab.redis.native")
 
@@ -264,10 +266,17 @@ class NativeRedisTransport:
         (backlog — e.g. pipelined clients), drain up to max_scan_depth
         further batches without lingering and decide the whole window in
         ONE device launch (limiter.rate_limit_many), exactly like the
-        asyncio engine's backlog path."""
+        asyncio engine's backlog path.
+
+        The thread's time splits into the blocking wait
+        (WIRE_WAIT_SECONDS) and the rest (DRIVER_BUSY_SECONDS), both
+        read at the wait's two ends; each decided window is one
+        `driver.window` span over its stage spans (tpu/profiling.py)."""
         B = self.batch_size
         can_scan = hasattr(self.limiter, "rate_limit_many")
         last_metrics = time.monotonic()
+        windows = 0
+        t_woke = time.monotonic_ns()
         while self._running:
             try:
                 if (
@@ -276,20 +285,28 @@ class NativeRedisTransport:
                 ):
                     self._push_metrics()
                     last_metrics = time.monotonic()
-                n = self._next_batch(self.max_linger_us)
+                with span("wire.next_batch", WIRE_WAIT_SECONDS) as wait:
+                    n = self._next_batch(self.max_linger_us)
+                DRIVER_BUSY_SECONDS.add_ns(wait.t0 - t_woke)
+                t_woke = wait.t1
                 if n <= 0:
                     continue
-                batches = [self._capture(int(n))]
-                while (
-                    can_scan
-                    and n == B
-                    and len(batches) < self.max_scan_depth
-                ):
-                    n = self._next_batch(0)
-                    if n <= 0:
-                        break
-                    batches.append(self._capture(int(n)))
-                self._decide_window(batches)
+                windows += 1
+                with span("driver.window", seq=windows):
+                    with span("wire.capture"):
+                        # The drain pops of a backlog wait for nothing
+                        # (linger 0): they are capture, not wire wait.
+                        batches = [self._capture(int(n))]
+                        while (
+                            can_scan
+                            and n == B
+                            and len(batches) < self.max_scan_depth
+                        ):
+                            n = self._next_batch(0)
+                            if n <= 0:
+                                break
+                            batches.append(self._capture(int(n)))
+                    self._decide_window(batches)
             except Exception:
                 log.exception("native redis driver error")
                 if not self._running:
@@ -505,6 +522,7 @@ class NativeRedisTransport:
         None after a post-launch failure) per frame."""
         if not frames:
             return [], 0
+        launch_tick()
         results = None
         seq = 0
         front = self.front
@@ -583,9 +601,10 @@ class NativeRedisTransport:
         n_expired = sum(int((b[5] < 0).sum()) for b in batches)
         if use_front:
             depth = int(self._lib.ws_queue_depth(self._h))
-            plans = [
-                self._front_filter(b, now_ns, depth) for b in batches
-            ]
+            with span("front.filter", FRONT_SECONDS):
+                plans = [
+                    self._front_filter(b, now_ns, depth) for b in batches
+                ]
             frames = [
                 p["miss_frame"] for p in plans
                 if p["miss_frame"] is not None
@@ -610,17 +629,19 @@ class NativeRedisTransport:
         if plans is not None:
             # Re-align miss results with their plans, observe the engine
             # rows, and merge hits/sheds/engine decisions per frame.
-            merged = []
             it = iter(results)
-            for plan in plans:
-                res = (
-                    next(it) if plan["miss_frame"] is not None else None
-                )
-                if front is not None and front.deny_cache is not None:
-                    self._observe_plan(plan, res, now_ns, seq)
-                merged.append(self._merge_plan(plan, res))
-            results = merged
-        self._maybe_record(batches, results, now_ns)
+            paired = [
+                (plan, next(it) if plan["miss_frame"] is not None else None)
+                for plan in plans
+            ]
+            if front is not None and front.deny_cache is not None:
+                with span("front.observe", FRONT_SECONDS):
+                    for plan, res in paired:
+                        self._observe_plan(plan, res, now_ns, seq)
+            with span("driver.merge"):
+                results = [self._merge_plan(p, r) for p, r in paired]
+        with span("recorder.capture"):
+            self._maybe_record(batches, results, now_ns)
         # Metrics: ONE aggregated record for the whole window — it was
         # one device launch (record_batch bumps device_launches, so
         # per-sub-batch calls would overcount launches by up to
@@ -631,59 +652,71 @@ class NativeRedisTransport:
             self.metrics is not None
             and self.metrics.top_denied is not None
         )
-        for (blob, offsets, _p, gen, fd, _b), res in zip(batches, results):
-            n_a, n_d, n_e, dk = self._respond_one(
-                blob, offsets, gen, fd, res, track_denied
-            )
-            tot_allowed += n_a
-            tot_denied += n_d
-            tot_errors += n_e
-            denied_keys.extend(dk)
-            any_launch = any_launch or res is not None
+        with span("wire.respond"):
+            for (blob, offsets, _p, gen, fd, _b), res in zip(
+                batches, results
+            ):
+                n_a, n_d, n_e, dk = self._respond_one(
+                    blob, offsets, gen, fd, res, track_denied
+                )
+                tot_allowed += n_a
+                tot_denied += n_d
+                tot_errors += n_e
+                denied_keys.extend(dk)
+                any_launch = any_launch or res is not None
         if self.insight is not None:
             # Throttled (~1/s) insight poll; this driver thread may
             # block on the device, exactly like its decide launches.
-            self.insight.maybe_poll(now_ns, self.limiter_lock)
+            with span("insight.poll"):
+                self.insight.maybe_poll(now_ns, self.limiter_lock)
         if self.control is not None:
             # Throttled control tick, same discipline.  The native wire
             # layer holds its own pending queue device-side of this
             # driver, so depth 0 is the honest engine-queue reading —
             # admission's EWMA wait still carries the launch-cost
             # signal.
-            self.control.maybe_tick(now_ns, self.limiter_lock)
+            with span("control.tick"):
+                self.control.maybe_tick(now_ns, self.limiter_lock)
         if self.checkpointer is not None:
-            if frames:
-                # Launched rows mark dirty for the next delta (raw wire
-                # key bytes — the identity the keymap holds on this
-                # path, so the delta gather matches the export).
-                self.checkpointer.note_keys(
-                    k
-                    for b, o, _p in frames
-                    for k in self._keys_of(b, o)
-                )
-            # Throttled checkpoint write: device export under
-            # limiter_lock, encode + fsync outside it — this driver
-            # thread blocks on the device for its decides anyway.
-            self.checkpointer.maybe_tick(now_ns, self.limiter_lock)
+            with span("checkpoint.tick"):
+                if frames:
+                    # Launched rows mark dirty for the next delta (raw
+                    # wire key bytes — the identity the keymap holds on
+                    # this path, so the delta gather matches the
+                    # export).
+                    self.checkpointer.note_keys(
+                        k
+                        for b, o, _p in frames
+                        for k in self._keys_of(b, o)
+                    )
+                # Throttled checkpoint write: device export under
+                # limiter_lock, encode + fsync outside it — this driver
+                # thread blocks on the device for its decides anyway.
+                self.checkpointer.maybe_tick(now_ns, self.limiter_lock)
         if self.metrics is not None and (
             any_launch or tot_errors
         ):
-            self.metrics.record_batch(
-                self.name,
-                n_allowed=tot_allowed,
-                n_denied=tot_denied,
-                n_errors=tot_errors,
-                denied_keys=denied_keys,
-                # Only requests that actually rode the launch count
-                # toward the batching/coalescing gauges.
-                batch=(
-                    launched_n
-                    if plans is not None
-                    else tot_allowed + tot_denied + tot_errors
-                ),
-                launches=1 if frames else 0,
-            )
-        self._maybe_sweep(now_ns, sum(len(b[1]) - 1 for b in batches))
+            with span("metrics.record"):
+                self.metrics.record_batch(
+                    self.name,
+                    n_allowed=tot_allowed,
+                    n_denied=tot_denied,
+                    n_errors=tot_errors,
+                    denied_keys=denied_keys,
+                    # Only requests that actually rode the launch count
+                    # toward the batching/coalescing gauges.
+                    batch=(
+                        launched_n
+                        if plans is not None
+                        else tot_allowed + tot_denied + tot_errors
+                    ),
+                    launches=1 if frames else 0,
+                )
+        if self.cleanup_policy is not None:
+            with span("cleanup.sweep"):
+                self._maybe_sweep(
+                    now_ns, sum(len(b[1]) - 1 for b in batches)
+                )
 
     def _maybe_record(self, batches, results, now_ns) -> None:
         """Flight-recorder capture (replay/): the native twin of

@@ -34,6 +34,7 @@ from ..core.errors import InternalError, InvalidRateLimit, NegativeQuantity
 from ..core.rate_limiter import RateLimitResult, normalize_now_ns
 from ..faults import maybe_fail
 from .keymap import PyKeyMap
+from .profiling import FETCH_SECONDS, KEYMAP_SECONDS, span
 from .table import BucketTable
 
 
@@ -429,9 +430,19 @@ class _PendingWireLaunch:
 
     def fetch(self) -> list:
         maybe_fail("fetch")
-        out = np.asarray(self._out_dev)
+        with span("driver.fetch", FETCH_SECONDS):
+            out = np.asarray(self._out_dev)
+        finished = None
         if self._w32:
             from .kernel import finish_w32
+        elif self._finish is not None:
+            # tk_finish completes the cur tier's words, every frame of
+            # the window under one span.
+            with span("keymap.finish", KEYMAP_SECONDS):
+                finished = [
+                    self._finish(packed, out[j, : len(status)], self._now_ns)
+                    for j, (packed, status, _) in enumerate(self._prepared)
+                ]
         results = []
         for j, (packed, status, params) in enumerate(self._prepared):
             n = len(status)
@@ -439,8 +450,8 @@ class _PendingWireLaunch:
             cur_plane = None
             if self._w32:
                 o = np.stack(finish_w32(out[j, :n]))
-            elif self._finish is not None:
-                o = self._finish(packed, out[j, :n], self._now_ns).T
+            elif finished is not None:
+                o = finished[j].T
                 # cur*2 + allowed words: expose the exact observed TATs
                 # for the front tier's deny cache (see BatchResult).
                 cur_plane = out[j, :n] >> 1
@@ -891,25 +902,27 @@ class TpuRateLimiter(ScalarCompatMixin):
         min_tol = 1 << 62
         max_inc = 0
         rem_bound = 0
-        for blob, offsets, params in frames:
-            packed, status, flags = km.prepare_batch(
-                blob, offsets, params, agg=agg
-            )
-            if flags & (PREP_CONFLICT | PREP_FULL):
-                return None
-            any_degen = any_degen or bool(flags & PREP_DEGEN)
-            any_bigtol = any_bigtol or bool(flags & PREP_BIGTOL)
-            max_tol = max(max_tol, int(agg[0]))
-            # agg[0] > 0 ⇔ the frame had a valid lane with tol > 0
-            # (tol <= 0 lanes carry PREP_DEGEN, and any_degen refuses
-            # w32 outright, so the 0-sentinel min never leaks in).
-            if int(agg[0]) > 0:
-                min_tol = min(min_tol, int(agg[1]))
-            max_inc = max(max_inc, int(agg[2]))
-            rem_bound = max(rem_bound, int(agg[3]))
-            prepared.append((packed, status, params))
-            n = len(status)
-            width = max(width, 1 << max(n - 1, 0).bit_length())
+        with span("keymap.prepare", KEYMAP_SECONDS):
+            for blob, offsets, params in frames:
+                packed, status, flags = km.prepare_batch(
+                    blob, offsets, params, agg=agg
+                )
+                if flags & (PREP_CONFLICT | PREP_FULL):
+                    return None
+                any_degen = any_degen or bool(flags & PREP_DEGEN)
+                any_bigtol = any_bigtol or bool(flags & PREP_BIGTOL)
+                max_tol = max(max_tol, int(agg[0]))
+                # agg[0] > 0 ⇔ the frame had a valid lane with tol > 0
+                # (tol <= 0 lanes carry PREP_DEGEN, and any_degen
+                # refuses w32 outright, so the 0-sentinel min never
+                # leaks in).
+                if int(agg[0]) > 0:
+                    min_tol = min(min_tol, int(agg[1]))
+                max_inc = max(max_inc, int(agg[2]))
+                rem_bound = max(rem_bound, int(agg[3]))
+                prepared.append((packed, status, params))
+                n = len(status)
+                width = max(width, 1 << max(n - 1, 0).bit_length())
 
         from .kernel import PACK_WIDTH
 
@@ -929,11 +942,6 @@ class TpuRateLimiter(ScalarCompatMixin):
             and self.table.cur_safe
             and hasattr(km, "finish")
         )
-        K = len(prepared)
-        K_pad = 1 << max(K - 1, 0).bit_length()
-        stack = np.zeros((K_pad, width, PACK_WIDTH), np.int32)
-        for j, (packed, _, _) in enumerate(prepared):
-            stack[j, : len(packed)] = packed
 
         # w32 tier (4 B/request, device-packed exact wire values): the
         # certificate runs on the C++ prep's aggregates — no Python pass
@@ -951,14 +959,20 @@ class TpuRateLimiter(ScalarCompatMixin):
         use_cur = use_cur and not use_w32
 
         maybe_fail("launch")
-        out_dev = self.table.check_many_packed(
-            stack,
-            np.full(K_pad, now_ns, np.int64),
-            with_degen=any_degen,
-            compact="w32" if use_w32 else ("cur" if use_cur else True),
-            params_cur_safe=params_cur_safe,
-            max_tolerance=max_tol,
-        )
+        with span("driver.launch"):
+            K = len(prepared)
+            K_pad = 1 << max(K - 1, 0).bit_length()
+            stack = np.zeros((K_pad, width, PACK_WIDTH), np.int32)
+            for j, (packed, _, _) in enumerate(prepared):
+                stack[j, : len(packed)] = packed
+            out_dev = self.table.check_many_packed(
+                stack,
+                np.full(K_pad, now_ns, np.int64),
+                with_degen=any_degen,
+                compact="w32" if use_w32 else ("cur" if use_cur else True),
+                params_cur_safe=params_cur_safe,
+                max_tolerance=max_tol,
+            )
         if use_w32:
             return _PendingWireLaunch(out_dev, prepared, w32=True)
         if use_cur:
