@@ -372,9 +372,8 @@ def run_front_bench(args, device) -> int:
     abuse the engine launches once per `batch_size` accumulated MISSES,
     not once per batch_size arrivals — the launch's fixed cost amortizes
     over every arrival the cache absorbed in between.  The cache path is
-    the bulk window flow the native driver uses (FrontTier.lookup_window
-    / observe_window: one lock + one computation per distinct combo per
-    window).  With the cache off, every arrival queues and launches
+    the bulk window flow (FrontTier.lookup_window / observe_window: one
+    native call per window).  With the cache off, every arrival queues and launches
     ride batch_size-request windows.  Time is virtual (1 ms per arrival
     window): the hot keys saturate in the first windows and then stay
     inside their proven deny windows — the regime this traffic shape

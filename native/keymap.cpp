@@ -25,6 +25,8 @@
 #include <cstring>
 #include <vector>
 
+#include "gcra_params.h"
+
 namespace {
 
 constexpr uint64_t FNV_OFFSET = 1469598103934665603ULL;
@@ -557,7 +559,7 @@ int64_t tk_prepare_batch(void* h, const char* keys, const int64_t* offsets,
         const int64_t qty = params[i * 4 + 3];
 
         uint8_t st = STATUS_OK;
-        if (burst <= 0 || count <= 0 || period <= 0)
+        if (!gcra_params_valid(burst, count, period))
             st = STATUS_INVALID_PARAMS;
         if (qty < 0) st = STATUS_NEGATIVE_QUANTITY;
         status[i] = st;
@@ -567,20 +569,10 @@ int64_t tk_prepare_batch(void* h, const char* keys, const int64_t* offsets,
             continue;
         }
 
-        // Exact f64 derivation (matches limiter.derive_params): numpy and
-        // C++ both follow IEEE-754 double semantics here.
-        const double emission_f =
-            static_cast<double>(period) * 1e9 / static_cast<double>(count);
-        int64_t em;
-        if (emission_f >= 9223372036854775808.0)  // 2^63
-            em = INT64_MAX;
-        else
-            em = static_cast<int64_t>(emission_f);
-        if (em < 0) em = 0;
-        const uint64_t b32 =
-            static_cast<uint64_t>(burst - 1) & 0xFFFFFFFFull;
-        const int64_t tol = static_cast<int64_t>(
-            static_cast<uint64_t>(em) * b32);  // wrapping, as reference
+        // Exact f64 derivation (matches limiter.derive_params), shared
+        // with the deny cache through gcra_params.h.
+        int64_t em, tol;
+        gcra_derive(burst, count, period, &em, &tol);
 
         if (em == 0 || tol <= 0 || qty == 0) flags |= TK_PREP_DEGEN;
         // Segment-arithmetic overflow certificate (must mirror

@@ -277,11 +277,12 @@ def test_param_change_never_serves_stale_denials():
 
 def test_snapshot_restore_clears_cache_direct():
     front = make_front()
-    front.deny_cache._entries[("k", (1, 1, 1, 1))] = object()
-    front.deny_cache._by_key["k"] = {(1, 1, 1, 1)}
+    _prime(front.deny_cache, key="k")
+    assert len(front.deny_cache) == 1
     front.on_restore()
     assert len(front.deny_cache) == 0
-    assert front.deny_cache._by_key == {}
+    assert front.deny_cache.entries() == []
+    assert front.deny_cache.records() == []
 
 
 # ===================================================================== #
@@ -296,12 +297,7 @@ class _AlwaysShed(AdmissionController):
     def __init__(self):
         super().__init__(max_pending=1)
 
-    def admit(self, depth, peek):
-        with self._lock:
-            if peek:
-                self.shed_peek += 1
-            else:
-                self.shed_consume += 1
+    def verdict(self, depth, peek):
         return False
 
 
@@ -646,11 +642,11 @@ def test_deny_cache_fail_window_drops_key_state():
     have moved the TAT, so neither can certify exactness any longer."""
     cache = DenyCache(64)
     _prime(cache)
-    assert len(cache) == 1 and "u" in cache._records
+    assert len(cache) == 1 and cache.record("u") is not None
     cache.begin_inflight("u")
     cache.fail_window(["u"])
     assert len(cache) == 0
-    assert "u" not in cache._records
+    assert cache.record("u") is None
     # Hold released: a fresh prime certifies again.
     _prime(cache)
     assert cache.lookup("u", 3, 60, 60, 3, T0 + 2) is not None
@@ -669,7 +665,7 @@ def test_deny_cache_stale_seq_cannot_roll_back_record():
     # must invalidate (an allow happened) but NOT overwrite the record.
     cache.observe("u", 3, 60, 60, 1, T0, True, seq=0, cur_ns=12345)
     assert len(cache) == 0
-    rec = cache._records.get("u")
+    rec = cache.record("u")
     assert rec is not None and rec[0] != 12345
 
 
@@ -678,7 +674,7 @@ def test_deny_cache_capacity_bound():
     for i in range(8):
         _prime(cache, key=f"c{i}")
     assert len(cache) <= 4
-    assert len(cache._records) <= 4
+    assert len(cache.records()) <= 4
 
 
 def test_deny_cache_record_refresh_defers_eviction():
@@ -692,8 +688,8 @@ def test_deny_cache_record_refresh_defers_eviction():
                   cur_ns=T0 + 3 * NS)
     # Cold churn evicts ONE record: it must be cold1, not hot.
     _prime(cache, key="cold2")
-    assert "hot" in cache._records
-    assert "cold1" not in cache._records
+    assert cache.record("hot") is not None
+    assert cache.record("cold1") is None
 
 
 def test_deny_cache_sweep_drops_expired():

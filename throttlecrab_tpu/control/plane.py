@@ -19,8 +19,9 @@ never having existed (pinned by the differential test).
 
 Lock discipline: ``ControlPlane._lock`` is ranked 81 in
 analysis/lockorder.toml — strictly BELOW every leaf lock a tick reads
-through (InsightTier._lock 82, DenyCache 84, AdmissionController 86,
-Metrics 88), so the snapshot can never invert the canonical order.
+through (InsightTier._lock 82, AdmissionController 86, Metrics 88; the
+deny cache's native mutex is a leaf taken only inside its calls), so the
+snapshot can never invert the canonical order.
 
 Clock discipline: the plane never reads a wall clock.  ``now_ns``
 always arrives from the caller (the engine's ``now_fn``, the native

@@ -15,7 +15,10 @@ them.  All methods are thread-safe.
 
 Key identity matches the limiter's keymap (`bytes_keys`): the cache
 normalizes str/bytes exactly like the transports do, so one client key
-is one cache row no matter which wire it arrived on.
+is one cache row no matter which wire it arrived on.  The native wire
+driver hands whole frames over (filter_frame, observe_frame): its raw
+key bytes are that identity for either keymap, since a str key is
+stored as its `surrogateescape` bytes.
 """
 
 from __future__ import annotations
@@ -26,7 +29,7 @@ from .admission import (  # noqa: F401  (re-exported API)
     AdmissionController,
     OverloadError,
 )
-from .deny_cache import DenyCache, DenyHit  # noqa: F401
+from .deny_cache import DenyCache, DenyHit, filter_frame  # noqa: F401
 
 
 class FrontTier:
@@ -155,6 +158,61 @@ class FrontTier:
         (shed rows)."""
         if self.deny_cache is not None:
             self.deny_cache.release_window(keys)
+
+    def filter_frame(self, blob, offsets, params, budgets, now_ns, depth,
+                     st_deadline, st_overloaded):
+        """One wire frame (keys as blob + offsets in the limiter's byte
+        identity, params i64[n, 4], deadline budgets i64[n]) through the
+        cache and admission in one native call (deny_cache.filter_frame,
+        which writes the caller's two shed statuses): admission is asked
+        once per class at this window's depth and its sheds counted in
+        bulk; served keys reach the insight tier once each, with their
+        count.  Miss keys stay in flight until observe_frame /
+        fail_frame."""
+        admission = self.admission
+        consume_ok = peek_ok = True
+        if admission is not None:
+            consume_ok = admission.verdict(depth, False)
+            peek_ok = admission.verdict(depth, True)
+        f = filter_frame(
+            self.deny_cache, blob, offsets, params, budgets, now_ns,
+            st_deadline, st_overloaded, consume_ok, peek_ok,
+        )
+        metrics = self.metrics
+        for peek, n in ((True, f.shed_peek), (False, f.shed_consume)):
+            if n:
+                admission.count_shed(peek, n)
+                if metrics is not None:
+                    metrics.record_front_shed(peek, n)
+        if metrics is not None:
+            if f.n_stale:
+                metrics.record_front_stale(f.n_stale)
+            if f.n_hits:
+                metrics.record_front_hits(f.n_hits)
+        if f.n_hits and self.insight is not None:
+            keys = [
+                blob[offsets[i]: offsets[i + 1]]
+                for i in f.hit_first.tolist()
+            ]
+            if not self.bytes_keys:
+                keys = [k.decode("utf-8", "surrogateescape") for k in keys]
+            self.insight.record_front_denied(keys, f.hit_count.tolist())
+        return f
+
+    def observe_frame(self, frame, res, now_ns, seq) -> None:
+        """Feed a device-decided miss frame's results (res None: the
+        launch failed after its writes may have committed) to the cache
+        and release its keys' holds: the frame twin of observe_window /
+        fail_window."""
+        if self.deny_cache is None or frame is None:
+            return
+        if res is None:
+            self.deny_cache.fail_frame(frame)
+            return
+        self.deny_cache.observe_frame(
+            frame, res.status, res.allowed, getattr(res, "cur_ns", None),
+            now_ns, seq,
+        )
 
     def fail_window(self, keys) -> None:
         """A launch failed after its writes may have committed: release

@@ -115,6 +115,24 @@ class AdmissionController:
     def admit(self, depth: int, peek: bool) -> bool:
         """Admit a new arrival given `depth` requests already pending?
         Counts the shed when refusing."""
+        ok = self.verdict(depth, peek)
+        if not ok:
+            self.count_shed(peek)
+        return ok
+
+    def count_shed(self, peek: bool, n: int = 1) -> None:
+        """Count `n` refused arrivals of one class."""
+        with self._lock:
+            if peek:
+                self.shed_peek += n
+            else:
+                self.shed_consume += n
+
+    def verdict(self, depth: int, peek: bool) -> bool:
+        """admit() without counting: the answer depends only on (depth,
+        peek) and the controller's current state, so a window of
+        arrivals at one depth asks once per class and counts its sheds
+        in bulk (count_shed)."""
         frac = self.peek_frac if peek else 1.0
         if peek and self.hot_shed_weight:
             # Concentrated abuse: tighten the peek bounds so advisory
@@ -129,10 +147,4 @@ class AdmissionController:
             over = True
         elif self.max_wait_us and self._cost_us:
             over = depth * self._cost_us > self.max_wait_us * frac
-        if over:
-            with self._lock:
-                if peek:
-                    self.shed_peek += 1
-                else:
-                    self.shed_consume += 1
         return not over

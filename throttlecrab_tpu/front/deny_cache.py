@@ -14,9 +14,9 @@ fields — until the earliest of
                                     key and first-touch semantics apply),
   * any *allowed* decision for the key (the one thing that writes).
 
-Everything here is plain Python integers; the oracle is
-`core/rate_limiter.py` and every served field reproduces its math (and
-therefore the kernel's, which is validated against it) bit for bit:
+The oracle is `core/rate_limiter.py` and every served field reproduces
+its math (and therefore the kernel's, which is validated against it) bit
+for bit:
 
     tat_eff   = S                      (unclamped inside the window)
     remaining = max((now + tol - S) // em, 0)
@@ -40,25 +40,42 @@ Exactness discipline — an entry is created only when ALL of:
 Anything that fails a check simply misses to the engine: the cache can
 only ever be *conservative*, never wrong.
 
-Concurrency: one lock guards all state (the asyncio engine's event loop,
-its executor threads, and the native wire driver all touch the cache).
-Observations are ordered by a dispatch-time sequence number so a slow
-fetch on one transport can never overwrite a newer write record from
-another with stale state.
+The state and every operation live in native/deny_cache.cpp; this module
+is its ctypes facade.  The GCRA parameters come from the same C++
+function the keymap's batch prep uses (native/gcra_params.h), and the
+arithmetic is held to the plain Python implementation
+(tests/deny_cache_oracle.py) by a differential test, not by the i64
+lint.  Eviction is FIFO in O(1) on intrusive lists: write records by
+last write, cached denials by insertion.
+
+Keys: bytes are stored as they are; str keys (a python keymap's
+identity) as their UTF-8 `surrogateescape` bytes, the exact inverse of
+how the native wire driver decodes them, so the wire's raw bytes name
+the same row.  A key that cannot be encoded never hits and is never
+recorded (conservative).  Parameters outside i64 never hit either.
+
+Concurrency: one native mutex guards all state; it is taken and released
+inside each call and never held across Python, and ctypes drops the GIL
+around the call.  Observations are ordered by a dispatch-time sequence
+number so a slow fetch on one transport can never overwrite a newer
+write record from another with stale state.
 """
 
 from __future__ import annotations
 
-import threading
+import ctypes
 from dataclasses import dataclass
+from typing import NamedTuple, Optional
+
+import numpy as np
 
 NS_PER_SEC = 1_000_000_000
 
-# All cached quantities must sit far below i64 saturation so the
-# reference's sat_add/sat_sub/wrap_u64 pipeline reduces to plain int
-# math.  2^61 ns is ~73 years — nothing a real rate limit reaches.
-_BOUND = 1 << 61
-_I32_MAX = (1 << 31) - 1
+_I64_MIN = -(1 << 63)
+_I64_MAX = (1 << 63) - 1
+
+# Row marker dc_filter_frame writes for a row served from the cache.
+ROW_SERVED = 255
 
 
 @dataclass(frozen=True)
@@ -80,54 +97,149 @@ class DenyHit:
         return self.retry_after_ns // NS_PER_SEC
 
 
-class _Entry:
-    __slots__ = ("tat", "emission", "tolerance", "increment", "limit",
-                 "expiry")
-
-    def __init__(self, tat, emission, tolerance, increment, limit, expiry):
-        self.tat = tat
-        self.emission = emission
-        self.tolerance = tolerance
-        self.increment = increment
-        self.limit = limit
-        self.expiry = expiry  # the bucket's true expiry (writer's TTL)
+_LIB = None
 
 
-# A key's last observed allowed write is a plain (tat, tol, seq) tuple:
-# exact new TAT + the writer's tolerance (=> true expiry), guarded by
-# dispatch order.  A tuple, not a class — one record is allocated per
-# engine-decided allowed row, on the serving path.
-_REC_TAT, _REC_TOL, _REC_SEQ = 0, 1, 2
+def _lib():
+    global _LIB
+    if _LIB is None:
+        from ..native import deny_build_error, get_deny_lib
+
+        _LIB = get_deny_lib()
+        if _LIB is None:
+            raise RuntimeError(
+                f"native deny cache unavailable: {deny_build_error()}"
+            )
+    return _LIB
 
 
-def _derive_scalar(max_burst: int, count_per_period: int, period: int):
-    """(emission_ns, tolerance_ns) via the limiter's exact pipeline, or
-    None for invalid params — scalar wrapper over tpu.limiter
-    derive_params so cached math can never drift from the kernel's."""
-    from ..tpu.limiter import derive_params
+def _ptr(a: Optional[np.ndarray]):
+    return None if a is None else a.ctypes.data
 
-    emission, tolerance, invalid = derive_params(
-        [max_burst], [count_per_period], [period]
+
+def _key_bytes(key) -> Optional[bytes]:
+    if isinstance(key, str):
+        try:
+            return key.encode("utf-8", "surrogateescape")
+        except UnicodeEncodeError:
+            return None
+    if isinstance(key, (bytes, bytearray, memoryview)):
+        return bytes(key)
+    return None
+
+
+def _clamp(v: int) -> int:
+    """An int in i64 (a `now` or quantity beyond it compares the same
+    way against every bound the cache checks)."""
+    v = int(v)
+    return _I64_MIN if v < _I64_MIN else _I64_MAX if v > _I64_MAX else v
+
+
+def _fits(*vals) -> bool:
+    return all(_I64_MIN <= int(v) <= _I64_MAX for v in vals)
+
+
+def _frame(keys):
+    """(blob, offsets i64[m+1], rows) of the encodable keys, where rows
+    lists their positions in `keys`."""
+    enc = [_key_bytes(k) for k in keys]
+    rows = [i for i, b in enumerate(enc) if b is not None]
+    parts = enc if len(rows) == len(enc) else [enc[i] for i in rows]
+    offsets = np.zeros(len(parts) + 1, np.int64)
+    if parts:
+        np.cumsum([len(b) for b in parts], out=offsets[1:])
+    return b"".join(parts), offsets, rows
+
+
+def _param_rows(cols, n: int) -> tuple:
+    """i64[n, 4] from four per-row columns, and a bool[n] of rows whose
+    values do not fit i64 (None when all do)."""
+    try:
+        return (
+            np.ascontiguousarray(
+                np.stack([np.asarray(c, np.int64) for c in cols], axis=1)
+            ).reshape(n, 4),
+            None,
+        )
+    except (OverflowError, TypeError, ValueError):
+        pass
+    out = np.zeros((n, 4), np.int64)
+    bad = np.zeros(n, bool)
+    for i, row in enumerate(zip(*cols)):
+        if _fits(*row):
+            out[i] = [int(v) for v in row]
+        else:
+            bad[i] = True
+    return out, bad
+
+
+class FrameFilter(NamedTuple):
+    """One wire frame through the front tier (see filter_frame)."""
+
+    status: np.ndarray      # u8[n]: 0 miss, ROW_SERVED, or a shed status
+    vals: np.ndarray        # i64[n, 5] wire reply values of served rows
+    miss_idx: np.ndarray    # i64[m] rows the device decides
+    miss_frame: Optional[tuple]  # (blob, offsets, params) of those rows
+    n_hits: int
+    n_stale: int
+    shed_peek: int
+    shed_consume: int
+    hit_first: np.ndarray   # first row of each distinct served key
+    hit_count: np.ndarray   # its served rows
+
+
+def filter_frame(cache, blob, offsets, params, budgets, now_ns,
+                 st_deadline: int, st_overloaded: int,
+                 admit_consume: bool = True,
+                 admit_peek: bool = True) -> FrameFilter:
+    """Run one captured wire frame (keys as blob + offsets, params
+    i64[n, 4], deadline budgets i64[n]) through the deny cache (`cache`,
+    or None for none) and the window's two admission answers, in ONE
+    native call: expired rows answer `st_deadline`, cached denials are
+    served, rows of a refused class answer `st_overloaded`, and the rest
+    compact into the miss frame the device decides.  Every miss row's
+    key holds an in-flight hold until observe_frame/fail_frame."""
+    lib = _lib()
+    n = len(offsets) - 1
+    offsets = np.ascontiguousarray(offsets, np.int64)
+    params = np.ascontiguousarray(params, np.int64)
+    budgets = np.ascontiguousarray(budgets, np.int64)
+    # Two workspaces carved into the outputs: each array's address
+    # costs a ctypes round trip (~1 us), which a frame of tens of rows
+    # would otherwise pay a dozen times.
+    ws = np.empty(13 * n + 7, np.int64)
+    vals = ws[: 5 * n].reshape(n, 5)
+    miss_params = ws[5 * n: 9 * n].reshape(n, 4)
+    miss_idx, hit_first, hit_count = ws[9 * n: 12 * n].reshape(3, n)
+    miss_off = ws[12 * n: 13 * n + 1]
+    counts = ws[13 * n + 1:]
+    bs = np.empty(n + len(blob) + 1, np.uint8)
+    status, miss_blob = bs[:n], bs[n:]
+    w, b = ws.ctypes.data, bs.ctypes.data
+    m = lib.dc_filter_frame(
+        cache._h if cache is not None else None, blob, offsets.ctypes.data,
+        n, params.ctypes.data, budgets.ctypes.data, _clamp(now_ns),
+        int(bool(admit_consume)) | (int(bool(admit_peek)) << 1),
+        st_deadline, st_overloaded, b, w, w + 8 * 9 * n, b + n,
+        w + 8 * 12 * n, w + 8 * 5 * n, w + 8 * 10 * n, w + 8 * 11 * n,
+        w + 8 * (13 * n + 1),
     )
-    if bool(invalid[0]):
-        return None
-    return int(emission[0]), int(tolerance[0])
-
-
-def _column(col):
-    """Normalize one bulk-lookup param column to a plain-int sequence.
-    numpy arrays convert wholesale (C-level, plain ints out); anything
-    else passes through — stray np.int64 elements in a list still hash
-    and compare equal to the int-keyed entries, just slower."""
-    tolist = getattr(col, "tolist", None)
-    return tolist() if tolist is not None else col
-
-
-# Serving traffic reuses a handful of parameter triples across millions
-# of requests; the numpy round trip per observe() would dominate the
-# cache's own cost.  Bound the memo so hostile param churn cannot grow
-# it without limit.
-_MEMO_CAP = 4096
+    if m == n:
+        miss_frame = (blob, offsets, params)
+    elif m:
+        miss_frame = (
+            miss_blob[: miss_off[m]].tobytes(),
+            miss_off[: m + 1],
+            miss_params[:m],
+        )
+    else:
+        miss_frame = None
+    n_hit_keys = int(counts[5])
+    return FrameFilter(
+        status, vals, miss_idx[:m], miss_frame, int(counts[0]),
+        int(counts[1]), int(counts[2]), int(counts[3]),
+        hit_first[:n_hit_keys], hit_count[:n_hit_keys],
+    )
 
 
 class DenyCache:
@@ -137,63 +249,66 @@ class DenyCache:
     def __init__(self, capacity: int = 65536) -> None:
         if capacity <= 0:
             raise ValueError("deny cache capacity must be positive")
-        self.capacity = capacity
-        self._lock = threading.Lock()
-        # (key, (mb, cpp, period, q)) -> _Entry, insertion-ordered for
-        # O(1) FIFO eviction at capacity.
-        self._entries: dict = {}
-        # key -> set of param tuples with live entries (O(1) invalidation).
-        self._by_key: dict = {}
-        # key -> (tat, tol, seq) write record (bounded to `capacity`
-        # keys, FIFO-ish eviction).
-        self._records: dict = {}
-        # key -> in-flight engine request count: while any same-key
-        # request is being decided, lookups must miss (the in-flight
-        # request may be allowed and mutate the bucket under us).
-        self._inflight: dict = {}
-        self._seq = 0
-        # (mb, cpp, period) -> (emission, tolerance) | None, memoized.
-        self._param_memo: dict = {}
-        # Raw counters; the FrontTier facade mirrors them into Metrics.
-        self.hits = 0
-        self.stale_evictions = 0
+        self._lib = _lib()
+        self._h = self._lib.dc_create(int(capacity))
 
-    def _derive(self, mb, cpp, period):
-        """Memoized _derive_scalar (callers hold self._lock)."""
-        k = (mb, cpp, period)
-        try:
-            return self._param_memo[k]
-        except KeyError:
-            pass
-        if len(self._param_memo) >= _MEMO_CAP:
-            self._param_memo.clear()
-        d = self._param_memo[k] = _derive_scalar(mb, cpp, period)
-        return d
+    def __del__(self):
+        h = getattr(self, "_h", None)
+        if h:
+            self._lib.dc_destroy(h)
+            self._h = None
 
     # ------------------------------------------------------------------ #
 
+    @property
+    def capacity(self) -> int:
+        """Entries (and write records) kept; live-settable — the control
+        plane's `deny_cache.capacity` actuator moves it.  Shrinking
+        evicts one oldest item per later insertion."""
+        return int(self._lib.dc_capacity(self._h))
+
+    @capacity.setter
+    def capacity(self, value: int) -> None:
+        if int(value) <= 0:
+            raise ValueError("deny cache capacity must be positive")
+        self._lib.dc_set_capacity(self._h, int(value))
+
+    def _counters(self) -> np.ndarray:
+        out = np.zeros(4, np.int64)
+        self._lib.dc_counters(self._h, _ptr(out))
+        return out
+
     def __len__(self) -> int:
-        return len(self._entries)
+        return int(self._counters()[0])
+
+    @property
+    def hits(self) -> int:
+        """Denials served so far."""
+        return int(self._counters()[2])
+
+    @property
+    def stale_evictions(self) -> int:
+        """Entries dropped because their proven window or their bucket's
+        TTL lapsed."""
+        return int(self._counters()[3])
 
     def next_seq(self) -> int:
         """Dispatch-order stamp: call once per launch window, *before*
         dispatch, and pass to observe() so late-arriving results from a
         concurrent transport can't roll a write record backwards."""
-        with self._lock:
-            self._seq += 1
-            return self._seq
+        return int(self._lib.dc_next_seq(self._h))
 
     def begin_inflight(self, key) -> None:
-        with self._lock:
-            self._inflight[key] = self._inflight.get(key, 0) + 1
+        """While any same-key request is being decided, lookups must
+        miss (it may be allowed and mutate the bucket)."""
+        k = _key_bytes(key)
+        if k is not None:
+            self._lib.dc_inflight(self._h, k, len(k), 1)
 
     def end_inflight(self, key) -> None:
-        with self._lock:
-            n = self._inflight.get(key, 0) - 1
-            if n <= 0:
-                self._inflight.pop(key, None)
-            else:
-                self._inflight[key] = n
+        k = _key_bytes(key)
+        if k is not None:
+            self._lib.dc_inflight(self._h, k, len(k), -1)
 
     # ------------------------------------------------------------------ #
 
@@ -202,50 +317,32 @@ class DenyCache:
         """Serve an exact denial, or None (engine decides).
 
         Misses when no entry, when any same-key request is in flight, or
-        when `now_ns` has left the proven window (stale entries evict)."""
-        if now_ns < 0:
-            # Pre-epoch clocks take the oracle's normalize_now_ns
-            # wall-clock fallback — not reproducible here; let the
-            # engine decide.
+        when `now_ns` has left the proven window (stale entries evict),
+        and for pre-epoch clocks (the oracle's wall-clock fallback is
+        not reproducible here)."""
+        k = _key_bytes(key)
+        if k is None or not _fits(
+            max_burst, count_per_period, period, quantity
+        ):
             return None
-        k = (key, (int(max_burst), int(count_per_period), int(period),
-                   int(quantity)))
-        with self._lock:
-            e = self._entries.get(k)
-            if e is None:
-                return None
-            if key in self._inflight:
-                return None
-            allow_at = e.tat + e.increment - e.tolerance
-            horizon = min(allow_at, e.tat + e.tolerance, e.expiry)
-            if now_ns >= horizon:
-                self._evict(k)
-                self.stale_evictions += 1
-                return None
-            room = now_ns + e.tolerance - e.tat
-            remaining = room // e.emission if room >= 0 else 0
-            self.hits += 1
-            return DenyHit(
-                limit=e.limit,
-                remaining=remaining,
-                reset_after_ns=e.tat + e.tolerance - now_ns,
-                retry_after_ns=allow_at - now_ns,
-            )
+        out = np.empty(4, np.int64)
+        if not self._lib.dc_lookup(
+            self._h, k, len(k), int(max_burst), int(count_per_period),
+            int(period), int(quantity), _clamp(now_ns), _ptr(out),
+        ):
+            return None
+        limit, remaining, reset, retry = out.tolist()
+        return DenyHit(limit, remaining, reset, retry)
 
     def lookup_window(self, keys, max_burst, count_per_period, period,
                       quantity, now_ns, mark_inflight: bool = True):
-        """Bulk lookup for one serving window (shared timestamp): one
-        lock acquisition and one exact-denial computation per *distinct*
-        (key, params, q) combo — under abuse traffic a window repeats a
-        handful of combos thousands of times, so the common row costs a
-        single dict probe instead of the full per-call path.
+        """Bulk lookup for one serving window (shared timestamp), in one
+        native call.
 
         The window memo is exact BECAUSE the timestamp is shared: a
         served denial is identical for every repeat (denials don't
-        mutate), and once a combo misses, its repeats must keep missing
-        (the miss row is about to reach the engine and may mutate the
-        bucket) — the memo's miss sentinel is the in-window equivalent
-        of the in-flight guard.
+        mutate), and once a combo misses, its repeats keep missing (the
+        miss row is about to reach the engine and may mutate the bucket).
 
         `max_burst`/`count_per_period`/`period`/`quantity` are per-row
         sequences; `keys` is a sequence of normalized keys.  Returns
@@ -256,187 +353,102 @@ class DenyCache:
         one (observe_window/end_inflight) after the engine decides."""
         n = len(keys)
         out = [None] * n
-        if now_ns < 0:
-            if mark_inflight:
-                for key in keys:
-                    self.begin_inflight(key)
+        if n == 0:
             return out, 0
-        _MISS = False  # sentinel distinct from any hit tuple
-        memo: dict = {}
-        memo_get = memo.get
-        entries_get = self._entries.get
-        inflight = self._inflight
-        n_hits = 0
-        stale = 0
-        # Normalize the param columns ONCE: numpy's C-level tolist()
-        # yields plain ints (~12 ns/element), where per-row indexing +
-        # int() in the loop costs ~an order of magnitude more — at 90 %
-        # hit rates this loop IS the serving path's cost.
-        mb_c = _column(max_burst)
-        cpp_c = _column(count_per_period)
-        per_c = _column(period)
-        q_c = _column(quantity)
-        # Serving windows routinely share ONE param config across every
-        # row (per-route limits); verifying that is one C-level count()
-        # pass per column (~15 ns/element), and it collapses the hot
-        # loop to a bare key-string dict probe — no per-row tuple
-        # allocation at all.  A non-uniform window (the wire protocol
-        # allows per-request params) takes the general per-row path.
-        uniform = False
-        if n > 32:
-            try:
-                uniform = (
-                    mb_c.count(mb_c[0]) == n
-                    and cpp_c.count(cpp_c[0]) == n
-                    and per_c.count(per_c[0]) == n
-                    and q_c.count(q_c[0]) == n
-                )
-            except (AttributeError, TypeError):
-                uniform = False
-        inflight_get = inflight.get
-        with self._lock:
-            if uniform:
-                pq = (mb_c[0], cpp_c[0], per_c[0], q_c[0])
-                for i, key in enumerate(keys):
-                    r = memo_get(key)
-                    if r is None:
-                        kt = (key, pq)
-                        e = entries_get(kt)
-                        r = _MISS
-                        if e is not None and key not in inflight:
-                            tat = e.tat
-                            tol = e.tolerance
-                            allow_at = tat + e.increment - tol
-                            horizon = min(allow_at, tat + tol, e.expiry)
-                            if now_ns >= horizon:
-                                self._evict(kt)
-                                stale += 1
-                            else:
-                                room = now_ns + tol - tat
-                                r = (
-                                    e.limit,
-                                    room // e.emission if room >= 0 else 0,
-                                    tat + tol - now_ns,
-                                    allow_at - now_ns,
-                                )
-                        memo[key] = r
-                        if r is _MISS and mark_inflight:
-                            inflight[key] = inflight_get(key, 0) + 1
-                    elif r is _MISS and mark_inflight:
-                        inflight[key] = inflight_get(key, 0) + 1
-                    if r is not _MISS:
-                        out[i] = r
-                        n_hits += 1
-                self.hits += n_hits
-                self.stale_evictions += stale
-                return out, n_hits
-            for i, (key, mb, cpp, per, q) in enumerate(
-                zip(keys, mb_c, cpp_c, per_c, q_c)
-            ):
-                kt = (key, (mb, cpp, per, q))
-                r = memo_get(kt)
-                if r is None:
-                    e = entries_get(kt)
-                    r = _MISS
-                    if e is not None and key not in inflight:
-                        tat = e.tat
-                        tol = e.tolerance
-                        allow_at = tat + e.increment - tol
-                        horizon = min(allow_at, tat + tol, e.expiry)
-                        if now_ns >= horizon:
-                            self._evict(kt)
-                            stale += 1
-                        else:
-                            room = now_ns + tol - tat
-                            r = (
-                                e.limit,
-                                room // e.emission if room >= 0 else 0,
-                                tat + tol - now_ns,
-                                allow_at - now_ns,
-                            )
-                    memo[kt] = r
-                    if r is _MISS and mark_inflight:
-                        inflight[key] = inflight_get(key, 0) + 1
-                elif r is _MISS and mark_inflight:
-                    inflight[key] = inflight_get(key, 0) + 1
-                if r is not _MISS:
-                    out[i] = r
-                    n_hits += 1
-            self.hits += n_hits
-            self.stale_evictions += stale
+        blob, offsets, rows = _frame(keys)
+        params, bad = _param_rows(
+            (max_burst, count_per_period, period, quantity), n
+        )
+        if bad is not None:
+            keep = [i for i in rows if not bad[i]]
+            if len(keep) != len(rows):
+                blob, offsets, _ = _frame([keys[i] for i in keep])
+                rows = keep
+        m = len(rows)
+        if m == 0:
+            return out, 0
+        if m != n:
+            params = params[rows]
+        hit = np.empty(m, np.uint8)
+        vals = np.empty((m, 4), np.int64)
+        stale = np.zeros(1, np.int64)
+        n_hits = int(self._lib.dc_lookup_window(
+            self._h, blob, _ptr(offsets), m, _ptr(params), _clamp(now_ns),
+            int(bool(mark_inflight)), _ptr(hit), _ptr(vals), _ptr(stale),
+        ))
+        if n_hits:
+            for j in np.flatnonzero(hit).tolist():
+                out[rows[j]] = tuple(vals[j].tolist())
         return out, n_hits
 
     # ------------------------------------------------------------------ #
 
     def observe_window(self, rows, now_ns, seq) -> None:
-        """Bulk observe for one decided window: one lock acquisition for
-        all rows, releasing each row's in-flight hold (the bulk twin of
-        observe + end_inflight).  `rows` is an iterable of (key,
-        max_burst, count_per_period, period, quantity, allowed, cur_ns)
-        tuples in arrival order; cur_ns may be None (allowed rows then
-        invalidate without certifying; denied rows are skipped)."""
-        now_ns = int(now_ns)
-        inflight = self._inflight
-        inflight_get = inflight.get
-        inflight_pop = inflight.pop
-        records = self._records
-        records_get = records.get
-        records_pop = records.pop
-        by_key_pop = self._by_key.pop
-        entries_pop = self._entries.pop
-        derive = self._derive
-        now_ok = 0 <= now_ns < _BOUND
-        cap = self.capacity
-        # Rows should carry plain Python ints (callers .tolist() their
-        # result planes); stray numpy scalars still hash/compare equal,
-        # just slower.  The allowed branch is _observe_allowed inlined:
-        # under abuse traffic the engine's miss stream is dominated by
-        # allowed cold-tail rows, so this loop body IS the observe
-        # path's cost.
-        with self._lock:
-            for key, mb, cpp, period, q, allowed, cur_ns in rows:
-                if allowed:
-                    # The one mutating outcome: cached denials die.
-                    s = by_key_pop(key, None)
-                    if s is not None:
-                        for pq in s:
-                            entries_pop((key, pq), None)
-                    rec = records_get(key)
-                    if rec is not None and seq < rec[_REC_SEQ]:
-                        pass  # stale cross-transport observation
-                    elif q < 1 or cur_ns is None or not now_ok:
-                        # Unquantified / uncertified write: poison.
-                        records_pop(key, None)
-                    else:
-                        derived = derive(mb, cpp, period)
-                        if derived is not None:
-                            em, tol = derived
-                            if (
-                                0 < em < _BOUND
-                                and 0 <= tol < _BOUND
-                                and 0 <= cur_ns < _BOUND
-                            ):
-                                # Pop-then-reinsert: a refreshed key
-                                # moves to the dict's end so FIFO
-                                # eviction tracks last-write age, not
-                                # first-insertion — hot keys must not
-                                # be the first evicted.
-                                records_pop(key, None)
-                                records[key] = (cur_ns, tol, seq)
-                                if len(records) > cap:
-                                    records_pop(next(iter(records)))
-                            else:
-                                records_pop(key, None)
-                elif cur_ns is not None:
-                    self._observe_denied(
-                        key, int(mb), int(cpp), int(period), int(q),
-                        now_ns, seq, cur_ns, None, None,
-                    )
-                m = inflight_get(key, 0) - 1
-                if m <= 0:
-                    inflight_pop(key, None)
-                else:
-                    inflight[key] = m
+        """Bulk observe for one decided window, in one native call,
+        releasing each row's in-flight hold (the bulk twin of observe +
+        end_inflight).  `rows` is an iterable of (key, max_burst,
+        count_per_period, period, quantity, allowed, cur_ns) tuples in
+        arrival order; cur_ns may be None (allowed rows then invalidate
+        without certifying; denied rows are skipped)."""
+        rows = [r for r in rows if _key_bytes(r[0]) is not None]
+        n = len(rows)
+        if n == 0:
+            return
+        keys, mb, cpp, per, q, allowed, cur = zip(*rows)
+        blob, offsets, _ = _frame(keys)
+        params, bad = _param_rows((mb, cpp, per, q), n)
+        allowed_u8 = np.fromiter(
+            (bool(a) for a in allowed), np.uint8, count=n
+        )
+        has_cur = np.fromiter(
+            (c is not None for c in cur), np.uint8, count=n
+        )
+        cur_i64 = np.fromiter(
+            (_clamp(c) if c is not None else 0 for c in cur), np.int64,
+            count=n,
+        )
+        if bad is not None:
+            # Beyond i64: an uncertifiable (quantity-0) row — allowed
+            # still invalidates and poisons, denied is a no-op.
+            params[bad] = 0
+        self._observe(blob, offsets, n, params, allowed_u8, cur_i64,
+                      has_cur, now_ns, seq)
+
+    def _observe(self, blob, offsets, n, params, allowed, cur, has_cur,
+                 now_ns, seq) -> None:
+        self._lib.dc_observe_window(
+            self._h, blob, _ptr(offsets), n, _ptr(params), _ptr(allowed),
+            _ptr(cur), _ptr(has_cur), _clamp(now_ns), _clamp(seq),
+        )
+
+    def observe_frame(self, frame, status, allowed, cur_ns, now_ns,
+                      seq) -> None:
+        """observe_window for a device-decided miss frame (blob,
+        offsets, params) and its result planes: a non-OK row never
+        reached the table, so it only releases its hold; without a
+        cur plane allowed rows invalidate and denials cannot certify."""
+        blob, offsets, params = frame
+        n = len(offsets) - 1
+        ok = np.asarray(status) == 0
+        allowed_u8 = (ok & (np.asarray(allowed) != 0)).astype(np.uint8)
+        cur = has_cur = None
+        if cur_ns is not None:
+            cur = np.ascontiguousarray(cur_ns, np.int64)
+            has_cur = ok.astype(np.uint8)
+        self._observe(
+            blob, np.ascontiguousarray(offsets, np.int64), n,
+            np.ascontiguousarray(params, np.int64), allowed_u8, cur,
+            has_cur, now_ns, seq,
+        )
+
+    def fail_frame(self, frame) -> None:
+        """fail_window for a miss frame whose launch failed after its
+        writes may have committed."""
+        blob, offsets, _params = frame
+        self._lib.dc_fail_window(
+            self._h, blob, _ptr(np.ascontiguousarray(offsets, np.int64)),
+            len(offsets) - 1,
+        )
 
     def release_window(self, keys) -> None:
         """Release in-flight holds for rows that never reached a launch
@@ -444,14 +456,11 @@ class DenyCache:
         launch may have COMMITTED before the failure, use fail_window —
         a plain release would leave entries/records that an unobserved
         write has invalidated."""
-        inflight = self._inflight
-        with self._lock:
-            for key in keys:
-                m = inflight.get(key, 0) - 1
-                if m <= 0:
-                    inflight.pop(key, None)
-                else:
-                    inflight[key] = m
+        blob, offsets, rows = _frame(keys)
+        if rows:
+            self._lib.dc_release_window(
+                self._h, blob, _ptr(offsets), len(rows)
+            )
 
     def fail_window(self, keys) -> None:
         """A launch failed after its writes may have committed (e.g. a
@@ -459,17 +468,9 @@ class DenyCache:
         conservatively drop the key's cached denials and write record —
         an unobserved allow may have moved the TAT, so neither can
         certify exactness any longer."""
-        inflight = self._inflight
-        records_pop = self._records.pop
-        with self._lock:
-            for key in keys:
-                m = inflight.get(key, 0) - 1
-                if m <= 0:
-                    inflight.pop(key, None)
-                else:
-                    inflight[key] = m
-                self._invalidate_key(key)
-                records_pop(key, None)
+        blob, offsets, rows = _frame(keys)
+        if rows:
+            self._lib.dc_fail_window(self._h, blob, _ptr(offsets), len(rows))
 
     # ------------------------------------------------------------------ #
 
@@ -483,132 +484,25 @@ class DenyCache:
         TAT for denied rows); full-ns results recover the same values
         from `reset_after_ns`/`retry_after_ns` instead.  Rows offering
         neither still invalidate on allowed — they just can't certify."""
-        q = int(quantity)
-        now_ns = int(now_ns)
-        mb = int(max_burst)
-        cpp = int(count_per_period)
-        period = int(period)
-        with self._lock:
-            if allowed:
-                self._observe_allowed(
-                    key, mb, cpp, period, q, now_ns, seq, cur_ns,
-                    reset_after_ns,
-                )
-            else:
-                self._observe_denied(
-                    key, mb, cpp, period, q, now_ns, seq, cur_ns,
-                    reset_after_ns, retry_after_ns,
-                )
-
-    def _observe_allowed(self, key, mb, cpp, period, q, now_ns, seq,
-                         cur_ns, reset_after_ns):
-        # The one mutating outcome: every cached denial for the key dies.
-        self._invalidate_key(key)
-        rec = self._records.get(key)
-        if rec is not None and seq < rec[_REC_SEQ]:
-            return  # stale cross-transport observation; record is newer
-        if q < 1:
-            # A quantity-0 probe may or may not refresh the TTL on a
-            # given backend; an unquantified write poisons the record.
-            self._records.pop(key, None)
+        k = _key_bytes(key)
+        if k is None:
             return
-        derived = self._derive(mb, cpp, period)
-        if derived is None:
-            return
-        em, tol = derived
-        if not (0 < em < _BOUND and 0 <= tol < _BOUND
-                and 0 <= now_ns < _BOUND):
-            self._records.pop(key, None)
-            return
-        if cur_ns is not None:
-            tat = int(cur_ns)
-        elif reset_after_ns is not None and 0 < int(reset_after_ns) < _BOUND:
-            # allowed => current_tat = new_tat and reset = new_tat+tol-now
-            tat = now_ns + int(reset_after_ns) - tol
-        else:
-            self._records.pop(key, None)
-            return
-        if not 0 <= tat < _BOUND:
-            self._records.pop(key, None)
-            return
-        # Pop-then-reinsert so FIFO eviction tracks last-write age —
-        # a refreshed hot key must not stay parked at the front of
-        # the eviction queue.
-        self._records.pop(key, None)
-        self._records[key] = (tat, tol, seq)
-        while len(self._records) > self.capacity:
-            self._records.pop(next(iter(self._records)))
-
-    def _observe_denied(self, key, mb, cpp, period, q, now_ns, seq,
-                        cur_ns, reset_after_ns, retry_after_ns):
-        if not 1 <= q <= _I32_MAX:
-            # q=0 denials are no-ops; q > i32::MAX could push `remaining`
-            # past where the wire tiers saturate and the ns planes don't.
-            return
-        rec = self._records.get(key)
-        if rec is None:
-            return  # last write not observed exactly: can't certify
-        derived = self._derive(mb, cpp, period)
-        if derived is None:
-            return
-        em, tol = derived
-        if not (0 < em < _BOUND and 0 < tol < _BOUND
-                and 0 <= now_ns < _BOUND):
-            return
-        inc = em * q
-        if inc >= _BOUND:
-            return
-        if cur_ns is not None:
-            tat = int(cur_ns)
-        elif (
-            reset_after_ns is not None
-            and retry_after_ns is not None
-            and 0 < int(reset_after_ns) < _BOUND
-            and 0 < int(retry_after_ns) < _BOUND
-            # Both planes must reconstruct the SAME TAT or something
-            # saturated/clamped along the way.
-            and now_ns + int(reset_after_ns) - tol
-            == now_ns + int(retry_after_ns) - inc + tol
-        ):
-            tat = now_ns + int(reset_after_ns) - tol
-        else:
-            return
-        if tat != rec[_REC_TAT]:
-            return  # an unobserved write intervened (or first touch)
-        rec_tol = rec[_REC_TOL]
-        if not 0 <= tat < _BOUND or rec_tol >= _BOUND:
-            return
-        if now_ns >= tat + inc - tol:
-            return  # inconsistent with a denial; refuse
-        k = (key, (mb, cpp, period, q))
-        if k not in self._entries and len(self._entries) >= self.capacity:
-            self._evict(next(iter(self._entries)))
-        self._entries.pop(k, None)
-        self._entries[k] = _Entry(
-            tat, em, tol, inc, int(mb), tat + rec_tol
+        if not _fits(max_burst, count_per_period, period):
+            # Beyond i64: treated as an uncertifiable (quantity-0) row.
+            max_burst = count_per_period = period = quantity = 0
+        flags = (
+            (cur_ns is not None)
+            | (reset_after_ns is not None) << 1
+            | (retry_after_ns is not None) << 2
         )
-        self._by_key.setdefault(key, set()).add(k[1])
-
-    # ------------------------------------------------------------------ #
-
-    def _evict(self, k) -> None:
-        self._entries.pop(k, None)
-        key, pq = k
-        s = self._by_key.get(key)
-        if s is not None:
-            s.discard(pq)
-            if not s:
-                del self._by_key[key]
-
-    def _invalidate_key(self, key) -> None:
-        s = self._by_key.pop(key, None)
-        if s is not None:
-            for pq in s:
-                self._entries.pop((key, pq), None)
-
-    def invalidate_key(self, key) -> None:
-        with self._lock:
-            self._invalidate_key(key)
+        self._lib.dc_observe(
+            self._h, k, len(k), int(max_burst), int(count_per_period),
+            int(period), _clamp(quantity), _clamp(now_ns), int(bool(allowed)),
+            _clamp(seq), flags,
+            _clamp(cur_ns) if cur_ns is not None else 0,
+            _clamp(reset_after_ns) if reset_after_ns is not None else 0,
+            _clamp(retry_after_ns) if retry_after_ns is not None else 0,
+        )
 
     def prewarm(self, keys) -> int:
         """Refresh confirmed-hot keys against FIFO eviction (the
@@ -619,48 +513,72 @@ class DenyCache:
         untouched: nothing is created, only re-ordered; a key with no
         certified state is a no-op.  Returns the number of refreshed
         keys."""
-        n = 0
-        with self._lock:
-            records = self._records
-            entries = self._entries
-            for key in keys:
-                touched = False
-                rec = records.pop(key, None)
-                if rec is not None:
-                    records[key] = rec
-                    touched = True
-                for pq in self._by_key.get(key, ()):
-                    k = (key, pq)
-                    e = entries.pop(k, None)
-                    if e is not None:
-                        entries[k] = e
-                        touched = True
-                if touched:
-                    n += 1
-        return n
+        blob, offsets, rows = _frame(keys)
+        if not rows:
+            return 0
+        return int(
+            self._lib.dc_prewarm(self._h, blob, _ptr(offsets), len(rows))
+        )
 
     def on_sweep(self, now_ns: int) -> int:
         """Expiry sweep ran on the table at `now_ns`: drop every entry
         whose bucket it vacated (the slot is gone even for a later
         regressed clock).  Returns the eviction count."""
-        with self._lock:
-            dead = [
-                k for k, e in self._entries.items() if e.expiry <= now_ns
-            ]
-            for k in dead:
-                self._evict(k)
-            for key in [
-                key for key, r in self._records.items()
-                if r[_REC_TAT] + r[_REC_TOL] <= now_ns
-            ]:
-                self._records.pop(key, None)
-            self.stale_evictions += len(dead)
-            return len(dead)
+        return int(self._lib.dc_sweep(self._h, _clamp(now_ns)))
 
     def clear(self) -> None:
         """Full invalidation: snapshot restore / param-surface changes —
         anything that rewrites bucket state out from under the cache."""
-        with self._lock:
-            self._entries.clear()
-            self._by_key.clear()
-            self._records.clear()
+        self._lib.dc_clear(self._h)
+
+    # ------------------------------------------------------------------ #
+    # Read-only views (tests, debugging): keys come back as the stored
+    # bytes, lists in eviction order (oldest first).
+
+    def record(self, key):
+        """The key's write record (tat, tol, seq), or None."""
+        k = _key_bytes(key)
+        if k is None:
+            return None
+        out = np.empty(3, np.int64)
+        if not self._lib.dc_record(self._h, k, len(k), _ptr(out)):
+            return None
+        return tuple(out.tolist())
+
+    def _export(self, what: int, width: int) -> list:
+        rows_n = ctypes.c_int64()
+        bytes_n = ctypes.c_int64()
+        while True:
+            self._lib.dc_export(
+                self._h, what, None, 0, None, None, 0,
+                ctypes.byref(rows_n), ctypes.byref(bytes_n),
+            )
+            rows, size = rows_n.value, bytes_n.value
+            blob = np.empty(max(size, 1), np.uint8)
+            off = np.empty(rows + 1, np.int64)
+            vals = np.empty((max(rows, 1), width), np.int64)
+            got = self._lib.dc_export(
+                self._h, what, _ptr(blob), size, _ptr(off), _ptr(vals),
+                rows, ctypes.byref(rows_n), ctypes.byref(bytes_n),
+            )
+            if got >= 0:
+                break
+        raw = blob.tobytes()
+        off = off.tolist()
+        return [
+            (raw[off[i]: off[i + 1]], tuple(vals[i].tolist()))
+            for i in range(got)
+        ]
+
+    def records(self) -> list:
+        """[(key, (tat, tol, seq))], least recently written first."""
+        return self._export(0, 3)
+
+    def entries(self) -> list:
+        """[(key, (mb, cpp, period, q, tat, em, tol, inc, limit,
+        expiry))], oldest insertion first."""
+        return self._export(1, 10)
+
+    def inflight(self) -> dict:
+        """{key: holds} for every key with an in-flight hold."""
+        return {k: v[0] for k, v in self._export(2, 1)}

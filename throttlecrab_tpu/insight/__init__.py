@@ -363,14 +363,21 @@ class InsightTier:
                     self._host_denied += 1
                     self.sketch.record(key, 1)
 
-    def record_front_denied(self, keys) -> None:
+    def record_front_denied(self, keys, counts=None) -> None:
         """Deny-cache-served denials (no device launch), keys
         normalized: counted into totals and the hot-key sketch so the
-        cache absorbing an attack doesn't hide it from /stats."""
+        cache absorbing an attack doesn't hide it from /stats.  With
+        `counts`, keys are distinct and counts[i] is key i's served
+        rows (one sketch fold of that count)."""
         with self._lock:
-            for key in keys:
-                self._front_denied += 1
-                self.sketch.record(key, 1)
+            if counts is None:
+                for key in keys:
+                    self._front_denied += 1
+                    self.sketch.record(key, 1)
+                return
+            for key, c in zip(keys, counts):
+                self._front_denied += c
+                self.sketch.record(key, c)
 
     def _totals_locked(self) -> tuple:
         """(allowed, denied) across every serving path: device

@@ -1,9 +1,12 @@
-"""ctypes bridge to the C++ keymap (native/keymap.cpp).
+"""ctypes bridge to the C++ libraries: the keymap (native/keymap.cpp),
+the wire server (native/wire_server.cpp) and the front tier's deny cache
+(native/deny_cache.cpp).
 
-Compiles the shared library on first use with g++ (cached next to the
+Compiles each shared library on first use with g++ (cached next to the
 source); falls back cleanly if no toolchain is available — the limiter then
-uses the pure-Python keymap.  No pybind11: the ABI is a small C surface and
-the batch arrays travel as numpy pointers.
+uses the pure-Python keymap, and the front tier builds only its admission
+half.  No pybind11: the ABI is a small C surface and the batch arrays
+travel as numpy pointers.
 """
 
 from __future__ import annotations
@@ -67,8 +70,14 @@ def _compile(src: Path, out: Path, extra=()) -> Optional[str]:
     flags = os.environ.get(
         "THROTTLECRAB_NATIVE_CFLAGS", "-O3 -march=native"
     ).split()
+    # The headers beside the sources (shared by more than one library)
+    # are part of every build's content.
+    headers = b"".join(
+        h.read_bytes() for h in sorted(src.parent.glob("*.h"))
+    )
     digest = hashlib.sha256(
-        src.read_bytes() + " ".join(flags).encode() + _host_key()
+        src.read_bytes() + headers + " ".join(flags).encode()
+        + _host_key()
     ).hexdigest()
     stamp = out.with_suffix(out.suffix + ".sha256")
     if (
@@ -255,6 +264,77 @@ def wire_build_error() -> Optional[str]:
     """The wire-server build failure (with compiler stderr), or None."""
     get_wire_lib()
     return _ws_error
+
+
+# ------------------------------------------------------------------ #
+# Deny-cache library (native/deny_cache.cpp): the front tier's exact
+# negative-decision cache, wrapped by front/deny_cache.py.
+
+_DC_SRC = _REPO_ROOT / "native" / "deny_cache.cpp"
+_DC_LIB = _REPO_ROOT / "native" / "build" / "libtkdeny.so"
+_dc_lib: Optional[ctypes.CDLL] = None
+_dc_error: Optional[str] = None
+
+
+def _build_deny() -> Optional[ctypes.CDLL]:
+    global _dc_error
+    _dc_error = _compile(_DC_SRC, _DC_LIB, extra=("-pthread",))
+    if _dc_error is not None:
+        return None
+    lib = ctypes.CDLL(str(_DC_LIB))
+    h = p = ctypes.c_void_p
+    i64 = ctypes.c_int64
+    s = ctypes.c_char_p
+    sigs = {
+        "dc_create": (h, [i64]),
+        "dc_destroy": (None, [h]),
+        "dc_set_capacity": (None, [h, i64]),
+        "dc_capacity": (i64, [h]),
+        "dc_counters": (None, [h, p]),
+        "dc_next_seq": (i64, [h]),
+        "dc_inflight": (None, [h, s, i64, i64]),
+        "dc_lookup": (i64, [h, s, i64, i64, i64, i64, i64, i64, p]),
+        "dc_lookup_window": (i64, [h, s, p, i64, p, i64, i64, p, p, p]),
+        "dc_observe_window": (None, [h, s, p, i64, p, p, p, p, i64, i64]),
+        "dc_observe": (
+            None, [h, s, i64, i64, i64, i64, i64, i64, i64, i64, i64,
+                   i64, i64, i64],
+        ),
+        "dc_release_window": (None, [h, s, p, i64]),
+        "dc_fail_window": (None, [h, s, p, i64]),
+        "dc_prewarm": (i64, [h, s, p, i64]),
+        "dc_sweep": (i64, [h, i64]),
+        "dc_clear": (None, [h]),
+        "dc_record": (i64, [h, s, i64, p]),
+        "dc_export": (i64, [h, i64, p, i64, p, p, i64, p, p]),
+        "dc_filter_frame": (
+            i64, [h, s, p, i64, p, p, i64, i64, i64, i64, p, p, p, p, p,
+                  p, p, p, p],
+        ),
+    }
+    for name, (res, args) in sigs.items():
+        fn = getattr(lib, name)
+        fn.restype = res
+        fn.argtypes = args
+    return lib
+
+
+def get_deny_lib() -> Optional[ctypes.CDLL]:
+    global _dc_lib
+    with _lock:
+        if _dc_lib is None and _dc_error is None:
+            _dc_lib = _build_deny()
+        return _dc_lib
+
+
+def deny_available() -> bool:
+    return get_deny_lib() is not None
+
+
+def deny_build_error() -> Optional[str]:
+    """The deny-cache build failure (with compiler stderr), or None."""
+    get_deny_lib()
+    return _dc_error
 
 
 # Flag bits returned by NativeKeyMap.prepare_batch (keymap.cpp TK_PREP_*).

@@ -313,13 +313,13 @@ class Metrics:
         with self._lock:
             self.front_deny_hits += n
 
-    def record_front_shed(self, peek: bool) -> None:
-        """A request shed by admission control, by priority class."""
+    def record_front_shed(self, peek: bool, n: int = 1) -> None:
+        """`n` requests shed by admission control, by priority class."""
         with self._lock:
             if peek:
-                self.front_shed_peek += 1
+                self.front_shed_peek += n
             else:
-                self.front_shed_consume += 1
+                self.front_shed_consume += n
 
     def record_front_stale(self, n: int) -> None:
         """Deny-cache entries evicted because their proven window (or

@@ -27,7 +27,8 @@ from typing import Optional
 
 import numpy as np
 
-from ..front import STATUS_OVERLOADED
+from ..front import STATUS_OVERLOADED, filter_frame
+from ..front.deny_cache import ROW_SERVED
 from ..native import get_wire_lib
 from ..runtime import health_suffix
 from ..tpu.limiter import (
@@ -314,147 +315,45 @@ class NativeRedisTransport:
 
     def _front_filter(self, batch, now_ns, depth):
         """Run one captured frame through the front tier BEFORE batch
-        prep: deny-cache hits get their exact denial filled in,
-        admission-shed rows get the overload status, and only the
-        surviving misses are compacted into a (blob, offsets, params)
-        frame for the device.  The cache is consulted first — a hit
-        never occupies the queue admission protects, so shedding it
-        would turn a free exact denial into a 503 under exactly the
-        abuse traffic this tier exists for.  Miss keys are marked
-        in-flight until observed.  Rows whose deadline budget expired
-        before pop are shed first (status 6) — the client stopped
-        waiting, so neither a cached denial nor a device row helps."""
-        blob, offsets, params, gen, fd, budgets = batch
-        n = len(offsets) - 1
-        front = self.front
-        admission = front.admission
-        deny = front.deny_cache
-        status_pre = np.zeros(n, np.uint8)
-        hit_vals = np.zeros((n, 5), np.int64)
-        NS = 1_000_000_000
-        q_col = params[:, 3].tolist()
-        miss_pos: list = []
-        miss_keys: list = []
-        miss_norm: list = []
-        if deny is not None:
-            raw = [blob[offsets[i] : offsets[i + 1]] for i in range(n)]
-            # The cache's key identity is the limiter keymap's: with a
-            # str-keyed (python) keymap the wire's bytes decode exactly
-            # like the transports do; with a bytes keymap (native, the
-            # serving default) normalization is the identity and costs
-            # nothing.
-            if front.bytes_keys:
-                norm = raw
-            else:
-                norm = [k.decode("utf-8", "surrogateescape") for k in raw]
-            # Bulk lookup, one lock + one computation per distinct
-            # (key, params, q) combo; misses are marked in-flight until
-            # _observe_plan releases them.
-            rows, _ = front.lookup_window(
-                norm, params[:, 0], params[:, 1], params[:, 2],
-                params[:, 3], now_ns,
-            )
-            shed_norm: list = []
-            for i in range(n):
-                if budgets[i] < 0:
-                    status_pre[i] = STATUS_DEADLINE
-                    if rows[i] is None:
-                        # The bulk lookup marked this miss in-flight;
-                        # it will never be observed, so free the hold.
-                        shed_norm.append(norm[i])
-                    continue
-                hit = rows[i]
-                if hit is not None:
-                    status_pre[i] = 255  # marker: row served from cache
-                    hit_vals[i] = (
-                        0, hit[0], hit[1], hit[2] // NS, hit[3] // NS,
-                    )
-                    continue
-                if admission is not None and not front.admit(
-                    depth, q_col[i] == 0
-                ):
-                    status_pre[i] = STATUS_OVERLOADED
-                    shed_norm.append(norm[i])
-                    continue
-                miss_pos.append(i)
-                miss_keys.append(raw[i])
-                miss_norm.append(norm[i])
-            if shed_norm:
-                # Shed rows never reach the engine: release the
-                # in-flight holds the bulk lookup took for them.
-                front.release_window(shed_norm)
-        else:
-            # Admission-only config: no cache, so the per-row key
-            # slices/decodes are never needed — shed or pass through.
-            for i in range(n):
-                if budgets[i] < 0:
-                    status_pre[i] = STATUS_DEADLINE
-                elif admission is not None and not front.admit(
-                    depth, q_col[i] == 0
-                ):
-                    status_pre[i] = STATUS_OVERLOADED
-                else:
-                    miss_pos.append(i)
-            if len(miss_pos) != n:
-                miss_keys = [
-                    blob[offsets[i] : offsets[i + 1]] for i in miss_pos
-                ]
-        miss_idx = np.asarray(miss_pos, np.int64)
-        m = len(miss_pos)
-        if m == n:
-            miss_frame = (blob, offsets, params)
-            miss_params = params
-        elif m:
-            offsets_m = np.zeros(m + 1, np.int64)
-            np.cumsum([len(k) for k in miss_keys], out=offsets_m[1:])
-            miss_params = np.ascontiguousarray(params[miss_idx])
-            miss_frame = (b"".join(miss_keys), offsets_m, miss_params)
-        else:
-            miss_frame = None
-            miss_params = None
-        return {
-            "batch": batch,
-            "n": n,
-            "status_pre": status_pre,
-            "hit_vals": hit_vals,
-            "miss_idx": miss_idx,
-            "miss_norm": miss_norm,
-            "miss_frame": miss_frame,
-            "miss_params": miss_params,
-        }
+        prep, in one native call (FrontTier.filter_frame): deny-cache
+        hits get their exact denial filled in, admission-shed rows get
+        the overload status, and only the surviving misses are compacted
+        into a (blob, offsets, params) frame for the device.  The cache
+        is consulted first — a hit never occupies the queue admission
+        protects, so shedding it would turn a free exact denial into a
+        503 under exactly the abuse traffic this tier exists for.  Miss
+        keys are held in flight until _observe_plan.  Rows whose deadline
+        budget expired before pop are shed first (status 6) — the client
+        stopped waiting, so neither a cached denial nor a device row
+        helps."""
+        blob, offsets, params, _gen, _fd, budgets = batch
+        f = self.front.filter_frame(
+            blob, offsets, params, budgets, now_ns, depth,
+            STATUS_DEADLINE, STATUS_OVERLOADED,
+        )
+        return self._plan(batch, f)
 
     def _deadline_plan(self, batch):
         """No-front-tier twin of _front_filter for batches carrying
         expired rows: expired budgets answer status 6, live rows
-        compact into the device frame.  Same plan shape _merge_plan
-        consumes (no hits, no norm keys to observe)."""
-        blob, offsets, params, gen, fd, budgets = batch
-        n = len(offsets) - 1
-        expired = budgets < 0
-        status_pre = np.where(expired, STATUS_DEADLINE, 0).astype(np.uint8)
-        miss_idx = np.flatnonzero(~expired)
-        m = len(miss_idx)
-        if m == n:
-            miss_frame = (blob, offsets, params)
-            miss_params = params
-        elif m:
-            keys = [blob[offsets[i] : offsets[i + 1]] for i in miss_idx]
-            offsets_m = np.zeros(m + 1, np.int64)
-            np.cumsum([len(k) for k in keys], out=offsets_m[1:])
-            miss_params = np.ascontiguousarray(params[miss_idx])
-            miss_frame = (b"".join(keys), offsets_m, miss_params)
-        else:
-            miss_frame = None
-            miss_params = None
+        compact into the device frame."""
+        blob, offsets, params, _gen, _fd, budgets = batch
+        f = filter_frame(
+            None, blob, offsets, params, budgets, 0, STATUS_DEADLINE,
+            STATUS_OVERLOADED,
+        )
+        return self._plan(batch, f)
+
+    @staticmethod
+    def _plan(batch, f):
+        """The per-frame plan _merge_plan and _observe_plan consume."""
         return {
             "batch": batch,
-            "n": n,
-            "status_pre": status_pre,
-            "hit_vals": np.zeros((n, 5), np.int64),
-            "miss_idx": miss_idx,
-            "miss_norm": [],
-            "miss_frame": miss_frame,
-            "miss_params": miss_params,
+            "n": len(f.status),
+            "status_pre": f.status,
+            "hit_vals": f.vals,
+            "miss_idx": f.miss_idx,
+            "miss_frame": f.miss_frame,
         }
 
     def _merge_plan(self, plan, res):
@@ -464,7 +363,7 @@ class NativeRedisTransport:
         n = plan["n"]
         out = np.zeros((n, 5), np.int64)
         status = plan["status_pre"].copy()
-        served = status == 255  # cache-hit marker → status OK on the wire
+        served = status == ROW_SERVED  # cache hit → status OK on the wire
         if bool(served.any()):
             out[served] = plan["hit_vals"][served]
             status[served] = 0
@@ -487,34 +386,11 @@ class NativeRedisTransport:
 
     def _observe_plan(self, plan, res, now_ns, seq) -> None:
         """Feed the miss rows' engine decisions to the deny cache and
-        release their in-flight holds, in bulk (one lock for the whole
-        window) — the native twin of engine._observe_window."""
-        front = self.front
-        norm = plan["miss_norm"]
-        if res is None:
-            # Post-launch failure: the writes may have committed, so
-            # drop the keys' cached denials/write records along with
-            # their holds.
-            front.deny_cache.fail_window(norm)
-            return
-        params = plan["miss_params"]
-        cur = getattr(res, "cur_ns", None)
-        # One C-level tolist() per plane; per-element int(arr[i]) costs
-        # ~10x and this loop runs once per device-decided request.
-        status = res.status.tolist()
-        allowed_col = res.allowed.tolist()
-        cur_l = cur.tolist() if cur is not None else None
-        params_l = params.tolist()
-        rows = []
-        for i, key in enumerate(norm):
-            ok = status[i] == 0
-            allowed = ok and bool(allowed_col[i])
-            # Without the exact observed TAT (cur tier), a denial can't
-            # certify — but an allowed row must still invalidate.
-            c = cur_l[i] if (ok and cur_l is not None) else None
-            p = params_l[i]
-            rows.append((key, p[0], p[1], p[2], p[3], allowed, c))
-        front.observe_window(rows, now_ns, seq)
+        release their in-flight holds, in one native call per frame —
+        the native twin of engine._observe_window.  After a post-launch
+        failure (res None) the writes may have committed, so the keys'
+        cached denials and write records go with their holds."""
+        self.front.observe_frame(plan["miss_frame"], res, now_ns, seq)
 
     def _decide_frames(self, frames, now_ns):
         """Decide a window of (blob, offsets, params) frames on the

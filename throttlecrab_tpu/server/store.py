@@ -156,11 +156,20 @@ def create_front_tier(config, metrics, limiter):
             "THROTTLECRAB_FRONT_DENY_CACHE=0 to silence",
             config.front_deny_cache,
         )
-    deny = (
-        DenyCache(config.front_deny_cache)
-        if config.front_deny_cache > 0 and certifiable
-        else None
-    )
+    deny = None
+    if config.front_deny_cache > 0 and certifiable:
+        from ..native import deny_available, deny_build_error
+
+        if deny_available():
+            deny = DenyCache(config.front_deny_cache)
+        else:
+            log.warning(
+                "front-tier deny cache configured "
+                "(THROTTLECRAB_FRONT_DENY_CACHE=%d) but its native library "
+                "is unavailable (%s); building admission control only",
+                config.front_deny_cache,
+                (deny_build_error() or "no build error").splitlines()[0],
+            )
     admission = None
     if config.front_max_pending or config.front_max_wait_us:
         admission = AdmissionController(
