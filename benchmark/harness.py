@@ -9,7 +9,10 @@ in.  Then, on the main thread again, the plain reference decides every
 request the server decided, in the order and at the clock readings of
 the server's flight recorder, and each client's replies are compared
 with it; every recorded clock reading has to fall while a request of its
-key was in flight, by the clients' own clocks.
+key was in flight, by the clients' own clocks.  Where the configuration's
+keys belong to tenants, the server's per-tenant allowed and denied
+counters have to have grown by the reference's decisions, tenant by
+tenant.
 """
 
 from __future__ import annotations
@@ -74,6 +77,56 @@ def scrape(port: int) -> dict:
             except ValueError:
                 pass
     return out
+
+
+TENANT_COUNTERS = ("throttlecrab_tpu_tenant_allowed",
+                   "throttlecrab_tpu_tenant_denied")
+
+
+def scrape_tenants(port: int) -> dict:
+    """{tenant: [allowed, denied]}: the labelled per-tenant samples of
+    GET /metrics (`name{tenant="t07"} 123`); a tenant the server has not
+    counted yet is absent."""
+    out = {}
+    for line in http_get(port, "/metrics").splitlines():
+        name, brace, rest = line.partition('{tenant="')
+        if brace and name in TENANT_COUNTERS:
+            label, _, value = rest.rpartition('"} ')
+            out.setdefault(label, [0, 0])[TENANT_COUNTERS.index(name)] = \
+                int(float(value))
+    return out
+
+
+def tenant_totals(config: dict, keys, allowed) -> np.ndarray:
+    """[T, 2] (allowed, denied) decisions per tenant of the requests of
+    key indices `keys` (-1, a key of another form, left out)."""
+    t = workload.tenant_of(config, keys)
+    allowed = np.asarray(allowed) != 0
+    known = np.asarray(keys) >= 0
+    T = workload.n_tenants(config)
+    return np.stack([np.bincount(t[known & allowed], minlength=T),
+                     np.bincount(t[known & ~allowed], minlength=T)], axis=1)
+
+
+def tenant_count_gap(config: dict, before: dict, after: dict,
+                     ref: np.ndarray) -> int:
+    """Sum over tenants of |program's counted (allowed, denied) - the
+    reference's|: the program's counts are the growth of its per-tenant
+    samples from `before` to `after`.  The server's registry names at
+    most THROTTLECRAB_TENANT_MAX - 1 tenants and counts the rest in one
+    overflow bucket, so the tenants that no sample names are compared,
+    summed, with the samples of every other label summed."""
+    grown = {k: np.subtract(after.get(k, (0, 0)), before.get(k, (0, 0)))
+             for k in set(before) | set(after)}
+    gap = 0
+    unnamed = np.zeros(2, np.int64)
+    for t, name in enumerate(workload.tenant_names(config)):
+        if name in grown:
+            gap += int(np.abs(grown.pop(name) - ref[t]).sum())
+        else:
+            unnamed += ref[t]
+    rest = sum(grown.values(), np.zeros(2, np.int64))
+    return gap + int(np.abs(rest - unnamed).sum())
 
 
 # --------------------------------------------------------------------- #
@@ -218,15 +271,25 @@ class CompileWatch:
             return sorted({str(f) for t, f in self.events if t >= t_ns})
 
 
+def _pow2(n: int) -> int:
+    return 1 << max(int(n) - 1, 0).bit_length()
+
+
+def _pow2_range(lo: int, hi: int) -> list:
+    """The powers of two from `lo` to `hi`, both powers of two."""
+    return [1 << i for i in range(lo.bit_length() - 1, hi.bit_length())]
+
+
 def prewarm_launches(config) -> int:
-    """Compile every launch the native driver can make for this server
-    configuration -- K_pad sub-batches (powers of two up to the scan
-    depth) of W lanes (powers of two from the limiter's MIN_PAD up to the
-    batch size), in the two output tiers a window with the deny cache
-    takes (`cur`, and the compact planes) -- by running each once on a
-    scratch limiter built from the same configuration.  The jit cache
-    they fill is the process's, so the server's own launches find every
-    shape compiled; the server's table is untouched.  Which (K_pad, W) a
+    """Compile every launch the native driver can make for this
+    single-device server configuration -- K_pad sub-batches (powers of
+    two up to the scan depth) of W lanes (powers of two from the
+    limiter's MIN_PAD up to the batch size), in the two output tiers a
+    window with the deny cache takes (`cur`, and the compact planes) --
+    by running each once on a scratch limiter built from the same
+    configuration.  The single-device programs are jitted once per
+    process, so the jit cache they fill serves the server's own launches
+    too; the server's table is untouched.  Which (K_pad, W) a
     window makes depends on backlog and deny-cache hits, so traffic alone
     reaches the rare ones only after minutes.  Returns the launches."""
     import jax
@@ -237,13 +300,9 @@ def prewarm_launches(config) -> int:
     limiter = create_limiter(config)
     now = time.time_ns()
     n = 0
-    depths = [1 << i for i in range(
-        max(config.max_scan_depth - 1, 0).bit_length() + 1)]
-    widths = [1 << i for i in range(
-        max(limiter.MIN_PAD - 1, 0).bit_length(),
-        max(config.batch_size - 1, 0).bit_length() + 1)]
-    for depth in depths:
-        for width in widths:
+    for depth in _pow2_range(1, _pow2(config.max_scan_depth)):
+        for width in _pow2_range(_pow2(limiter.MIN_PAD),
+                                 _pow2(config.batch_size)):
             for compact in ("cur", True):
                 jax.block_until_ready(limiter.table.check_many_packed(
                     np.zeros((depth, width, PACK_WIDTH), np.int32),
@@ -253,6 +312,60 @@ def prewarm_launches(config) -> int:
                 n += 1
     del limiter
     gc.collect()
+    return n
+
+
+# The output tiers a sharded launch may take (ShardedTpuRateLimiter's
+# w32 -> cur -> 4-plane ladder, chosen per launch from its lanes and the
+# table's high-water marks).  A configuration whose limits certify one
+# tier for every launch names it under "sharded_tiers", and only that
+# tier is compiled: each tier costs a program per launch shape.
+SHARDED_TIERS = ("w32", "cur", True)
+
+
+def sharded_launch_shapes(config, n_shards: int, min_pad: int) -> list:
+    """(K, B) of every launch the native driver can make on a sharded
+    table: a window of one batch is one `check_batch` of B lanes per
+    shard (K 1), a window of K > 1 batches one `check_many` scan of K
+    padded to a power of two.  B is max(min_pad, the next power of two
+    of the widest shard's rows): any power of two up to the batch size
+    for one batch; for a scan at least the widest shard of a full batch,
+    since the driver scans only behind a full first batch (whose rows
+    the benchmark's traffic makes all valid)."""
+    top = max(_pow2(config.batch_size), min_pad)
+    shapes = [(1, b) for b in _pow2_range(min_pad, top)]
+    full = max(_pow2(-(-config.batch_size // n_shards)), min_pad)
+    for k in _pow2_range(2, _pow2(config.max_scan_depth)):
+        shapes += [(k, b) for b in _pow2_range(full, top)]
+    return shapes
+
+
+def prewarm_sharded(limiter, config, tiers=SHARDED_TIERS) -> int:
+    """Compile every launch of a sharded limiter (`sharded_launch_shapes`,
+    in each of `tiers`) by running each once on the SERVER'S
+    OWN table, with every lane idle: the sharded table builds its jitted
+    steps per instance, so a scratch limiter's compiles would not reach
+    the server's.  An idle lane is decided by nothing and written
+    nowhere, and the table-level launch folds no counter, so the state,
+    the global and the per-tenant counters stay as they were.  Returns
+    the launches."""
+    import jax
+
+    table = limiter.table
+    n = 0
+    for k, b in sharded_launch_shapes(config, table.n_shards,
+                                      limiter.MIN_PAD):
+        lanes = (table.n_shards, b) if k == 1 else (table.n_shards, k, b)
+        zeros = np.zeros(lanes, np.int64)
+        idle = np.zeros(lanes, bool)
+        launch, now = ((table.check_batch, 0) if k == 1 else
+                       (table.check_many, np.zeros(k, np.int64)))
+        for tier in tiers:
+            out = launch(zeros, zeros, ~idle, zeros, zeros, zeros, idle,
+                         now, with_degen=False, compact=tier,
+                         params_cur_safe=True)
+            jax.block_until_ready([x for x in out if x is not None])
+            n += 1
     return n
 
 
@@ -276,6 +389,7 @@ class Run:
         self.workdir = Path(tempfile.mkdtemp(prefix="tcbench-"))
         self.error = None
         self.stopping = threading.Event()
+        self.tenants_before = None
 
     # -- set-up on the main thread ------------------------------------- #
 
@@ -289,6 +403,13 @@ class Run:
             os.environ["JAX_PLATFORMS"] = "cpu"
             os.environ["THROTTLECRAB_PLATFORM"] = "cpu"
             os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+            flags = os.environ.get("XLA_FLAGS", "")
+            if self.cell.chips > 1 and \
+                    "xla_force_host_platform_device_count" not in flags:
+                os.environ["XLA_FLAGS"] = (
+                    f"{flags} --xla_force_host_platform_device_count="
+                    f"{self.cell.chips}"
+                ).strip()
         for k, v in self.config.get("server_env", {}).items():
             os.environ[k] = str(v)
         import jax
@@ -315,7 +436,9 @@ class Run:
         self.resp_port, self.http_port = free_port(), free_port()
         argv = server_argv(self.config, self.resp_port, self.http_port)
         t = time.monotonic()
-        self.prewarmed = prewarm_launches(Config.from_env_and_args(argv))
+        boot = Config.from_env_and_args(argv)
+        # A sharded table is prewarmed as the server builds it (_building).
+        self.prewarmed = 0 if boot.shards > 1 else prewarm_launches(boot)
         self.prewarm_s = time.monotonic() - t
         # The keys reach the table as users restore theirs: a snapshot
         # read at boot.
@@ -351,20 +474,41 @@ class Run:
         """The server, on this (the main) thread, until SIGINT."""
         import asyncio
 
-        from throttlecrab_tpu.server.__main__ import run_server
+        import throttlecrab_tpu.server.__main__ as server_main
 
+        create = server_main.create_limiter
+        server_main.create_limiter = self._building(create)
         worker = threading.Thread(target=self._drive, daemon=True)
         worker.start()
         try:
-            asyncio.run(run_server(self.server_config))
+            asyncio.run(server_main.run_server(self.server_config))
         except KeyboardInterrupt:  # SIGINT before the server's handler
             pass
         finally:
+            server_main.create_limiter = create
             self.stopping.set()
             if worker.is_alive():
                 self.clients.stop()  # unblocks a worker waiting on them
             worker.join(timeout=120)
             self.clients.stop()
+
+    def _building(self, create):
+        """The server's own limiter factory, wrapped: the harness notes the
+        devices the table spans and compiles a sharded table's launches
+        on it (prewarm_sharded), before the server restores its keys."""
+
+        def build(config):
+            limiter = create(config)
+            self.devices = list(limiter.table.state.devices())
+            if config.shards > 1:
+                t = time.monotonic()
+                self.prewarmed = prewarm_sharded(
+                    limiter, config,
+                    self.config.get("sharded_tiers", SHARDED_TIERS))
+                self.prewarm_s += time.monotonic() - t
+            return limiter
+
+        return build
 
     # -- the harness thread -------------------------------------------- #
 
@@ -410,6 +554,10 @@ class Run:
         if self.recorder is None and not args.no_recorder:
             raise RunError("the flight recorder is not armed")
         self.server_config.snapshot_path = ""  # no write-back at exit
+        # The per-tenant counters before any request: their growth is
+        # what the run decided.
+        self.tenants_before = (scrape_tenants(self.http_port)
+                               if workload.n_tenants(self.config) else None)
         self.offsets = [clock.offset_ns()]
         self.clients.wait_ready()
         start = time.monotonic_ns() + 50_000_000
@@ -437,6 +585,8 @@ class Run:
             jax.profiler.stop_trace()
         m1 = scrape(self.http_port)
         self.conns = self.clients.results()
+        if self.tenants_before is not None:  # every reply is in
+            self.tenants_after = scrape_tenants(self.http_port)
         self.offsets.append(clock.offset_ns())
         # A compile that opened in the window may close after it.
         self.compiled_in_window = self.compiles.since(t0)
@@ -522,6 +672,21 @@ class Run:
             ok_c = np.ones(len(rk), bool)
         wrong, unmatched = gcra.compare(keys_c[ok_c], replies_c[ok_c],
                                         rk, ref)
+        tenants = None
+        if self.tenants_before is not None:
+            # The per-tenant counters against the reference's decisions,
+            # tenant by tenant: those the server counted, or the
+            # control's own where it stands in the program's place.
+            before, after = self.tenants_before, self.tenants_after
+            if self.args.control:
+                names = workload.tenant_names(self.config)
+                totals = tenant_totals(self.config, rec_keys,
+                                       replies_c[:, 0])
+                before, after = {}, dict(zip(names, totals.tolist()))
+            tenants = tenant_count_gap(
+                self.config, before, after,
+                tenant_totals(self.config, rec_keys, ref[:, 0]),
+            )
         checks = {
             "wrong_replies": {"value": wrong, "limit": 0},
             "unmatched_decisions": {"value": unmatched + unknown,
@@ -531,6 +696,8 @@ class Run:
                                                           rec_now),
                               "limit": 0},
         }
+        if tenants is not None:
+            checks["tenant_counts"] = {"value": tenants, "limit": 0}
         self.reference_s = ref_s
         self.decisions = len(rk)
         return checks
@@ -628,11 +795,10 @@ def recorded(recorder, config) -> tuple:
     return idx, (np.concatenate(now) if now else np.zeros(0, np.int64))
 
 
-def memory_peak() -> int:
-    import jax
-
-    stats_ = jax.devices()[0].memory_stats() or {}
-    return int(stats_.get("peak_bytes_in_use", 0))
+def memory_peak(devices) -> int:
+    """The peak bytes in use of the fullest of `devices`."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
 
 
 def main(argv=None, t_start: float = None) -> int:
@@ -665,7 +831,7 @@ def main(argv=None, t_start: float = None) -> int:
         run.serve()
         if run.error is not None:
             raise run.error
-        peak = memory_peak()
+        peak = memory_peak(run.devices)
         gc.collect()
         checks = run.check()
         ctx = run.context()

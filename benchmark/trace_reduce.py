@@ -9,6 +9,8 @@ the numbers the per-layer readers and the breakdown take.
 - device_ops: the device operations that took most time, each named
   by its program and the HLO name of the op ("jit_f/%fusion.3", the
   text before " = " of the event's name);
+- ops: every device operation's interval and name, per device, for a
+  reader that sums a kind of operation (`op_seconds`);
 - idle_gaps: the longest gaps between device operations, each named by
   the host event that spans it (its thread, then its name).
 """
@@ -78,7 +80,7 @@ def reduce(profile, top: int = 10) -> dict:
         elif plane.name.startswith("/host:CPU"):
             hosts.append(plane)
     busy, op_time, modules = [], {}, {}
-    gaps = []
+    gaps, op_spans = [], []
     for plane in devices:
         starts, ends, ops, mods = [], [], [], []
         for line in plane.lines:
@@ -98,8 +100,11 @@ def reduce(profile, top: int = 10) -> dict:
                     mods.append((s, s + int(ev.duration_ns), name))
         if not starts:
             continue
-        for (s, _), e, name in zip(ops, ends, _op_names(ops, mods)):
+        names = _op_names(ops, mods)
+        for (s, _), e, name in zip(ops, ends, names):
             op_time[name] = op_time.get(name, 0) + e - s
+        op_spans.append((names, np.asarray(starts, np.int64),
+                         np.asarray(ends, np.int64)))
         ms, me = _union(np.asarray(starts, np.int64),
                         np.asarray(ends, np.int64))
         busy.append(int((me - ms).sum()))
@@ -121,6 +126,7 @@ def reduce(profile, top: int = 10) -> dict:
                 :top
             ]
         ],
+        "ops": op_spans,
     }
     gaps.sort(key=lambda g: g[0] - g[1])
     gaps = gaps[:top]
@@ -178,3 +184,21 @@ def module_seconds(reduced: dict, patterns) -> tuple:
             t += v["seconds"]
             n += v["count"]
     return (t, n) if n else None
+
+
+def op_seconds(reduced: dict, patterns):
+    """Device seconds, averaged over the devices, in which an operation
+    whose `program/op` name matches any of the regular expressions
+    `patterns` ran: the union of their intervals, so operations that
+    overlap count once.  None where none matched."""
+    rx = [re.compile(p) for p in patterns]
+    total = found = 0
+    for names, starts, ends in reduced.get("ops", []):
+        hit = np.fromiter((any(r.search(n) for r in rx) for n in names),
+                          bool, len(names))
+        found += int(hit.sum())
+        ms, me = _union(starts[hit], ends[hit])
+        total += int((me - ms).sum())
+    if not found:
+        return None
+    return total / max(len(reduced["ops"]), 1) / 1e9

@@ -30,20 +30,58 @@ def n_keys(config: dict) -> int:
     return int(config["keys"]["count"])
 
 
-def key_bytes(config: dict, idx) -> list:
-    """Fixed-width keys: prefix + zero-padded decimal index."""
+TENANT_DIGITS = 2
+TENANT_DELIM = b":"  # the server's default THROTTLECRAB_TENANT_DELIM
+
+
+def n_tenants(config: dict) -> int:
+    """The configuration's tenant count T, or 0 where its keys have no
+    tenant namespace."""
+    return int(config["keys"].get("tenants", 0))
+
+
+def tenant_of(config: dict, idx) -> np.ndarray:
+    """The tenant of each key index: index i is tenant i mod T."""
+    return np.asarray(idx, np.int64) % n_tenants(config)
+
+
+def tenant_names(config: dict) -> list:
+    """Tenant t's namespace as the server names it: the key's text
+    before the first delimiter, `<prefix><t, 2 digits>`."""
     prefix = config["keys"]["prefix"]
-    digits = int(config["keys"]["digits"])
-    fmt = f"{prefix}%0{digits}d".encode()
-    return [fmt % int(i) for i in idx]
+    return [f"{prefix}{t:0{TENANT_DIGITS}d}" for t in range(n_tenants(config))]
+
+
+def _key_form(config: dict) -> tuple:
+    """(prefix bytes, tenant count, digits): a key is prefix + decimal
+    index, or, with T tenants, prefix + tenant + ':' + user, each
+    field zero-padded, so every key of a configuration has one width."""
+    keys = config["keys"]
+    tenants = n_tenants(config)
+    if not 0 <= tenants <= 10 ** TENANT_DIGITS:
+        raise ValueError(f"keys.tenants {tenants} outside 0..100")
+    return keys["prefix"].encode(), tenants, int(keys["digits"])
+
+
+def key_bytes(config: dict, idx) -> list:
+    """Fixed-width keys: prefix + zero-padded decimal index; with T
+    tenants, index i is user i div T of tenant i mod T,
+    `<prefix><tenant>:<user>` (`t07:12345`)."""
+    prefix, tenants, digits = _key_form(config)
+    if not tenants:
+        fmt = prefix + f"%0{digits}d".encode()
+        return [fmt % int(i) for i in idx]
+    fmt = prefix + f"%0{TENANT_DIGITS}d".encode() + TENANT_DELIM \
+        + f"%0{digits}d".encode()
+    return [fmt % (int(i) % tenants, int(i) // tenants) for i in idx]
 
 
 def parse_keys(config: dict, keys: list) -> np.ndarray:
     """Key bytes back to indices, vectorised; -1 for a key that is not
     of the configuration's form."""
-    prefix = config["keys"]["prefix"].encode()
-    digits = int(config["keys"]["digits"])
-    width = len(prefix) + digits
+    prefix, tenants, digits = _key_form(config)
+    head = len(prefix) + (TENANT_DIGITS + 1 if tenants else 0)
+    width = head + digits
     out = np.full(len(keys), -1, np.int64)
     if not keys:
         return out
@@ -52,15 +90,26 @@ def parse_keys(config: dict, keys: list) -> np.ndarray:
     if not good:
         return out
     raw = np.frombuffer(b"".join(good), np.uint8).reshape(-1, width)
-    pre = np.frombuffer(prefix, np.uint8)
-    d = raw[:, len(prefix):].astype(np.int64) - 48
-    valid = (raw[:, : len(prefix)] == pre).all(axis=1) & (
-        (d >= 0) & (d <= 9)
-    ).all(axis=1)
-    val = d @ (10 ** np.arange(digits - 1, -1, -1, dtype=np.int64))
+    valid = (raw[:, : len(prefix)] == np.frombuffer(prefix, np.uint8)).all(
+        axis=1
+    )
+    val = _decimal(raw[:, head:], valid)
+    if tenants:
+        valid &= raw[:, head - 1] == TENANT_DELIM[0]
+        tenant = _decimal(raw[:, len(prefix): head - 1], valid)
+        valid &= tenant < tenants
+        val = val * tenants + tenant
     val = np.where(valid & (val < n_keys(config)), val, -1)
     out[np.flatnonzero(ok)] = val
     return out
+
+
+def _decimal(cols: np.ndarray, valid: np.ndarray) -> np.ndarray:
+    """The decimal number in each row of ASCII digit columns; clears
+    `valid` (in place) where a row holds anything but digits."""
+    d = cols.astype(np.int64) - 48
+    valid &= ((d >= 0) & (d <= 9)).all(axis=1)
+    return d @ (10 ** np.arange(cols.shape[1] - 1, -1, -1, dtype=np.int64))
 
 
 def limits(config: dict, idx) -> tuple:

@@ -363,3 +363,115 @@ def test_trace_reduction_on_a_v5e_trace():
     # Ops nest (a scan's loop holds its body's ops), so only each one
     # alone is bounded by the busy time.
     assert all(t <= red["busy_s"] for _, t in red["device_ops"])
+
+
+MESH = json.loads((ROOT / "benchmark/configs/tenants-mesh4.json").read_text())
+
+
+@pytest.mark.parametrize("name, digest, first, last", [
+    ("gw-1m", "b3a54240099537d94b4b1ce4f48d14eb"
+              "9f7908c1d7f70a06de4db8b55bc78b19", b"gw:0000000", b"gw:0999999"),
+    ("api-10k", "52f7cc25280311d51baee3eb02e8a807"
+                "572e8cc5b025b54fdd24d3aaadfbdd26", b"api:00000", b"api:09999"),
+])
+def test_keys_without_tenants_keep_their_bytes(name, digest, first, last):
+    """Every key of the configurations without tenants, byte for byte
+    as the benchmark made them before tenant keys existed."""
+    import hashlib
+
+    config = json.loads((ROOT / f"benchmark/configs/{name}.json").read_text())
+    keys = workload.key_bytes(config, range(workload.n_keys(config)))
+    assert (keys[0], keys[-1]) == (first, last)
+    assert hashlib.sha256(b"".join(keys)).hexdigest() == digest
+    assert workload.n_tenants(config) == 0
+
+
+def test_tenant_keys_round_trip():
+    n = workload.n_keys(MESH)
+    assert n == 64 * 100_000
+    idx = np.array([0, 1, 63, 64, 7 + 64 * 12345, n - 1])
+    keys = workload.key_bytes(MESH, idx)
+    assert keys[:4] == [b"t00:00000", b"t01:00000", b"t63:00000",
+                        b"t00:00001"]
+    assert keys[4] == b"t07:12345" and keys[5] == b"t63:99999"
+    assert workload.tenant_of(MESH, idx).tolist() == [0, 1, 63, 0, 7, 63]
+    names = workload.tenant_names(MESH)
+    assert len(names) == 64 and names[7] == "t07"
+    # Each key's namespace (the text before ':') is its tenant's name.
+    assert all(k.split(b":")[0].decode() == names[t] for k, t in
+               zip(keys, workload.tenant_of(MESH, idx)))
+    others = [b"t64:00000", b"t07-12345", b"u07:12345", b"t07:1234x",
+              b"t7:012345", b"gw:0000007"]
+    assert workload.parse_keys(MESH, keys + others).tolist() == (
+        idx.tolist() + [-1] * len(others))
+
+
+def test_tenant_count_gap_folds_the_overflow_bucket():
+    from benchmark.harness import tenant_count_gap, tenant_totals
+
+    config = {"keys": {"count": 12, "prefix": "t", "digits": 2,
+                       "tenants": 3}}
+    keys = np.array([0, 1, 2, 3, 4, 5, 0, -1])
+    allowed = np.array([1, 1, 0, 1, 0, 0, 0, 1])
+    ref = tenant_totals(config, keys, allowed)
+    assert ref.tolist() == [[2, 1], [1, 1], [0, 2]]  # the -1 key left out
+    before = {"t00": [5, 5]}
+    # t02 is counted in the overflow bucket; t00 grew by its reference.
+    after = {"t00": [7, 6], "t01": [1, 1], "~overflow": [0, 2]}
+    assert tenant_count_gap(config, before, after, ref) == 0
+    shifted = dict(after, t01=[2, 1])
+    assert tenant_count_gap(config, before, shifted, ref) == 1
+    lost = dict(after, **{"~overflow": [0, 1]})
+    assert tenant_count_gap(config, before, lost, ref) == 1
+    assert tenant_count_gap(config, {}, {}, ref) == 7
+
+
+def test_sharded_launch_shapes_cover_every_window():
+    from benchmark.harness import sharded_launch_shapes
+
+    config = type("C", (), {"batch_size": 4096, "max_scan_depth": 16})()
+    shapes = sharded_launch_shapes(config, 4, 16)
+    single = [b for k, b in shapes if k == 1]
+    assert single == [16, 32, 64, 128, 256, 512, 1024, 2048, 4096]
+    # A scan follows a full first batch: its widest shard holds at least
+    # a quarter of 4096 rows.
+    for k in (2, 4, 8, 16):
+        assert [b for kk, b in shapes if kk == k] == [1024, 2048, 4096]
+    assert len(shapes) == 21
+    one = type("C", (), {"batch_size": 100, "max_scan_depth": 1})()
+    assert sharded_launch_shapes(one, 4, 16) == [
+        (1, 16), (1, 32), (1, 64), (1, 128)]
+
+
+def _mesh_trace():
+    # Two chips: each runs one scan launch holding a while loop and an
+    # all-reduce of its counters; chip 1's all-reduce overlaps an
+    # all-gather for 10 ns.
+    def chip(n, ar_start):
+        return (f"/device:TPU:{n}", [
+            ("XLA Modules", [("jit_local(4)", 1000, 400)]),
+            ("XLA Ops", [("%while.2 = (s32[]) while(...)", 1000, 300),
+                         ("%all-reduce.1 = s64[3] all-reduce(...)",
+                          ar_start, 40),
+                         ("%all-gather.7 = s64[2,3] all-gather(...)",
+                          ar_start + 30, 20)]),
+        ])
+    return _Profile([chip(0, 1300), chip(1, 1350)])
+
+
+def test_collective_share_on_a_small_trace():
+    red = trace_reduce.reduce(_mesh_trace())
+    assert red["devices"] == 2
+    # Chip 0: [1000, 1350) busy, collectives [1300, 1350): 350 and 50 ns.
+    # Chip 1: [1000, 1300) and [1350, 1400): 350 busy, 50 collective.
+    assert red["busy_s"] == pytest.approx(350e-9)
+    share = spec.load_reader(
+        ROOT / "benchmark/metrics/mesh.collective_share.py")
+    assert trace_reduce.op_seconds(
+        red, share.COLLECTIVES) == pytest.approx(50e-9)
+    assert trace_reduce.op_seconds(red, (r"nothing",)) is None
+    ctx = type("Ctx", (), {"trace": red})()
+    assert share.read(ctx) == pytest.approx(100 * 50 / 350)
+    # One chip (or none): no mesh to read.
+    ctx.trace = trace_reduce.reduce(_synthetic())
+    assert share.read(ctx) is None
