@@ -13,7 +13,10 @@
 // Design:
 //   - open addressing, power-of-two bucket count, linear probing;
 //   - FNV-1a 64-bit hashing;
-//   - keys interned in an append-only arena (offset, len per entry);
+//   - keys interned in an arena (offset, len per entry), appended to on
+//     insert; a sweep's frees count the bytes they orphan, and once those
+//     outweigh the live keys' bytes the arena is compacted (only there,
+//     never on the insert or lookup path), so it follows the live keys;
 //   - slot free-list for sweep recycling;
 //   - per-batch segment tracking via a batch-stamp on each entry: no
 //     per-call allocation, O(1) per request.
@@ -52,6 +55,8 @@ struct KeyMap {
     std::vector<Entry> buckets;       // size is a power of two
     uint64_t mask = 0;
     std::vector<char> arena;          // interned key bytes
+    int64_t arena_dead = 0;           // bytes of freed keys still in it
+    int64_t arena_reserve = 0;        // the arena's capacity at creation
     std::vector<int32_t> free_slots;  // stack, low indices on top
     std::vector<int64_t> slot_bucket; // slot -> bucket index (-1 free)
     int64_t size = 0;                 // live keys
@@ -92,7 +97,28 @@ struct KeyMap {
         slot_count.assign(cap, 0);
         slot_last_pos.assign(cap, -1);
         slot_first_pos.assign(cap, -1);
-        arena.reserve(cap * 16);
+        arena_reserve = cap * 16;
+        arena.reserve(arena_reserve);
+    }
+
+    // Drop freed keys' bytes once they outweigh the live keys': copy
+    // every live key into a fresh arena of the creation reserve (or
+    // twice the live bytes, if more) and re-point its entry.  A scan of
+    // the buckets and a copy of the live bytes, after a sweep's frees.
+    void compact_arena() {
+        const int64_t live = static_cast<int64_t>(arena.size()) - arena_dead;
+        if (arena_dead <= live) return;
+        std::vector<char> fresh;
+        fresh.reserve(static_cast<size_t>(
+            arena_reserve > 2 * live ? arena_reserve : 2 * live));
+        for (Entry& e : buckets) {
+            if (e.key_off < 0) continue;
+            const char* src = arena.data() + e.key_off;
+            e.key_off = static_cast<int64_t>(fresh.size());
+            fresh.insert(fresh.end(), src, src + e.key_len);
+        }
+        arena.swap(fresh);
+        arena_dead = 0;
     }
 
     // Shared probe path for resolve / assemble / prepare: find the key's
@@ -714,6 +740,7 @@ int64_t tk_free_slots(void* h, const int32_t* slots, int64_t n) {
         if (b < 0) continue;  // not allocated
         // Backward-shift deletion keeps probe chains intact.
         uint64_t hole = static_cast<uint64_t>(b);
+        m->arena_dead += m->buckets[hole].key_len;
         m->buckets[hole] = Entry{};
         uint64_t j = (hole + 1) & m->mask;
         while (m->buckets[j].key_off >= 0) {
@@ -740,7 +767,17 @@ int64_t tk_free_slots(void* h, const int32_t* slots, int64_t n) {
         m->size--;
         freed++;
     }
+    if (freed) m->compact_arena();
     return freed;
+}
+
+// The key arena's bytes: out[0] in use (live and freed keys), out[1] of
+// freed keys not yet reclaimed, out[2] allocated.
+void tk_arena_stats(void* h, int64_t* out) {
+    const KeyMap* m = static_cast<KeyMap*>(h);
+    out[0] = static_cast<int64_t>(m->arena.size());
+    out[1] = m->arena_dead;
+    out[2] = static_cast<int64_t>(m->arena.capacity());
 }
 
 }  // extern "C"

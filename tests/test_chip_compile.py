@@ -153,3 +153,20 @@ def test_fused_knob_refused_for_the_chip(one_chip, monkeypatch):
     monkeypatch.setenv("THROTTLECRAB_PALLAS_FUSED", "1")
     with pytest.raises(RuntimeError, match="TPU v5 lite.*Mosaic"):
         pallas_fused.require_compiles(served_table(one_chip), K, B)
+
+
+def test_sweep_compiles_in_place(one_chip):
+    """The expiry sweep at churn-1m's 2,097,152 slots of insight-widened
+    rows: the table is donated and vacated in place, and only the
+    capacity's expired mask comes back beside it."""
+    from throttlecrab_tpu.tpu.kernel import sweep_expired_ins
+
+    capacity = 1 << 21
+    compiled = sweep_expired_ins.lower(
+        shape((), jnp.int64, one_chip),
+        shape((capacity + SCRATCH, INS_WIDTH), jnp.int32, one_chip),
+        capacity=capacity,
+    ).compile()
+    mem = compiled.memory_analysis()
+    # The 66 MiB table aliases its input; the rest is the 2 MiB mask.
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 2 * capacity

@@ -216,3 +216,76 @@ def test_sharded_expired_hits_ride_the_counters():
     lim.rate_limit_batch(keys, 5, 10, 10, 1, t0 + 10_000 * NS)
     assert lim.take_expired_hits() == 8
     assert lim.take_expired_hits() == 0  # drained
+
+
+def test_boot_compiles_the_sweep_before_serving():
+    """A server with a cleanup policy compiles its table's sweep at
+    boot: once it serves, its first sweep compiles nothing (JAX's own
+    compile events, as the benchmark's CompileWatch reads them), and the
+    boot's sweep freed no key.  The capacity is one no other test uses,
+    so no earlier compile in this process can stand in for the boot's."""
+    import asyncio
+    import os
+    import signal
+    import socket
+    import time
+
+    import jax
+
+    import throttlecrab_tpu.server.__main__ as server_main
+    from benchmark.harness import CompileWatch
+    from throttlecrab_tpu.server.config import Config
+
+    s = socket.socket()
+    s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]
+    s.close()
+    built = []
+    create = server_main.create_limiter
+
+    def capture(config):
+        built.append(create(config))
+        return built[-1]
+
+    async def health() -> bytes:
+        reader, writer = await asyncio.open_connection("127.0.0.1", port)
+        writer.write(b"GET /health HTTP/1.1\r\nHost: x\r\n"
+                     b"Connection: close\r\n\r\n")
+        await writer.drain()
+        raw = await reader.read(-1)
+        writer.close()
+        return raw
+
+    async def boot_then_sweep():
+        cfg = Config(http=True, http_host="127.0.0.1", http_port=port,
+                     store="adaptive", store_capacity=3000)
+        task = asyncio.create_task(server_main.run_server(cfg))
+        for _ in range(600):
+            if task.done():
+                task.result()  # surface boot failures
+            try:
+                if b"OK" in await health():
+                    break
+            except OSError:
+                await asyncio.sleep(0.05)
+        limiter = built[0]
+        assert len(limiter) == 0
+        watch = CompileWatch()
+        t0 = time.monotonic_ns()
+        try:
+            freed = limiter.sweep(time.time_ns())
+            compiled = watch.since(t0)
+        finally:
+            jax.monitoring.unregister_scalar_listener(watch._opened)
+            jax.monitoring.unregister_event_duration_listener(watch._closed)
+        os.kill(os.getpid(), signal.SIGINT)
+        await asyncio.wait_for(task, timeout=60)
+        return freed, compiled
+
+    server_main.create_limiter = capture
+    try:
+        freed, compiled = asyncio.run(boot_then_sweep())
+    finally:
+        server_main.create_limiter = create
+    assert freed == 0
+    assert compiled == []

@@ -135,3 +135,37 @@ def test_grow_keeps_probe_invariant():
     slots, _, _, n_full = km.resolve(keys, valid)
     assert n_full == 1  # 129 keys into 128 slots: one reported full
     assert (slots >= 0).sum() == 128
+
+
+def test_arena_follows_live_keys_under_churn():
+    """Insert-then-free over a bounded universe: the freed keys' bytes
+    are reclaimed, so the arena stays within twice the live keys' bytes
+    plus what it reserved at creation, and every key that survives a
+    round still resolves to the slot it had."""
+    rng = np.random.RandomState(26)
+    km = NativeKeyMap(4096)
+    reserve = km.arena_stats()["allocated"]
+    universe = [b"key:%08d" % i for i in range(2000)]
+    held: dict = {}
+    reclaimed = False
+    for rnd in range(50):
+        keys = [universe[i] for i in rng.choice(2000, 1500)]
+        slots, _, _, n_full = km.resolve(keys, np.ones(len(keys), bool))
+        assert n_full == 0
+        for k, s in zip(keys, slots.tolist()):
+            assert held.setdefault(k, s) == s, f"{k!r} moved in round {rnd}"
+        drop = [k for k in held if rng.rand() < 0.5]
+        assert km.free_slots(
+            np.array([held.pop(k) for k in drop], np.int32)
+        ) == len(drop)
+        stats = km.arena_stats()
+        live = sum(len(k) for k in held)
+        assert stats["used"] - stats["dead"] == live
+        assert stats["used"] <= 2 * live
+        assert stats["allocated"] <= 2 * live + reserve, (rnd, stats)
+        reclaimed |= stats["dead"] == 0 and stats["used"] == live
+    assert reclaimed
+    survivors = list(held)
+    slots, _, _, _ = km.resolve(survivors, np.ones(len(survivors), bool))
+    assert slots.tolist() == [held[k] for k in survivors]
+    assert len(km) == len(held)

@@ -30,6 +30,7 @@ COUNTERS = (
     "throttlecrab_tpu_front_seconds_total",
     "throttlecrab_tpu_keymap_seconds_total",
     "throttlecrab_tpu_fetch_seconds_total",
+    "throttlecrab_tpu_cleanup_seconds_total",
     "throttlecrab_process_gc_seconds_total",
 )
 STAGES = (
@@ -207,6 +208,60 @@ def test_every_stage_is_a_span_inside_its_window(tmp_path):
             assert any(w0 <= s and e <= w1 for w0, w1 in windows), stage
     assert any(ev.name == "process.gc" for line in lines
                for ev in line.events)
+
+
+SWEEP_STAGES = ("cleanup.drain", "cleanup.compact", "cleanup.free",
+                "cleanup.front")
+
+
+def test_a_sweep_is_spanned_and_counted(tmp_path):
+    """A sweep under a profiler trace: its stages are spans inside
+    `cleanup.sweep`, inside a `driver.window`; the cleanup clock, the
+    sweep counters and the keymap's arena gauge move on /metrics.  The
+    clock jumps a minute a window, so every bucket (burst 3, 10 per
+    60 s: 12 s of tolerance) has expired at the next window's sweep."""
+    clock = iter(range(T0, T0 + 10**6 * 60 * 10**9, 60 * 10**9))
+
+    async def main():
+        transport, metrics = make_transport(
+            cleanup_policy=make_policy("adaptive", max_operations=1),
+        )
+        transport.now_fn = lambda: next(clock)
+        before = scrape(metrics)
+        await transport.start()
+        assert await pipeline(transport.bound_port, 200, "s:") == 200
+        await asyncio.sleep(0.3)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+        try:
+            assert await pipeline(transport.bound_port, 200, "u:") == 200
+            await asyncio.sleep(0.3)
+        finally:
+            jax.profiler.stop_trace()
+        await transport.stop()
+        return before, scrape(metrics)
+
+    trace_dir = tmp_path / "trace"
+    before, after = asyncio.run(main())
+    d = {k: after[k] - before[k] for k in after if k in before}
+    assert d["throttlecrab_tpu_sweeps"] >= 2
+    assert d["throttlecrab_tpu_slots_freed"] > 0
+    assert d["throttlecrab_tpu_sweep_occupied_total"] >= \
+        d["throttlecrab_tpu_slots_freed"]
+    assert d["throttlecrab_tpu_cleanup_seconds_total"] > 0
+    assert after["throttlecrab_tpu_keymap_arena_bytes"] > 0
+    events = [(ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns))
+              for line in _host_lines(trace_dir) for ev in line.events]
+    sweeps = [(s, e) for name, s, e in events if name == "cleanup.sweep"]
+    windows = [(s, e) for name, s, e in events if name == "driver.window"]
+    assert all(any(w0 <= s and e <= w1 for w0, w1 in windows)
+               for s, e in sweeps)
+    for stage in SWEEP_STAGES:
+        spans = [(s, e) for name, s, e in events if name == stage]
+        assert spans, stage
+        for s, e in spans:
+            assert any(w0 <= s and e <= w1 for w0, w1 in sweeps), stage
 
 
 def test_stage_clock_loses_no_add_between_threads():

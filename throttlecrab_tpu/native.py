@@ -129,6 +129,8 @@ def _build() -> Optional[ctypes.CDLL]:
     ]
     lib.tk_free_slots.restype = ctypes.c_int64
     lib.tk_free_slots.argtypes = [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64]
+    lib.tk_arena_stats.restype = None
+    lib.tk_arena_stats.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
     lib.tk_intern_keys.restype = ctypes.c_int64
     lib.tk_intern_keys.argtypes = [
         ctypes.c_void_p, ctypes.c_char_p, ctypes.c_void_p, ctypes.c_int64,
@@ -727,6 +729,15 @@ class NativeKeyMap:
     def grow(self, new_capacity: int) -> None:
         self._lib.tk_grow(self._h, new_capacity)
         self.mutations += 1
+
+    def arena_stats(self) -> dict:
+        """Bytes of the key arena: `used` (live and freed keys),
+        `dead` (freed keys not yet reclaimed) and `allocated`."""
+        out = np.zeros(3, np.int64)
+        self._lib.tk_arena_stats(
+            self._h, out.ctypes.data_as(ctypes.c_void_p)
+        )
+        return dict(zip(("used", "dead", "allocated"), out.tolist()))
 
     def items(self):
         """(key_bytes, slot) pairs for every live entry (snapshot export)."""

@@ -82,6 +82,7 @@ from ..tpu.limiter import (
     segment_info,
     sequential_fallback,
 )
+from ..tpu.profiling import span
 from .tenants import (
     KeyTooLong,
     TenantRegistry,
@@ -1484,20 +1485,26 @@ class ShardedTpuRateLimiter(ScalarCompatMixin):
 
     def sweep(self, now_ns: int) -> int:
         """Sweep every shard; returns total slots freed."""
-        expired = self.table.sweep(now_ns)
+        with span("cleanup.compact"):
+            expired = self.table.sweep(now_ns)
         freed = 0
-        for d in range(self.n_shards):
-            idx = np.flatnonzero(expired[d])
-            freed += self.keymaps[d].free_slots(idx)
-            if self._tenant_of_slot is not None and len(idx):
-                # Release quota attribution for the vacated slots.
-                tos = self._tenant_of_slot[d]
-                tids = tos[idx]
-                live = tids >= 0
-                if live.any():
-                    self._tenant_used[d] -= np.bincount(
-                        tids[live],
-                        minlength=self.tenants.max_tenants,
-                    )
-                    tos[idx[live]] = -1
+        with span("cleanup.free"):
+            for d in range(self.n_shards):
+                idx = np.flatnonzero(expired[d])
+                freed += self.keymaps[d].free_slots(idx)
+                if self._tenant_of_slot is not None and len(idx):
+                    # Release quota attribution for the vacated slots.
+                    tos = self._tenant_of_slot[d]
+                    tids = tos[idx]
+                    live = tids >= 0
+                    if live.any():
+                        self._tenant_used[d] -= np.bincount(
+                            tids[live],
+                            minlength=self.tenants.max_tenants,
+                        )
+                        tos[idx[live]] = -1
         return freed
+
+    def prewarm_sweep(self) -> None:
+        """TpuRateLimiter.prewarm_sweep on every shard's table."""
+        self.table.sweep(EMPTY_EXPIRY)

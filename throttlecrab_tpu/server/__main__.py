@@ -361,6 +361,10 @@ async def run_server(config: Config) -> None:
         # or it races the RPC path's donated state buffers.
         insight.poll_lock = limiter.device_lock
     cleanup_policy = create_cleanup_policy(config)
+    if cleanup_policy is not None:
+        # The sweep compiles now, before serving: the policy's first
+        # sweep fires under traffic, and a compile there stalls it.
+        await loop.run_in_executor(None, device_limiter.prewarm_sweep)
     # Control plane (L3.9): adaptive feedback over the knob surface the
     # tiers above just built.  Off by default (THROTTLECRAB_CONTROL=0):
     # create_control returns None, nothing ticks, no knob ever moves.

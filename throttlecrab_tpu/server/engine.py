@@ -773,7 +773,7 @@ class BatchingEngine:
                         if not policy.should_clean(
                             now_ns, live_now, capacity
                         ):
-                            return None, drained
+                            return None, drained, live_now
                     # Attribute hits already counted on-device to the
                     # window this sweep closes (after_sweep resets the
                     # policy's count — a late drain would leak them into
@@ -785,9 +785,9 @@ class BatchingEngine:
                         )
                     freed = self.limiter.sweep(now_ns)
                     policy.after_sweep(now_ns, freed, live_now)
-                    return freed, drained
+                    return freed, drained, live_now
 
-            freed, drained = await loop.run_in_executor(
+            freed, drained, live_now = await loop.run_in_executor(
                 None, locked_policy_step
             )
             if freed is not None and self.front is not None:
@@ -798,7 +798,7 @@ class BatchingEngine:
                 if drained:
                     self.metrics.record_expired_hits(drained)
                 if freed is not None:
-                    self.metrics.record_sweep(freed)
+                    self.metrics.record_sweep(freed, live_now)
 
     def health_state(self) -> str:
         """The failure-domain state for GET /health: "ok" | "retrying"

@@ -49,6 +49,10 @@ METRIC_NAMES = (
     "throttlecrab_tpu_sweeps",
     "throttlecrab_tpu_expired_hits",
     "throttlecrab_tpu_slots_freed",
+    # Occupied slots summed over sweeps (each sweep reads every occupied
+    # slot's expiry), and the native keymap's key arena.
+    "throttlecrab_tpu_sweep_occupied_total",
+    "throttlecrab_tpu_keymap_arena_bytes",
     "throttlecrab_tpu_front_deny_hits",
     "throttlecrab_tpu_front_shed",
     "throttlecrab_tpu_front_stale_evictions",
@@ -75,12 +79,14 @@ METRIC_NAMES = (
     "throttlecrab_tpu_drain_shed_total",
     "throttlecrab_tpu_deadline_shed_total",
     # Host stage clocks (STAGE_CLOCKS below): the native driver's wait
-    # and busy time, its front-tier, keymap and fetch stages, GC passes.
+    # and busy time, its front-tier, keymap, fetch and cleanup stages,
+    # GC passes.
     "throttlecrab_tpu_wire_wait_seconds_total",
     "throttlecrab_tpu_driver_busy_seconds_total",
     "throttlecrab_tpu_front_seconds_total",
     "throttlecrab_tpu_keymap_seconds_total",
     "throttlecrab_tpu_fetch_seconds_total",
+    "throttlecrab_tpu_cleanup_seconds_total",
     "throttlecrab_process_gc_seconds_total",
     # Insight tier (L3.75, insight/).
     "throttlecrab_tpu_insight_allowed_rate",
@@ -129,6 +135,11 @@ FRONT_SECONDS = StageClock(
     "Native driver time in the front tier (spans front.filter, "
     "front.observe)",
 )
+CLEANUP_SECONDS = StageClock(
+    "throttlecrab_tpu_cleanup_seconds_total",
+    "Native driver time in the cleanup hook: the policy's bookkeeping "
+    "each window and every sweep (span cleanup.sweep)",
+)
 GC_SECONDS = StageClock(
     "throttlecrab_process_gc_seconds_total",
     "Garbage-collector passes in the server process, in any thread "
@@ -136,7 +147,7 @@ GC_SECONDS = StageClock(
 )
 STAGE_CLOCKS = (
     WIRE_WAIT_SECONDS, DRIVER_BUSY_SECONDS, FRONT_SECONDS, KEYMAP_SECONDS,
-    FETCH_SECONDS, GC_SECONDS,
+    FETCH_SECONDS, CLEANUP_SECONDS, GC_SECONDS,
 )
 
 
@@ -206,6 +217,8 @@ class Metrics:
         self.max_batch = 0
         self.sweeps = 0
         self.slots_freed = 0
+        self.sweep_occupied = 0
+        self.keymap_arena_bytes = 0
         self.expired_hits = 0
         # Front tier (L3.5: deny cache + admission control).
         self.front_deny_hits = 0
@@ -291,9 +304,16 @@ class Metrics:
         self.batched_requests += batch_size
         self.max_batch = max(self.max_batch, batch_size)
 
-    def record_sweep(self, freed: int) -> None:
+    def record_sweep(self, freed: int, occupied: int) -> None:
+        """One sweep that freed `freed` of the `occupied` slots it
+        found holding a key."""
         self.sweeps += 1
         self.slots_freed += freed
+        self.sweep_occupied += occupied
+
+    def record_keymap_arena(self, nbytes: int) -> None:
+        """The native keymap's key arena, in bytes allocated."""
+        self.keymap_arena_bytes = nbytes
 
     def record_expired_hits(self, n: int) -> None:
         """Requests that landed on expired entries (the kernel's
@@ -521,6 +541,19 @@ class Metrics:
             "Slots freed by compaction sweeps",
             "counter",
             self.slots_freed,
+        )
+        metric(
+            "throttlecrab_tpu_sweep_occupied_total",
+            "Occupied slots summed over compaction sweeps (the keys "
+            "each sweep found, live or expired)",
+            "counter",
+            self.sweep_occupied,
+        )
+        metric(
+            "throttlecrab_tpu_keymap_arena_bytes",
+            "Bytes allocated to the native keymap's interned keys",
+            "gauge",
+            self.keymap_arena_bytes,
         )
         # Front tier (L3.5): exact deny cache + admission control.
         metric(

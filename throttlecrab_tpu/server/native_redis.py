@@ -38,7 +38,12 @@ from ..tpu.limiter import (
     limiter_uses_bytes_keys,
 )
 from ..tpu.profiling import launch_tick, span
-from .metrics import DRIVER_BUSY_SECONDS, FRONT_SECONDS, WIRE_WAIT_SECONDS
+from .metrics import (
+    CLEANUP_SECONDS,
+    DRIVER_BUSY_SECONDS,
+    FRONT_SECONDS,
+    WIRE_WAIT_SECONDS,
+)
 
 log = logging.getLogger("throttlecrab.redis.native")
 
@@ -589,7 +594,7 @@ class NativeRedisTransport:
                     launches=1 if frames else 0,
                 )
         if self.cleanup_policy is not None:
-            with span("cleanup.sweep"):
+            with span("cleanup.sweep", CLEANUP_SECONDS):
                 self._maybe_sweep(
                     now_ns, sum(len(b[1]) - 1 for b in batches)
                 )
@@ -702,13 +707,19 @@ class NativeRedisTransport:
 
     def _maybe_sweep(self, now_ns: int, n_ops: int) -> None:
         """Policy state is shared with the asyncio engine — all policy
-        interaction happens under limiter_lock (see engine._maybe_sweep)."""
+        interaction happens under limiter_lock (see engine._maybe_sweep).
+        A sweep's stages are spans of their own: `cleanup.drain` (the
+        expired-hit fetch), `cleanup.compact` and `cleanup.free` (in
+        limiter.sweep: the device sweep with its mask, then the keymap's
+        frees), `cleanup.front` (the deny cache's on_sweep)."""
         policy = self.cleanup_policy
         if policy is None:
             return
         from ..tpu.cleanup import feed_expired_hits
 
         n_hits = 0
+        arena = getattr(getattr(self.limiter, "keymap", None),
+                        "arena_stats", None)
         with self.limiter_lock:
             policy.record_ops(n_ops)
             # Did the throttled drain just hit the device?  Then the
@@ -717,7 +728,8 @@ class NativeRedisTransport:
             fetched = getattr(
                 self.limiter, "expired_hits_fetch_due", lambda t: False
             )(now_ns)
-            n_hits += feed_expired_hits(policy, self.limiter, now_ns)
+            with span("cleanup.drain"):
+                n_hits += feed_expired_hits(policy, self.limiter, now_ns)
             live = len(self.limiter)
             capacity = getattr(self.limiter, "total_capacity", 1 << 62)
             if not policy.should_clean(now_ns, live, capacity):
@@ -728,17 +740,23 @@ class NativeRedisTransport:
                 # already sweeps inline, so the blocking fetch is
                 # acceptable here.
                 if not fetched:
-                    n_hits += feed_expired_hits(
-                        policy, self.limiter, now_ns, force=True
-                    )
+                    with span("cleanup.drain"):
+                        n_hits += feed_expired_hits(
+                            policy, self.limiter, now_ns, force=True
+                        )
                 freed = self.limiter.sweep(now_ns)
                 policy.after_sweep(now_ns, freed, live)
+            # The keymap is this lock's: its arena is read under it.
+            arena_bytes = arena()["allocated"] if arena else None
         if freed is not None and self.front is not None:
             # Swept buckets are gone even for a later regressed clock:
             # drop the deny-cache entries they backed.
-            self.front.on_sweep(now_ns)
+            with span("cleanup.front"):
+                self.front.on_sweep(now_ns)
         if self.metrics is not None:
             if n_hits:
                 self.metrics.record_expired_hits(n_hits)
             if freed is not None:
-                self.metrics.record_sweep(freed)
+                self.metrics.record_sweep(freed, live)
+            if arena_bytes is not None:
+                self.metrics.record_keymap_arena(arena_bytes)

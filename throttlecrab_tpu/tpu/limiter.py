@@ -33,6 +33,7 @@ import numpy as np
 from ..core.errors import InternalError, InvalidRateLimit, NegativeQuantity
 from ..core.rate_limiter import RateLimitResult, normalize_now_ns
 from ..faults import maybe_fail
+from .kernel import EMPTY_EXPIRY
 from .keymap import PyKeyMap
 from .profiling import FETCH_SECONDS, KEYMAP_SECONDS, span
 from .table import BucketTable
@@ -983,8 +984,17 @@ class TpuRateLimiter(ScalarCompatMixin):
 
     def sweep(self, now_ns: int) -> int:
         """Run a cleanup sweep; returns the number of slots freed."""
-        expired = self.table.sweep(now_ns)
-        return self.keymap.free_slots(np.flatnonzero(expired))
+        with span("cleanup.compact"):
+            expired = self.table.sweep(now_ns)
+        with span("cleanup.free"):
+            return self.keymap.free_slots(np.flatnonzero(expired))
+
+    def prewarm_sweep(self) -> None:
+        """Compile the table's sweep now (the server's boot), not at a
+        policy's first sweep, which fires under traffic.  A sweep at the
+        empty rows' sentinel clock (kernel.EMPTY_EXPIRY) vacates only
+        rows that are empty already, so nothing is freed."""
+        self.table.sweep(EMPTY_EXPIRY)
 
     def __len__(self) -> int:
         return len(self.keymap)
