@@ -728,6 +728,33 @@ void tk_export(void* h, int32_t* slots_out, int64_t* offsets_out,
     offsets_out[i] = off;
 }
 
+// Keys of a few given slots (the insight top-K), read through the
+// slot_bucket column: found_out[i] is 1 and keys_out[offsets_out[i],
+// offsets_out[i+1]) the key bytes for a live slot; a free or
+// out-of-range slot gets 0 and an empty span.  Bytes are copied only
+// while they fit in keys_cap; the return is the total bytes, so a
+// caller whose buffer was too small calls again with that many.
+int64_t tk_keys_for_slots(void* h, const int32_t* slots, int64_t n,
+                          int64_t* offsets_out, uint8_t* found_out,
+                          char* keys_out, int64_t keys_cap) {
+    const KeyMap* m = static_cast<const KeyMap*>(h);
+    int64_t off = 0;
+    for (int64_t i = 0; i < n; i++) {
+        offsets_out[i] = off;
+        const int32_t slot = slots[i];
+        const int64_t b =
+            (slot >= 0 && slot < m->capacity) ? m->slot_bucket[slot] : -1;
+        found_out[i] = b >= 0;
+        if (b < 0) continue;
+        const Entry& e = m->buckets[b];
+        if (off + e.key_len <= keys_cap)
+            memcpy(keys_out + off, m->arena.data() + e.key_off, e.key_len);
+        off += e.key_len;
+    }
+    offsets_out[n] = off;
+    return off;
+}
+
 // Free the given slots (from a sweep's expired mask).  Tombstone-free
 // removal for linear probing: re-place any displaced cluster members.
 int64_t tk_free_slots(void* h, const int32_t* slots, int64_t n) {

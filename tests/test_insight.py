@@ -248,6 +248,51 @@ def test_slot_key_resolver_python_and_items_backends():
     assert r2.keys_for([3, 4]) == [b"k", None]
 
 
+@pytest.fixture
+def native_items_forbidden(monkeypatch):
+    """NativeKeyMap with items() made to raise: the slot→key path must
+    never export the whole map."""
+    from throttlecrab_tpu.native import NativeKeyMap, native_available
+
+    if not native_available():
+        pytest.skip("native keymap unavailable")
+
+    def _export(self):
+        raise AssertionError("NativeKeyMap.items() on the serving path")
+
+    monkeypatch.setattr(NativeKeyMap, "items", _export)
+    return NativeKeyMap
+
+
+def test_slot_key_resolver_native_slot_lookup(native_items_forbidden):
+    """Over the C++ keymap the resolver reads the slot column: a key
+    inserted after a resolve is named at once (no stamp to wait for),
+    a freed slot reads None, and a recycled slot names its new key."""
+    km = native_items_forbidden(64)
+    r = SlotKeyResolver(km)
+    ones = np.ones(2, bool)
+    (sa, sb), _, _, _ = km.resolve([b"a", b"b"], ones)
+    assert r.keys_for([sa, sb, 9999, -1]) == [b"a", b"b", None, None]
+    (sc,), _, _, _ = km.resolve([b"c"], ones[:1])
+    assert r.keys_for([sc, sa]) == [b"c", b"a"]
+    assert km.free_slots(np.array([sa], np.int32)) == 1
+    assert r.keys_for([sa, sb]) == [None, b"b"]
+    (sd,), _, _, _ = km.resolve([b"d"], ones[:1])
+    assert sd == sa
+    assert r.keys_for([sa, sb, sc]) == [b"d", b"b", b"c"]
+
+
+def test_insight_poll_names_hot_key_without_export(native_items_forbidden):
+    lim = TpuRateLimiter(capacity=1 << 10, keymap="native", insight=True)
+    assert isinstance(lim.keymap, native_items_forbidden)
+    ins = InsightTier(limiter=lim, poll_ms=1000, window_s=10.0, decay_s=0.0)
+    lim.rate_limit_batch(["h"] * 50, 2, 5, 60, 1, T0, wire=True)
+    assert ins.maybe_poll(T0)
+    s = ins.stats(state="ok")
+    assert ins.poll_failures == 0
+    assert s["top_denied"][0]["key"] == "h"
+
+
 # --------------------------------------------------------------------- #
 # InsightTier: polling, /stats shape, feedback loop.
 

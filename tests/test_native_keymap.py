@@ -169,3 +169,47 @@ def test_arena_follows_live_keys_under_churn():
     slots, _, _, _ = km.resolve(survivors, np.ones(len(survivors), bool))
     assert slots.tolist() == [held[k] for k in survivors]
     assert len(km) == len(held)
+
+
+def _reverse(km):
+    return {slot: key for key, slot in km.items()}
+
+
+def test_keys_for_slots_reads_the_slot_column():
+    """keys_for_slots answers, slot for slot, what items() pairs with
+    each live slot, and None for a free or out-of-range one: on a few
+    thousand keys, after frees and a re-insert that recycles a slot,
+    and after the arena compaction those frees trigger."""
+    cap = 8192
+    km = NativeKeyMap(cap)
+    keys = [b"user:%06d" % i for i in range(3000)]
+    keys += [b"", "пользователь:🔑".encode(), b"x" * 1000]
+    slots, _, _, n_full = km.resolve(keys, np.ones(len(keys), bool))
+    assert n_full == 0
+    probe = list(range(cap)) + [-1, cap, 2**31 - 1]
+    rev = _reverse(km)
+    assert km.keys_for_slots(probe) == [rev.get(s) for s in probe]
+    assert km.keys_for_slots(slots.tolist()) == keys
+    assert km.keys_for_slots([]) == []
+    free_slot = next(s for s in range(cap) if s not in rev)
+    assert km.keys_for_slots([free_slot, -1, cap]) == [None] * 3
+
+    # Free two thirds of the keys: their bytes outweigh the live ones',
+    # so free_slots compacts the arena.
+    gone = slots[: 2000]
+    assert km.free_slots(gone) == 2000
+    stats = km.arena_stats()
+    assert stats["dead"] == 0
+    assert stats["used"] == sum(len(k) for k in keys[2000:])
+    assert km.keys_for_slots(gone.tolist()) == [None] * 2000
+    rev = _reverse(km)
+    assert km.keys_for_slots(probe) == [rev.get(s) for s in probe]
+    assert km.keys_for_slots(slots[2000:].tolist()) == keys[2000:]
+
+    # A new key takes a freed slot: the lookup names it at once.
+    fresh = [b"fresh:%d" % i for i in range(50)]
+    got, _, _, _ = km.resolve(fresh, np.ones(len(fresh), bool))
+    assert set(got.tolist()) <= set(gone.tolist())
+    assert km.keys_for_slots(got.tolist()) == fresh
+    rev = _reverse(km)
+    assert km.keys_for_slots(probe) == [rev.get(s) for s in probe]

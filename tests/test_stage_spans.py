@@ -12,6 +12,7 @@ import jax
 import pytest
 
 from throttlecrab_tpu.front import AdmissionController, DenyCache, FrontTier
+from throttlecrab_tpu.insight import InsightTier
 from throttlecrab_tpu.native import native_available, wire_available
 from throttlecrab_tpu.server.metrics import GC_SECONDS, Metrics
 from throttlecrab_tpu.tpu import profiling
@@ -56,11 +57,12 @@ class _Periodic:
             pass
 
 
-def make_transport(**kwargs):
+def make_transport(limiter=None, **kwargs):
     from throttlecrab_tpu.server.native_redis import NativeRedisTransport
 
     metrics = Metrics(max_denied_keys=10)
-    limiter = TpuRateLimiter(capacity=1024, keymap="native")
+    if limiter is None:
+        limiter = TpuRateLimiter(capacity=1024, keymap="native")
     front = FrontTier(
         DenyCache(1024), AdmissionController(max_pending=10**6),
         metrics=metrics, bytes_keys=True,
@@ -208,6 +210,44 @@ def test_every_stage_is_a_span_inside_its_window(tmp_path):
             assert any(w0 <= s and e <= w1 for w0, w1 in windows), stage
     assert any(ev.name == "process.gc" for line in lines
                for ev in line.events)
+
+
+def test_insight_resolve_is_a_span_inside_the_poll(tmp_path):
+    """A real insight tier on the native driver, traced: each poll's
+    slot→key resolution is an `insight.resolve` span inside its
+    `insight.poll`, and the poll names the hot keys."""
+    limiter = TpuRateLimiter(capacity=1024, keymap="native", insight=True)
+    insight = InsightTier(limiter=limiter, poll_ms=1, decay_s=0.0)
+    t_start = time.monotonic_ns()
+
+    async def main():
+        transport, _ = make_transport(limiter=limiter, insight=insight)
+        transport.now_fn = lambda: T0 + time.monotonic_ns() - t_start
+        await transport.start()
+        assert await pipeline(transport.bound_port, 200, "w:") == 200
+        await asyncio.sleep(0.3)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+        try:
+            assert await pipeline(transport.bound_port, 200, "t:") == 200
+            await asyncio.sleep(0.3)
+        finally:
+            jax.profiler.stop_trace()
+        await transport.stop()
+
+    trace_dir = tmp_path / "trace"
+    asyncio.run(main())
+    events = [(ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns))
+              for line in _host_lines(trace_dir) for ev in line.events]
+    polls = [(s, e) for name, s, e in events if name == "insight.poll"]
+    resolves = [(s, e) for name, s, e in events if name == "insight.resolve"]
+    assert resolves
+    for s, e in resolves:
+        assert any(p0 <= s and e <= p1 for p0, p1 in polls)
+    assert insight.poll_failures == 0
+    top = {row["key"] for row in insight.stats(state="ok")["top_denied"]}
+    assert top and all(k.startswith(("w:", "t:")) for k in top)
 
 
 SWEEP_STAGES = ("cleanup.drain", "cleanup.compact", "cleanup.free",

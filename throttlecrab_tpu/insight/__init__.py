@@ -41,6 +41,7 @@ import logging
 import threading
 from typing import Optional
 
+from ..tpu.profiling import span
 from .collector import (
     NS_PER_SEC,
     RateWindow,
@@ -254,7 +255,8 @@ class InsightTier:
                 self._last_decay_ns = now_ns
             # Keymap read rides the same limiter-lock hold as the
             # fetch, so slot→key attribution cannot race a sweep.
-            keys = self._resolver.keys_for(ids)
+            with span("insight.resolve"):
+                keys = self._resolver.keys_for(ids)
         except Exception:
             log.debug("insight device poll failed", exc_info=True)
             with self._lock:

@@ -19,11 +19,12 @@ class SlotKeyResolver:
     """slot id → key, against the limiter's live keymap.
 
     PyKeyMap exposes its reverse column directly (O(1) per slot); the
-    C++ keymap only exports (key, slot) pairs wholesale, so its reverse
-    map is cached and pinned by the keymap's ``mutations`` counter —
-    the same staleness stamp the by-id launch rows use — and rebuilt
-    only after a sweep/growth actually remapped slots.  Callers must
-    hold the limiter lock so the map cannot mutate mid-resolution.
+    C++ keymap reads its own slot→bucket column in one native call for
+    the given slots (``keys_for_slots``).  A keymap with neither, which
+    only exports (key, slot) pairs wholesale through ``items()``, gets
+    a reverse map cached and pinned by its ``mutations`` counter and
+    rebuilt after a sweep/growth remapped slots.  Callers must hold the
+    limiter lock so the map cannot mutate mid-resolution.
     """
 
     def __init__(self, keymap) -> None:
@@ -39,6 +40,9 @@ class SlotKeyResolver:
             return [
                 rev[s] if 0 <= s < n else None for s in slots
             ]
+        keys_for_slots = getattr(km, "keys_for_slots", None)
+        if keys_for_slots is not None:
+            return keys_for_slots(slots)
         stamp = getattr(km, "mutations", 0)
         if self._cache is None or stamp != self._stamp:
             self._cache = {slot: key for key, slot in km.items()}
@@ -53,8 +57,8 @@ class ShardedSlotKeyResolver:
     The mesh top-K (parallel/sharded.py ShardedBucketTable.insight_topk)
     reports global ids ``shard * capacity_per_shard + local_slot``; this
     decodes them against the LIVE per-shard capacity and resolves each
-    shard's slots through a plain SlotKeyResolver, so the C++ keymap's
-    mutation-pinned reverse-map cache is reused per shard.  Table
+    shard's slots through a plain SlotKeyResolver, so each shard's
+    keymap answers by its own slot lookup.  Table
     growth re-bases the id encoding — ``id_base()`` exposes the live
     base so the insight tier can reset its per-slot delta map instead
     of diffing new ids against stale ones (which would re-record hot

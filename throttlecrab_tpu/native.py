@@ -17,7 +17,7 @@ import os
 import subprocess
 import threading
 from pathlib import Path
-from typing import Optional, Sequence
+from typing import List, Optional, Sequence
 
 import numpy as np
 
@@ -169,6 +169,11 @@ def _build() -> Optional[ctypes.CDLL]:
     ]
     lib.tk_export.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+    ]
+    lib.tk_keys_for_slots.restype = ctypes.c_int64
+    lib.tk_keys_for_slots.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
     ]
     return lib
 
@@ -738,6 +743,36 @@ class NativeKeyMap:
             self._h, out.ctypes.data_as(ctypes.c_void_p)
         )
         return dict(zip(("used", "dead", "allocated"), out.tolist()))
+
+    def keys_for_slots(self, slots) -> List[Optional[bytes]]:
+        """The key bytes `items()` pairs with each slot, None for a free
+        or out-of-range slot: one native call that reads the keymap's
+        slot→bucket column, for a few slots (the insight top-K)."""
+        arr = np.ascontiguousarray(slots, np.int32)
+        n = len(arr)
+        offsets = np.empty(n + 1, np.int64)
+        found = np.empty(n, np.uint8)
+        cap = 256 * n
+        while True:
+            blob = ctypes.create_string_buffer(max(cap, 1))
+            total = self._lib.tk_keys_for_slots(
+                self._h,
+                arr.ctypes.data_as(ctypes.c_void_p),
+                n,
+                offsets.ctypes.data_as(ctypes.c_void_p),
+                found.ctypes.data_as(ctypes.c_void_p),
+                blob,
+                cap,
+            )
+            if total <= cap:
+                break
+            cap = total
+        raw = blob.raw[:total]
+        offs = offsets.tolist()
+        return [
+            raw[offs[i] : offs[i + 1]] if hit else None
+            for i, hit in enumerate(found.tolist())
+        ]
 
     def items(self):
         """(key_bytes, slot) pairs for every live entry (snapshot export)."""
