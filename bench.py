@@ -103,15 +103,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "on TPU when the table fits (fewest bytes per "
                          "request), host elsewhere (the 1-vCPU XLA sort "
                          "costs more than it saves)")
-    ap.add_argument("--pallas-fused", action="store_true",
-                    help="fused-kernel A/B instead: the serving scan "
-                         "shape with decision windows fused into one "
-                         "Pallas launch (tpu/pallas_fused.py) vs the "
-                         "composed-XLA path, both row widths (insight "
-                         "off/on), same session.  Off-TPU the fused "
-                         "kernel runs in interpret mode: its rate is "
-                         "NOT measured there — the A/B degrades to a "
-                         "bit-identity verification plus the XLA rates")
     ap.add_argument("--wire", choices=("auto", "cur", "w32"),
                     default="auto",
                     help="by-id device output tier: w32 = 4 B/request "
@@ -218,13 +209,6 @@ def main() -> int:
     import throttlecrab_tpu  # noqa: F401  (enables x64)
     import jax
 
-    if args.cpu:
-        # Mosaic compiles the Pallas kernel only for a TPU: a CPU run
-        # asks for it interpreted.
-        from throttlecrab_tpu.tpu import pallas_fused
-
-        pallas_fused.INTERPRET = True
-
     from throttlecrab_tpu.runtime import enable_compile_cache
 
     enable_compile_cache()
@@ -241,8 +225,6 @@ def main() -> int:
         return run_front_bench(args, device)
     if args.insight:
         return run_insight_bench(args, device)
-    if args.pallas_fused:
-        return run_pallas_fused_bench(args, device)
     if args.mesh:
         return run_mesh_bench(args, device)
     if args.replay:
@@ -579,155 +561,6 @@ def run_insight_bench(args, device) -> int:
             }
         )
     )
-    return 0
-
-
-def run_pallas_fused_bench(args, device) -> int:
-    """Fused-kernel same-session A/B (ISSUE 15): decisions/s with each
-    window decided by ONE fused Pallas launch vs the composed-XLA
-    window, at BOTH row widths (insight off = 4-wide, insight on =
-    INS_WIDTH), over the serving scan shape (rate_limit_many wire=True,
-    the engine's backlog path).
-
-    Before any timing, the two dispatches are pinned bit-identical on a
-    shared window stream (allowed/remaining/reset/retry equal
-    request-by-request).  Off-TPU the fused kernel executes in Pallas
-    interpret mode — correct but orders of magnitude slower, a property
-    of the emulation, not the kernel — so its rate is reported null
-    there and explicitly excluded from measurement, per the
-    docs/benchmark-results.md convention.
-    """
-    import throttlecrab_tpu.tpu.pallas_fused  # noqa: F401  (import cost
-    # paid before any timed region)
-
-    interpreted = args.cpu
-    prev_env = os.environ.get("THROTTLECRAB_PALLAS_FUSED")
-    try:
-        return _pallas_fused_body(args, device, interpreted)
-    finally:
-        # run() flips the env per mode; restore the operator's value on
-        # EVERY exit (incl. the divergence error path) so a programmatic
-        # caller never inherits a leaked fused switch.
-        if prev_env is None:
-            os.environ.pop("THROTTLECRAB_PALLAS_FUSED", None)
-        else:
-            os.environ["THROTTLECRAB_PALLAS_FUSED"] = prev_env
-
-
-def _pallas_fused_body(args, device, interpreted) -> int:
-    from throttlecrab_tpu.tpu.limiter import TpuRateLimiter
-
-    rng = np.random.default_rng(17)
-    n_keys = 10_000 if args.quick else 50_000
-    batch = 1024 if args.quick else BATCH
-    depth = 4 if args.quick else 8
-    warm = 2
-    timed = 4 if args.quick else 12
-    kid = np.arange(n_keys, dtype=np.int64)
-    burst_all = 5 + (kid % 60)
-    count_all = 50 + (kid % 1000)
-    period_all = 30 + (kid % 120)
-    keys = [f"bench:key:{i}" for i in range(n_keys)]
-    n_launches = warm + timed
-    draws = zipf_indices(rng, n_keys, n_launches * batch * depth).astype(
-        np.int64
-    )
-
-    def windows(li, width):
-        base = li * batch * depth
-        out = []
-        for j in range(depth):
-            sel = draws[base + j * batch : base + (j + 1) * batch][:width]
-            out.append(
-                (
-                    [keys[i] for i in sel],
-                    burst_all[sel],
-                    count_all[sel],
-                    period_all[sel],
-                    1,
-                    T0 + li * 50_000_000,
-                )
-            )
-        return out
-
-    def run(fused, insight, launches, width=None, timed_from=None):
-        os.environ["THROTTLECRAB_PALLAS_FUSED"] = "1" if fused else "0"
-        limiter = TpuRateLimiter(
-            capacity=1 << 17, keymap="python", insight=insight
-        )
-        results = []
-        t0 = None
-        for li in range(launches):
-            if li == timed_from:
-                t0 = time.perf_counter()
-            res = limiter.rate_limit_many(
-                windows(li, width or batch), wire=True
-            )
-            if timed_from is None:
-                results.extend(res)
-        if t0 is None:
-            return results
-        elapsed = time.perf_counter() - t0
-        return (launches - timed_from) * batch * depth / elapsed
-
-    report = {
-        "metric": (
-            "pallas-fused A/B decisions/s "
-            f"({n_keys // 1000}k keys, Zipf-1.1, batch={batch}, "
-            f"depth={depth})"
-        ),
-        "unit": "decisions/s",
-        "platform": device.platform,
-        "fused_interpreted": interpreted,
-    }
-    # Bit-identity gate first (small windows, never timed): the A/B is
-    # only meaningful if both dispatches decide identically.
-    checked = 0
-    for insight in (False, True):
-        a = run(False, insight, launches=3, width=256)
-        b = run(True, insight, launches=3, width=256)
-        for ra, rb in zip(a, b):
-            for f in ("allowed", "remaining", "reset_after_s",
-                      "retry_after_s", "status"):
-                ga = np.asarray(getattr(ra, f))
-                gb = np.asarray(getattr(rb, f))
-                if not (ga == gb).all():
-                    print(
-                        json.dumps(
-                            {**report, "error":
-                             f"fused/XLA divergence in {f}"}
-                        )
-                    )
-                    return 1
-            checked += len(ra.allowed)
-    report["identity_checked_requests"] = checked
-
-    for insight, tag in ((False, "w4"), (True, "w6")):
-        # Best of 2 per mode (the repo bench idiom for this host's
-        # several-fold scheduling swings).
-        report[f"xla_{tag}"] = round(
-            max(
-                run(False, insight, n_launches, timed_from=warm)
-                for _ in range(2)
-            )
-        )
-        if interpreted:
-            # Interpret mode measures the emulator, not the kernel.
-            report[f"fused_{tag}"] = None
-        else:
-            report[f"fused_{tag}"] = round(
-                max(
-                    run(True, insight, n_launches, timed_from=warm)
-                    for _ in range(2)
-                )
-            )
-    if interpreted:
-        report["caveat"] = (
-            "fused rates null: off-TPU the fused kernel runs in Pallas "
-            "interpret mode (emulated DMA + pair math) — excluded from "
-            "measurement by convention; bit-identity verified above"
-        )
-    print(json.dumps(report))
     return 0
 
 

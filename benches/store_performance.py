@@ -99,42 +99,6 @@ def main() -> int:
         limiter = TpuRateLimiter(capacity=1 << 18, keymap="auto")
         bench_scenario(limiter, name, ids, B, iters, params, now0)
 
-    # Fused Pallas decision-kernel row (THROTTLECRAB_PALLAS_FUSED=1,
-    # tpu/pallas_fused.py): the zipfian workload with every window
-    # decided by ONE fused launch instead of the composed-XLA chain.
-    # Off-TPU the fused kernel runs in Pallas interpret mode — correct
-    # but emulated — so the row reports skipped there, per the
-    # interpret-exclusion convention in docs/benchmark-results.md.
-    import os
-
-    import jax
-
-    if jax.default_backend() == "tpu":
-        prev_env = os.environ.get("THROTTLECRAB_PALLAS_FUSED")
-        os.environ["THROTTLECRAB_PALLAS_FUSED"] = "1"
-        try:
-            limiter = TpuRateLimiter(capacity=1 << 18, keymap="auto")
-            bench_scenario(
-                limiter, "zipfian_100k_pallas_fused",
-                scenarios["zipfian_100k"], B, iters, params, now0,
-            )
-        finally:
-            # Restore (not pop): an operator-exported =1 must keep
-            # governing the remaining scenarios, or one JSON session
-            # silently mixes fused and XLA rates.
-            if prev_env is None:
-                os.environ.pop("THROTTLECRAB_PALLAS_FUSED", None)
-            else:
-                os.environ["THROTTLECRAB_PALLAS_FUSED"] = prev_env
-    else:
-        print(json.dumps({
-            "scenario": "zipfian_100k_pallas_fused",
-            "skipped": "non-TPU backend: the fused kernel would run in "
-                       "interpret mode, which measures the emulator — "
-                       "excluded from measurement",
-            "batch": B,
-        }))
-
     # Workload-pattern rps sweep: the configured request-rate knob
     # (count_per_period = 100/1000/10000) cycled sequentially over 100
     # hot keys, like the reference's workload_patterns rps_* group

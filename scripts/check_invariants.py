@@ -54,7 +54,7 @@ def main(argv=None) -> int:
         default="",
         help="comma-separated subset of checkers "
         "(i64,twin,jit,registry,lock,block,async,"
-        "wire,harden,status,fault,ktwin)",
+        "wire,harden,status,fault)",
     )
     parser.add_argument(
         "--baseline",

@@ -144,19 +144,12 @@ def tier_of(handle):
     return "planes"
 
 
-def run_seed(seed, steps, sharded_mesh, fused_alternate=False,
-             insight_single=False):
+def run_seed(seed, steps, sharded_mesh, insight_single=False):
     """One differential seed.
 
-    `fused_alternate=True` flips THROTTLECRAB_PALLAS_FUSED between the
-    fused Pallas kernel (interpret mode off-TPU) and the composed-XLA
-    path on every step: both paths stay pinned to the scalar oracle
-    request-by-request AND the table state each leaves behind must be
-    one the other path continues from exactly — the cross-path
-    stored-state compatibility the kill switch promises.
     `insight_single=True` arms the insight tier (INS_WIDTH rows) on the
-    single-device limiter too, so the alternation covers both row-width
-    templates of the fused kernel.
+    single-device limiter too, so the ladder runs through the gcra_*_ins
+    launches as well as the 4-wide ones.
     """
     from throttlecrab_tpu.tpu.limiter import TpuRateLimiter
     from throttlecrab_tpu.tpu.snapshot import load_snapshot, save_snapshot
@@ -200,12 +193,6 @@ def run_seed(seed, steps, sharded_mesh, fused_alternate=False,
     # drop point.
     floor_now = 0
     for step in range(steps):
-        if fused_alternate:
-            # Flip the dispatch per step: an XLA window, then a fused
-            # window over the state the XLA one left, and so on.
-            os.environ["THROTTLECRAB_PALLAS_FUSED"] = (
-                "1" if step % 2 else "0"
-            )
         # Occasional param churn, sweeps, clock moves (incl. regression).
         if rng.random() < 0.15:
             k = pool[rng.integers(len(pool))]
@@ -286,10 +273,6 @@ def run_seed(seed, steps, sharded_mesh, fused_alternate=False,
                 load_snapshot(lim2, path + ".npz", now_ns=now)
                 lim = lim2
                 floor_now = now
-    if fused_alternate:
-        # Leave the process with the kill switch engaged (test callers
-        # additionally restore the exact prior value via monkeypatch).
-        os.environ["THROTTLECRAB_PALLAS_FUSED"] = "0"
 
 
 def run_hotkey_deny_seed(seed, steps):

@@ -25,12 +25,6 @@ if not os.environ.get("THROTTLECRAB_TPU_TEST_REAL"):
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    # The Pallas kernel compiles only for a TPU: on the CPU backend the
-    # suite asks for interpret mode explicitly (the program never
-    # infers it from the backend).
-    from throttlecrab_tpu.tpu import pallas_fused
-
-    pallas_fused.INTERPRET = True
 
 # No persistent compile cache from the tests: not in this process, and
 # not in the servers the out-of-process tests boot (the env is inherited;

@@ -5,8 +5,7 @@ shapes (a 1,048,576-slot table with the default insight-widened rows,
 K = 16 sub-batches of B = 4096, the engine's defaults) and compiles it
 with the TPU compiler for a v5e that is described, not attached.  What
 the chip's compiler refuses fails here, at no chip time.  The served
-launches go through the tables' own `compile_launch`, the method the
-fused knob's boot gate calls.
+launches go through the tables' own `compile_launch`.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and test workers import
@@ -28,7 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from throttlecrab_tpu.tpu.kernel import IDROW_WIDTH, INS_WIDTH
-from throttlecrab_tpu.tpu.pallas_fused import SERVED_VARIANTS
+from throttlecrab_tpu.tpu.table import SERVED_VARIANTS
 
 CAPACITY = 1 << 20
 SCRATCH = 1 << 16  # BucketTable.SCRATCH
@@ -139,20 +138,6 @@ def test_sharded_scan_step_compiles_with_psum(topo, with_degen, compact):
     )
     assert "all-reduce" in compiled.as_text()
     assert compiled.output_shardings[0].device_set == set(topo.devices[:4])
-
-
-def test_fused_knob_refused_for_the_chip(one_chip, monkeypatch):
-    """THROTTLECRAB_PALLAS_FUSED=1's boot gate on a v5e: Mosaic still
-    refuses the fused kernel (1-D column-vector relayouts and 4-wide row
-    DMAs; ROADMAP S5), so a server with the knob on fails at boot.
-    When the kernel's layout rewrite lands this test turns into the
-    compile test: the gate passes and `tpu_custom_call` is in the text."""
-    from throttlecrab_tpu.tpu import pallas_fused
-
-    monkeypatch.setattr(pallas_fused, "INTERPRET", False)
-    monkeypatch.setenv("THROTTLECRAB_PALLAS_FUSED", "1")
-    with pytest.raises(RuntimeError, match="TPU v5 lite.*Mosaic"):
-        pallas_fused.require_compiles(served_table(one_chip), K, B)
 
 
 def test_sweep_compiles_in_place(one_chip):

@@ -5,7 +5,10 @@ millisecond offsets to a fixed base timestamp exactly like the reference
 passes `now + Duration::from_secs(n)`.
 """
 
+import numpy as np
 import pytest
+
+import jax.numpy as jnp
 
 from throttlecrab_tpu import (
     AdaptiveStore,
@@ -15,6 +18,7 @@ from throttlecrab_tpu import (
     RateLimiter,
 )
 from throttlecrab_tpu.core.i64 import I64_MAX
+from throttlecrab_tpu.tpu import sat
 
 NS = 1_000_000_000
 # Fixed virtual base; all time travel is expressed as offsets from it.
@@ -403,3 +407,61 @@ def test_retry_after_when_denied(limiter):
     assert result.retry_after_ns == NS
     assert result.reset_after_ns == 2 * NS
     assert result.remaining == 0
+
+
+# ---------------------------------------------------------------------- #
+# The device kernels' saturating closed forms (tpu/sat.py) against exact
+# Python integer arithmetic, on an edge grid plus random pairs inside
+# each form's documented domain.
+
+I64_MIN = -(1 << 63)
+I64_EDGES = [
+    0, 1, -1, 2, -2, (1 << 31) - 1, 1 << 31, -(1 << 31),
+    (1 << 32) - 1, 1 << 32, (1 << 62), -(1 << 62),
+    I64_MAX, I64_MIN, NS, -NS, (1 << 61), 977,
+]
+
+
+def _clamp(x: int) -> int:
+    return max(I64_MIN, min(I64_MAX, x))
+
+
+def _div_trunc(a: int, b: int) -> int:
+    q = abs(a) // b
+    return q if a >= 0 else -q
+
+
+# form -> (exact value, domain of the pair)
+_SAT_FORMS = {
+    "sat_add": (lambda a, b: _clamp(a + b), lambda a, b: True),
+    "sat_sub": (lambda a, b: _clamp(a - b), lambda a, b: True),
+    "sat_add_nn": (lambda a, b: _clamp(a + b), lambda a, b: b >= 0),
+    "sat_sub_nn": (lambda a, b: _clamp(a - b), lambda a, b: b >= 0),
+    "sat_mul_nonneg": (
+        lambda a, b: _clamp(a * b), lambda a, b: a >= 0 and b >= 0
+    ),
+    "div_trunc": (_div_trunc, lambda a, b: b > 0),
+}
+
+
+@pytest.mark.parametrize("form", list(_SAT_FORMS))
+def test_sat_closed_form_matches_exact_int(form):
+    """Each saturating form (clamped to i64) and div_trunc (truncated
+    toward zero) equals exact integer arithmetic on every pair."""
+    exact, in_domain = _SAT_FORMS[form]
+    rng = np.random.default_rng(sum(map(ord, form)))
+    rand = rng.integers(
+        I64_MIN, I64_MAX, 1024, dtype=np.int64, endpoint=True
+    )
+    pairs = [(a, b) for a in I64_EDGES for b in I64_EDGES]
+    pairs += list(zip(rand[:512].tolist(), rand[512:].tolist()))
+    # Random pairs outside the domain are folded into it.
+    pairs += [(abs(a) >> 1, abs(b) >> 1) for a, b in pairs]
+    pairs += [(a, (abs(b) >> 1) + 1) for a, b in pairs]
+    pairs = [(a, b) for a, b in pairs if in_domain(a, b)]
+    a = jnp.asarray([p[0] for p in pairs], jnp.int64)
+    b = jnp.asarray([p[1] for p in pairs], jnp.int64)
+    got = np.asarray(getattr(sat, form)(a, b)).tolist()
+    want = [exact(x, y) for x, y in pairs]
+    bad = [(p, g, w) for p, g, w in zip(pairs, got, want) if g != w]
+    assert not bad, f"{form}: {len(bad)} of {len(pairs)} differ: {bad[:3]}"

@@ -13,11 +13,11 @@ Three layers:
     ``fits_w32_wire`` (the ADVICE round-5 high finding) must produce a
     finding again;
   * the wave-3 protocol-surface family (wire / harden / status /
-    fault / ktwin) — real anchor files are copied into a temp tree and
+    fault) — real anchor files are copied into a temp tree and
     mutated (an OP_* with no decoder, a decoder without the
     trailing-bytes check, a STATUS_* absent from one transport, a
-    fault site with no hook, a flipped saturation predicate), and each
-    rule must fire with the right code and symbol.
+    fault site with no hook), and each rule must fire with the right
+    code and symbol.
 """
 
 from __future__ import annotations
@@ -44,7 +44,6 @@ from throttlecrab_tpu.analysis import (
     fault_surface,
     i64_hygiene,
     jit_boundary,
-    kernel_twins,
     registry,
     status_surface,
     twin_drift,
@@ -1463,13 +1462,12 @@ class TestCliOutput:
 
 
 # ------------------------------------------------------------------ #
-# Wave 3: protocol-surface family (wire / harden / status / fault /
-# ktwin).  Each fixture copies the real anchor files into a temp tree
+# Wave 3: protocol-surface family (wire / harden / status / fault).
+# Each fixture copies the real anchor files into a temp tree
 # and mutates them — the mutation is the exact defect class the rule
 # exists to catch, so these double as regression pins for the rules.
 
 CLUSTER_REL = "throttlecrab_tpu/parallel/cluster.py"
-PAIRS_REL = "throttlecrab_tpu/tpu/pallas_fused.py"
 INJECTOR_REL = "throttlecrab_tpu/faults/injector.py"
 
 _WIRE_RELS = (
@@ -1494,11 +1492,6 @@ _FAULT_RELS = (
     "throttlecrab_tpu/tpu/limiter.py",
     "throttlecrab_tpu/tpu/snapshot.py",
     "README.md",
-)
-_KTWIN_RELS = (
-    "throttlecrab_tpu/tpu/sat.py",
-    KERNEL_REL,
-    PAIRS_REL,
 )
 
 
@@ -1828,98 +1821,12 @@ class TestFaultSurface:
         )
 
 
-class TestKernelTwins:
-    def test_real_tree_clean(self):
-        assert kernel_twins.check(REPO) == []
-
-    def test_fixture_tree_clean(self, tmp_path):
-        root = _copy_tree(tmp_path, _KTWIN_RELS)
-        assert kernel_twins.check(root) == []
-
-    def test_saturation_predicate_drift_flagged(self, tmp_path):
-        """Flip one overflow predicate on the pair side of sat_add —
-        the IRs no longer match, so ktwin-drift must fire."""
-        root = _copy_tree(tmp_path, _KTWIN_RELS)
-        _mutate(
-            root, PAIRS_REL,
-            "pos_of = _is_pos(a) & _is_pos(b) & _is_neg(s)",
-            "pos_of = _is_pos(a) & _is_neg(b) & _is_neg(s)",
-        )
-        findings = kernel_twins.check(root)
-        assert any(
-            f.code == "ktwin-drift" and f.symbol == "_sat_add64"
-            for f in findings
-        )
-
-    def test_unmarked_sat_reaching_form_flagged(self, tmp_path):
-        root = _copy_tree(tmp_path, _KTWIN_RELS)
-        path = root / KERNEL_REL
-        path.write_text(
-            path.read_text()
-            + "\n\ndef sneaky_form(a, b):\n    return sat_add(a, b)\n"
-        )
-        findings = kernel_twins.check(root)
-        assert any(
-            f.code == "ktwin-unmarked" and f.symbol == "sneaky_form"
-            for f in findings
-        )
-
-    def test_empty_marker_reason_flagged(self, tmp_path):
-        root = _copy_tree(tmp_path, _KTWIN_RELS)
-        path = root / KERNEL_REL
-        path.write_text(
-            path.read_text()
-            + "\n\ndef probe_form(a, b):  # twin: xla-only()\n"
-            "    return sat_add(a, b)\n"
-        )
-        findings = kernel_twins.check(root)
-        assert any(
-            f.code == "ktwin-marker" and f.symbol == "probe_form"
-            for f in findings
-        )
-
-    def test_marker_with_reason_passes(self, tmp_path):
-        root = _copy_tree(tmp_path, _KTWIN_RELS)
-        path = root / KERNEL_REL
-        path.write_text(
-            path.read_text()
-            + "\n\ndef probe_form(a, b):"
-            "  # twin: xla-only(host-side scalar probe)\n"
-            "    return sat_add(a, b)\n"
-        )
-        assert kernel_twins.check(root) == []
-
-    def test_op_coverage_strip_flagged(self, tmp_path):
-        """Remove every _min64 from the pair transcription while the
-        XLA body still uses minimum — the coverage tier must fire."""
-        root = _copy_tree(tmp_path, _KTWIN_RELS)
-        path = root / PAIRS_REL
-        path.write_text(path.read_text().replace("_min64(", "_max64("))
-        findings = kernel_twins.check(root)
-        assert any(
-            f.code == "ktwin-coverage" and "_min64" in f.message
-            for f in findings
-        )
-
-    def test_vanished_manifest_twin_is_loud(self, tmp_path):
-        root = _copy_tree(tmp_path, _KTWIN_RELS)
-        _mutate(
-            root, PAIRS_REL,
-            "def _sat_add64(", "def _renamed_sat_add64(",
-        )
-        findings = kernel_twins.check(root)
-        assert any(
-            f.code == "ktwin-missing" and f.symbol == "_sat_add64"
-            for f in findings
-        )
-
-
 class TestWave3Registry:
     def test_checker_codes_registry_total(self):
         """Every registered checker declares its code prefixes — the
         partial-run waiver filter depends on this map being total."""
         assert set(CHECKER_CODES) == set(CHECKERS)
-        for name in ("wire", "harden", "status", "fault", "ktwin"):
+        for name in ("wire", "harden", "status", "fault"):
             assert name in CHECKER_CODES
 
     def test_stale_wave3_waivers_ratchet(self):
@@ -1934,7 +1841,6 @@ class TestWave3Registry:
             ("harden-trailing", CLUSTER_REL),
             ("status-transport", "throttlecrab_tpu/server/http.py"),
             ("fault-site", INJECTOR_REL),
-            ("ktwin-drift", PAIRS_REL),
         ):
             w = Waiver(code, path, symbol="ghost", reason="r")
             unwaived, stale = apply_baseline(findings, [w])
@@ -1960,7 +1866,7 @@ class TestWave3Registry:
         )
         assert proc.returncode == 2
         assert "unknown checks" in proc.stderr
-        assert "ktwin" in proc.stderr  # the valid roster is listed
+        assert "fault" in proc.stderr  # the valid roster is listed
 
     def test_cli_wave3_partial_run_times_each_checker(self):
         proc = subprocess.run(
@@ -1970,7 +1876,7 @@ class TestWave3Registry:
                 "--json",
                 "--strict",
                 "--checks",
-                "wire,harden,status,fault,ktwin",
+                "wire,harden,status,fault",
             ],
             capture_output=True,
             text=True,
@@ -1979,7 +1885,7 @@ class TestWave3Registry:
         assert proc.returncode == 0, proc.stdout + proc.stderr
         report = json.loads(proc.stdout)
         assert set(report["checker_s"]) == {
-            "wire", "harden", "status", "fault", "ktwin",
+            "wire", "harden", "status", "fault",
         }
         assert report["findings"] == []
         assert report["jax_imported"] is False

@@ -1,21 +1,34 @@
 """Packed-launch path: one fused i32 buffer must decide identically to the
-eight-array scan path, and the C++ assembler must emit exactly what the
-Python resolve + numpy packing emits."""
+eight-array scan path, the served packed scans must decide as the scalar
+oracle does, and the C++ assembler must emit exactly what the Python
+resolve + numpy packing emits."""
 
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
+from throttlecrab_tpu.core.i64 import I64_MAX
+from throttlecrab_tpu.core.rate_limiter import RateLimiter
+from throttlecrab_tpu.core.store.mapstore import MapStore
 from throttlecrab_tpu.tpu.kernel import (
     EMPTY_EXPIRY,
+    INS_WIDTH,
     PACK_WIDTH,
+    finish_cur,
+    finish_w32,
+    fits_cur_wire,
+    fits_w32_wire,
     gcra_scan,
     gcra_scan_packed,
+    gcra_scan_packed_acc,
+    gcra_scan_packed_ins,
     pack_requests,
     pack_state,
+    unpack_deny,
     unpack_state,
 )
+from throttlecrab_tpu.tpu.limiter import has_degenerate
 
 NS = 1_000_000_000
 BASE = 1_753_700_000 * NS
@@ -109,6 +122,288 @@ def test_pack_requests_roundtrips_i64_extremes():
     lo = packed[:, 3].view(np.uint32).astype(np.int64)
     hi = packed[:, 4].astype(np.int64)
     np.testing.assert_array_equal((hi << 32) | lo, vals)
+
+
+# ---------------------------------------------------------------------- #
+# The served packed scans (gcra_scan_packed_acc on 4-wide rows,
+# gcra_scan_packed_ins on INS_WIDTH rows) against the scalar oracle,
+# request by request, on hostile windows.
+
+
+def _fresh_state(rows, width):
+    st = pack_state(
+        jnp.zeros((rows,), jnp.int64),
+        jnp.full((rows,), EMPTY_EXPIRY, jnp.int64),
+    )
+    if width > 4:
+        st = jnp.concatenate(
+            [st, jnp.zeros((rows, width - 4), jnp.int32)], axis=-1
+        )
+    return st
+
+
+def _rand_window(rng, K, B, cap, degen, w32=False):
+    """A hostile window: duplicate segments, degenerate params (when
+    `degen`), invalid lanes, saturating-scale values.  `w32` draws the
+    certified params inside the w32 output tier's field widths."""
+    slots = rng.integers(0, cap, (K, B)).astype(np.int32)
+    em = rng.choice([0, 1, 1000, NS, 7 * NS, 1 << 62], (K, B)).astype(
+        np.int64
+    )
+    tol = rng.choice(
+        [0, 5, NS, 100 * NS, (1 << 61) + 7, -(3 * NS)], (K, B)
+    ).astype(np.int64)
+    q = rng.choice([0, 1, 2, 50], (K, B)).astype(np.int64)
+    if w32:
+        em = rng.choice([NS // 10, NS, 7 * NS], (K, B)).astype(np.int64)
+        tol = em * rng.integers(1, 60, (K, B))
+        q = np.maximum(q, 1)
+    elif not degen:
+        em = np.maximum(em % (10 * NS), 1)
+        tol = np.abs(tol) % (100 * NS) + 1
+        q = np.maximum(q, 1)
+    valid = rng.random((K, B)) < 0.9
+    rank = np.zeros((K, B), np.int32)
+    is_last = np.ones((K, B), bool)
+    for k in range(K):
+        first: dict = {}
+        state: dict = {}
+        for i in range(B):
+            if not valid[k, i]:
+                continue
+            s = int(slots[k, i])
+            if s in state:
+                cnt, last = state[s]
+                rank[k, i] = cnt
+                is_last[k, last] = False
+                state[s] = (cnt + 1, i)
+                j = first[s]  # uniform params per segment
+                em[k, i], tol[k, i], q[k, i] = (
+                    em[k, j], tol[k, j], q[k, j],
+                )
+            else:
+                state[s] = (1, i)
+                first[s] = i
+    now = BASE + np.sort(rng.integers(0, 100 * NS, K)).astype(np.int64)
+    return (slots, rank, is_last, em, tol, q, valid), now
+
+
+class _SlotStore(MapStore):
+    """The oracle's store, keyed by table slot, with cleanup off.  It
+    counts the expired entries a segment's FIRST request replaces: the
+    kernel's expired-hit accumulator (which knowingly leaves out the
+    re-hits of a ttl-0 write later in the same segment)."""
+
+    def __init__(self):
+        super().__init__()
+        self.first_of_segment = False
+        self.expired_hits = 0
+
+    def _maybe_cleanup(self, now_ns):
+        pass
+
+    def _on_expired_hit_set(self):
+        self.expired_hits += self.first_of_segment
+
+
+def _oracle_window(oracle, deny, window, now):
+    """Decide a window request by request with the scalar GCRA; returns
+    the i64[K, 4, B] (allowed, remaining, reset_ns, retry_ns) planes,
+    valid lanes only, and advances `deny` (denied requests per slot)."""
+    slots, rank, _, em, tol, q, valid = window
+    K, B = slots.shape
+    out = np.zeros((K, 4, B), np.int64)
+    for k in range(K):
+        for i in np.flatnonzero(valid[k]):
+            slot = int(slots[k, i])
+            oracle.store.first_of_segment = rank[k, i] == 0
+            allowed, r = oracle.rate_limit_intervals(
+                slot, int(em[k, i]), int(tol[k, i]), int(q[k, i]),
+                int(now[k]),
+            )
+            out[k, :, i] = (
+                allowed,
+                r.remaining,
+                min(r.reset_after_ns, I64_MAX),
+                min(r.retry_after_ns, I64_MAX),
+            )
+            deny[slot] = deny.get(slot, 0) + (not allowed)
+    return out
+
+
+def _wire(planes):
+    """i64 (allowed, remaining, reset_ns, retry_ns) → the i32 wire
+    values every compact tier carries."""
+    i32 = (1 << 31) - 1
+    return np.stack(
+        [
+            planes[:, 0],
+            np.minimum(planes[:, 1], i32),
+            np.minimum(planes[:, 2] // NS, i32),
+            np.minimum(planes[:, 3] // NS, i32),
+        ],
+        axis=1,
+    )
+
+
+def _served_planes(out, compact, window, now):
+    """The launch's output in the oracle's plane layout [K, 4, B]."""
+    out = np.asarray(out)
+    if compact == "w32":
+        return np.stack(finish_w32(out), axis=1).astype(np.int64)
+    if compact == "cur":
+        _, _, _, em, tol, q, _ = window
+        return np.stack(
+            [
+                np.stack(finish_cur(out[k], em[k], tol[k], q[k], now[k]))
+                for k in range(len(now))
+            ]
+        ).astype(np.int64)
+    return out.astype(np.int64)
+
+
+def _assert_rows_match(state, oracle, deny, cap, ctx):
+    """Every real slot holds what the oracle's store holds (fresh where
+    it holds nothing), and on INS_WIDTH rows the denied-hit counter."""
+    rows = np.asarray(state)[:cap]
+    tat, exp = (np.asarray(x) for x in unpack_state(jnp.asarray(rows)))
+    for slot in range(cap):
+        tat_o, exp_o = oracle.store._data.get(slot, (0, EMPTY_EXPIRY))
+        assert tat[slot] == tat_o, f"{ctx}: tat of slot {slot}"
+        assert exp[slot] == min(exp_o, I64_MAX), f"{ctx}: expiry {slot}"
+    if rows.shape[-1] > 4:
+        want = np.array([deny.get(s, 0) for s in range(cap)], np.int64)
+        got = np.asarray(unpack_deny(jnp.asarray(rows)))
+        np.testing.assert_array_equal(got, want, err_msg=f"{ctx}: deny")
+
+
+def _run_served(seed, K, B, cap, width, compact, with_degen, steps=2):
+    """Drive the served packed scan at `width` over `steps` consecutive
+    windows and hold it to the scalar oracle: each valid lane's reply,
+    each real slot's stored row, the expired-hit accumulator and, at
+    INS_WIDTH, the [allowed, denied] totals."""
+    rng = np.random.default_rng(seed)
+    state = _fresh_state(cap + B, width)
+    exp_acc = jnp.zeros((), jnp.int64)
+    ins_counts = jnp.zeros((2,), jnp.int64)
+    oracle, deny = RateLimiter(_SlotStore()), {}
+    totals = np.zeros(2, np.int64)
+    tol_hwm = now_hwm = 0
+    for step in range(steps):
+        window, now = _rand_window(
+            rng, K, B, cap, with_degen, w32=compact == "w32"
+        )
+        now = now + step * 200 * NS
+        slots, rank, is_last, em, tol, q, valid = window
+        if not with_degen:
+            # The certificates the served path checks before it takes
+            # the certified body and the one-word output tiers.
+            assert not has_degenerate(valid, em, tol, q)
+            for k in range(K):
+                assert fits_cur_wire(tol[k][valid[k]], int(now[k]))
+                if compact == "w32":
+                    assert fits_w32_wire(
+                        valid[k], em[k], tol[k], q[k], int(now[k]),
+                        tol_hwm, now_hwm,
+                    )
+                tol_hwm = max(tol_hwm, int(tol[k].max(initial=0)))
+                now_hwm = max(now_hwm, int(now[k]))
+        packed = jnp.asarray(
+            pack_requests(slots, rank, is_last, em, tol, q, valid)
+        )
+        if width > 4:
+            state, exp_acc, ins_counts, out = gcra_scan_packed_ins(
+                state, exp_acc, ins_counts, packed, jnp.asarray(now),
+                with_degen=with_degen, compact=compact,
+            )
+        else:
+            state, exp_acc, out = gcra_scan_packed_acc(
+                state, exp_acc, packed, jnp.asarray(now),
+                with_degen=with_degen, compact=compact,
+            )
+        want = _oracle_window(oracle, deny, window, now)
+        allowed = want[:, 0][valid] != 0
+        totals += [allowed.sum(), (~allowed).sum()]
+        if compact is not False:
+            want = _wire(want)
+        got = _served_planes(out, compact, window, now)
+        ctx = f"{compact=} {with_degen=} {width=} step {step}"
+        bad = (got != want) & valid[:, None, :]
+        assert not bad.any(), f"{ctx}: replies {np.argwhere(bad)[:4]}"
+        _assert_rows_match(state, oracle, deny, cap, ctx)
+        assert int(exp_acc) == oracle.store.expired_hits, ctx
+        if width > 4:
+            np.testing.assert_array_equal(np.asarray(ins_counts), totals)
+
+
+@pytest.mark.parametrize("width", [4, INS_WIDTH])
+@pytest.mark.parametrize(
+    "compact,with_degen",
+    [(False, True), (True, True), (True, False), ("cur", False),
+     ("w32", False)],
+)
+def test_served_window_matches_oracle(width, compact, with_degen):
+    """Every output tier at both row widths, exact and certified
+    bodies: duplicate segments, degenerate orbits, invalid lanes, and
+    state carried across consecutive windows."""
+    _run_served(
+        7 * width + len(str(compact)), K=2, B=16, cap=32,
+        width=width, compact=compact, with_degen=with_degen,
+    )
+
+
+@pytest.mark.parametrize("K,B", [(1, 4), (1, 16), (3, 8), (2, 48)])
+@pytest.mark.parametrize(
+    "width,compact,with_degen",
+    [(4, True, True), (INS_WIDTH, "w32", False), (INS_WIDTH, True, True)],
+    ids=["w4-general", "served-w32", "served-general"],
+)
+def test_served_scan_shape_edges(K, B, width, compact, with_degen):
+    """One and several sub-batches, lane counts that are and are not
+    powers of two; the last two variants are SERVED_VARIANTS at the
+    served row width."""
+    _run_served(99 + K * B, K=K, B=B, cap=64, width=width,
+                compact=compact, with_degen=with_degen, steps=1)
+
+
+@pytest.mark.parametrize("width", [4, INS_WIDTH])
+def test_suppressed_writes_land_in_scratch_tail(width):
+    """A denied-everywhere window (one key, tolerance 0, quantity 2):
+    the real rows match the oracle, and the suppressed writes land in
+    the last B rows, never on a real slot nor elsewhere past capacity."""
+    B, cap = 16, 8
+    rows = cap + 2 * B
+    slots = np.zeros((1, B), np.int32)
+    rank = np.arange(B, dtype=np.int32)[None]
+    is_last = np.zeros((1, B), bool)
+    is_last[0, -1] = True
+    em = np.full((1, B), NS, np.int64)
+    tol = np.zeros((1, B), np.int64)
+    q = np.full((1, B), 2, np.int64)
+    valid = np.ones((1, B), bool)
+    window = (slots, rank, is_last, em, tol, q, valid)
+    now = np.array([BASE], np.int64)
+    packed = jnp.asarray(pack_requests(*window))
+    fresh = _fresh_state(rows, width)
+    if width > 4:
+        state, _, _, out = gcra_scan_packed_ins(
+            fresh, jnp.zeros((), jnp.int64), jnp.zeros((2,), jnp.int64),
+            packed, jnp.asarray(now), with_degen=True, compact=True,
+        )
+    else:
+        state, _, out = gcra_scan_packed_acc(
+            fresh, jnp.zeros((), jnp.int64), packed, jnp.asarray(now),
+            with_degen=True, compact=True,
+        )
+    oracle, deny = RateLimiter(_SlotStore()), {}
+    want = _wire(_oracle_window(oracle, deny, window, now))
+    assert not want[:, 0].any(), "the window must deny everywhere"
+    np.testing.assert_array_equal(np.asarray(out), want)
+    _assert_rows_match(state, oracle, deny, cap, f"{width=}")
+    np.testing.assert_array_equal(
+        np.asarray(state)[cap : rows - B],
+        np.asarray(_fresh_state(rows, width))[cap : rows - B],
+    )
 
 
 # ---------------------------------------------------------------------- #

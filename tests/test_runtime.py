@@ -9,6 +9,8 @@ from pathlib import Path
 
 import pytest
 
+from throttlecrab_tpu.tpu.table import SERVED_VARIANTS
+
 ROOT = Path(__file__).resolve().parent.parent
 
 
@@ -71,58 +73,37 @@ def test_bench_fails_without_tpu():
 
 
 @pytest.mark.parametrize(
+    "with_degen,compact", SERVED_VARIANTS, ids=["w32", "general"]
+)
+@pytest.mark.parametrize(
     "shape",
     [dict(), dict(shards=2), dict(shards=2, tenant_max=0)],
     ids=["single", "sharded-tenants", "sharded"],
 )
-def test_fused_knob_fails_at_boot_when_it_cannot_compile(
-    monkeypatch, shape
+def test_served_launch_compiles_on_the_tables_devices(
+    shape, with_degen, compact
 ):
-    """THROTTLECRAB_PALLAS_FUSED=1 whose served launch does not compile
-    for the backend raises at boot — never interpret mode or XLA behind
-    its back.  The gate compiles the launch the engine dispatches: the
-    packed scan, or the sharded scan step."""
+    """The table the server boots compiles the launch its served window
+    dispatches (the packed scan, or the sharded scan step) in each
+    served tier, onto the table's own devices: compile_launch needs no
+    more of a table than the chip-compile tests describe."""
     from throttlecrab_tpu.server.config import Config
     from throttlecrab_tpu.server.store import create_limiter
-    from throttlecrab_tpu.tpu import pallas_fused
-
-    monkeypatch.setattr(pallas_fused, "INTERPRET", False)
-    # create_limiter writes the knob into os.environ: monkeypatch it
-    # first, so teardown restores the unset value.
-    monkeypatch.setenv("THROTTLECRAB_PALLAS_FUSED", "1")
-    with pytest.raises(RuntimeError, match="THROTTLECRAB_PALLAS_FUSED=1"):
-        create_limiter(
-            Config(http=True, store_capacity=4096, pallas_fused=True,
-                   **shape)
-        )
-
-
-def test_fused_gate_compiles_the_dispatched_launch(monkeypatch):
-    """With the kernel interpreted, the gate compiles the jit the served
-    window dispatches, on the table's own device."""
-    from throttlecrab_tpu.server.config import Config
-    from throttlecrab_tpu.server.store import create_limiter
-    from throttlecrab_tpu.tpu import pallas_fused
-
-    monkeypatch.setenv("THROTTLECRAB_PALLAS_FUSED", "1")  # restored after
-    table = create_limiter(
-        Config(http=True, store_capacity=1024, pallas_fused=True,
-               batch_size=16, max_scan_depth=1)
-    ).table
-    assert table._packed_launch()[0] in (
-        pallas_fused.gcra_scan_packed_fused_acc,
-        pallas_fused.gcra_scan_packed_fused_ins,
+    from throttlecrab_tpu.tpu.kernel import (
+        gcra_scan_packed_acc,
+        gcra_scan_packed_ins,
     )
-    compiled = table.compile_launch(1, 16, with_degen=False, compact="w32")
-    assert compiled.output_shardings[-1].device_set == (
+
+    table = create_limiter(
+        Config(http=True, store_capacity=4096, **shape)
+    ).table
+    if not shape:
+        assert table._packed_launch()[0] is (
+            gcra_scan_packed_ins if table.insight else gcra_scan_packed_acc
+        )
+    compiled = table.compile_launch(
+        1, 16, with_degen=with_degen, compact=compact
+    )
+    assert compiled.output_shardings[0].device_set == (
         table.state.sharding.device_set
     )
-
-
-def test_fused_knob_left_unset():
-    """Runs after the knob tests in this file's worker: none of them
-    leaks THROTTLECRAB_PALLAS_FUSED=1 into the servers later tests boot
-    (create_limiter writes the resolved value, so "0" may remain)."""
-    from throttlecrab_tpu.tpu.kernel import pallas_fused_enabled
-
-    assert not pallas_fused_enabled()

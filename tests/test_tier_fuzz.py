@@ -40,18 +40,13 @@ def test_tier_ladder_fuzz_slice(seed):
 
 
 @pytest.mark.parametrize("seed", [3100, 3101])  # edges/hostile profiles
-def test_tier_ladder_fuzz_fused_alternation(seed, monkeypatch):
-    """The fused Pallas decision kernel alternated with the composed-XLA
-    path across consecutive windows of the tier-ladder corpus: both stay
-    pinned to the scalar oracle request-by-request, and each continues
-    exactly from the table state the other left (the kill-switch
-    stored-state compatibility contract).  Odd seeds arm the insight
-    tier on BOTH the single-device limiter and the mesh, covering the
-    fused kernel's 6-wide row template; even seeds pin the 4-wide one.
-    The hostile profile (3101) drives the degenerate three-view orbit
-    and the tier ladder's mid-stream downgrades through the fused path.
-    """
-    monkeypatch.setenv("THROTTLECRAB_PALLAS_FUSED", "0")
+def test_tier_ladder_fuzz_insight_single(seed):
+    """The edges and hostile profiles through the composed-XLA tier
+    ladder, pinned to the scalar oracle request-by-request.  Odd seeds
+    arm the insight tier on the single-device limiter too (the
+    INS_WIDTH rows and the gcra_*_ins launches), which the plain slice
+    never arms; the hostile profile (3101) drives the degenerate
+    three-view orbit and the ladder's mid-stream downgrades there."""
     from conftest import require_devices
 
     try:
@@ -63,8 +58,7 @@ def test_tier_ladder_fuzz_fused_alternation(seed, monkeypatch):
         mesh = None
     before = fuzz.TOTAL["requests"]
     fuzz.run_seed(
-        seed, steps=6, sharded_mesh=mesh,
-        fused_alternate=True, insight_single=bool(seed % 2),
+        seed, steps=6, sharded_mesh=mesh, insight_single=bool(seed % 2)
     )
     assert fuzz.TOTAL["requests"] > before
 

@@ -49,12 +49,7 @@ those invariants mechanically, on every PR, in seconds:
   * ``fault``        — fault-site registry: ``SITES``/``MODES``
     bidirectionally consistent with the armed hook call sites, the
     typed ``_site_error`` arms, the replay path, and the README
-    fault-site table;
-  * ``ktwin``        — kernel-twin contract: XLA closed forms and the
-    i32-pair library normalized into one op-DAG IR; structural pairs
-    must match, declared pairs must cite their twin, transcribed
-    bodies must cover every op kind, and anything else needs an
-    explicit ``# twin: xla-only(reason)`` marker.
+    fault-site table.
 
 Pure stdlib, AST-based plus a small C++ token scanner: importing this
 package (or running ``scripts/check_invariants.py``) must never import
@@ -76,7 +71,6 @@ from . import (
     fault_surface,
     i64_hygiene,
     jit_boundary,
-    kernel_twins,
     lock_order,
     registry,
     status_surface,
@@ -97,7 +91,6 @@ CHECKERS = {
     "harden": wire_surface.check_hardening,
     "status": status_surface.check,
     "fault": fault_surface.check,
-    "ktwin": kernel_twins.check,
 }
 
 #: checker name -> the finding-code prefixes it emits.  The CLI uses
@@ -116,7 +109,6 @@ CHECKER_CODES = {
     "harden": ("harden",),
     "status": ("status",),
     "fault": ("fault",),
-    "ktwin": ("ktwin",),
 }
 assert set(CHECKER_CODES) == set(CHECKERS)
 

@@ -125,8 +125,27 @@ class RateLimiter:
         emission_interval_ns, tolerance_ns = derive_intervals(
             max_burst, count_per_period, period
         )
-        now_ns = normalize_now_ns(now_ns, period)
+        return self.rate_limit_intervals(
+            key,
+            emission_interval_ns,
+            tolerance_ns,
+            quantity,
+            normalize_now_ns(now_ns, period),
+            limit=max_burst,
+        )
 
+    def rate_limit_intervals(
+        self,
+        key: str,
+        emission_interval_ns: int,
+        tolerance_ns: int,
+        quantity: int,
+        now_ns: int,
+        limit: int = 0,
+    ) -> tuple[bool, RateLimitResult]:
+        """The GCRA check of :meth:`rate_limit` from already-derived i64
+        intervals (what the device kernels take per request), at a
+        non-negative `now_ns`; `limit` is reported back unchanged."""
         retries = 0
         while True:
             tat_val = self.store.get(key, now_ns)
@@ -175,7 +194,7 @@ class RateLimiter:
             )
 
             return allowed, RateLimitResult(
-                limit=max_burst,
+                limit=limit,
                 remaining=remaining,
                 reset_after_ns=reset_after_ns,
                 retry_after_ns=retry_after_ns,

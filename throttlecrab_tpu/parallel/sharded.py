@@ -206,17 +206,9 @@ class ShardedBucketTable(HwmMarksMixin):
 
         `compact` may be "cur" (one i64/request off the mesh, see
         kernel._finish) — the output rank and the allowed-counter read
-        change with it.  With THROTTLECRAB_PALLAS_FUSED=1 the per-shard
-        body is the fused Pallas kernel (pallas_fused.fused_window):
-        each device runs the identical one-launch fused program on its
-        slice, and the per-launch counter psums below are untouched."""
-        from ..tpu.kernel import pallas_fused_enabled
-
+        change with it."""
         T = self.tenant_slots
-        fused = pallas_fused_enabled()
-        if fused:
-            from ..tpu import pallas_fused
-        key = (with_degen, compact, T, fused)
+        key = (with_degen, compact, T)
         fn = self._step_cache.get(key)
         if fn is not None:
             return fn
@@ -226,33 +218,22 @@ class ShardedBucketTable(HwmMarksMixin):
 
         def local(state, slots, rank, is_last, em, tol, q, valid, now,
                   *tenant):
-            if fused:
-                packed = pallas_fused.pack_requests_traced(
-                    slots[0], rank[0], is_last[0], em[0], tol[0], q[0],
+            st, out, n_exp = _gcra_body(
+                state[0],
+                (
+                    slots[0],
+                    rank[0].astype(jnp.int64),
+                    is_last[0],
+                    em[0],
+                    tol[0],
+                    q[0],
                     valid[0],
-                )[None]
-                st, out_k, nexp = pallas_fused.fused_window(
-                    state[0], packed, jnp.reshape(now, (1,)),
-                    with_degen=with_degen, compact=compact,
-                )
-                out, n_exp = out_k[0], nexp[0]
-            else:
-                st, out, n_exp = _gcra_body(
-                    state[0],
-                    (
-                        slots[0],
-                        rank[0].astype(jnp.int64),
-                        is_last[0],
-                        em[0],
-                        tol[0],
-                        q[0],
-                        valid[0],
-                        now,
-                    ),
-                    with_degen=with_degen,
-                    compact=compact,
-                    count_expired=True,
-                )
+                    now,
+                ),
+                with_degen=with_degen,
+                compact=compact,
+                count_expired=True,
+            )
             allowed_b = ((out & 1) != 0) if cur else (out[0] != 0)
             denied_b = valid[0] & ~allowed_b
             n_allowed = jnp.sum(allowed_b.astype(jnp.int64))
@@ -287,10 +268,6 @@ class ShardedBucketTable(HwmMarksMixin):
             mesh=self.mesh,
             in_specs=tuple(in_specs),
             out_specs=tuple(out_specs),
-            # shard_map has no replication rule for pallas_call; the
-            # fused body's outputs follow the same specs as the XLA
-            # body's, so skipping the check is sound.
-            **({"check_vma": False} if fused else {}),
         )
         fn = jax.jit(mapped, donate_argnums=(0,))
         self._step_cache[key] = fn
@@ -360,53 +337,13 @@ class ShardedBucketTable(HwmMarksMixin):
         lax.scan carry is the shard's state), so one launch decides K×D
         sub-batches; the only collectives are one psum of the summed
         counters (and the summed per-tenant counters) after the scan.
-        With THROTTLECRAB_PALLAS_FUSED=1 the whole K-deep per-shard scan
-        is ONE fused pallas launch (the kernel grid walks the K
-        sub-batches, state carried by aliasing) — same psums after.
         """
-        from ..tpu.kernel import pallas_fused_enabled
-
         T = self.tenant_slots
-        fused = pallas_fused_enabled()
-        if fused:
-            from ..tpu import pallas_fused
-        key = ("scan", with_degen, compact, T, fused)
+        key = ("scan", with_degen, compact, T)
         fn = self._step_cache.get(key)
         if fn is not None:
             return fn
         cur = compact in ("cur", "w32")  # one word/request, allowed at bit 0
-
-        def local_fused(state, slots, rank, is_last, em, tol, q, valid,
-                        now, *tenant):
-            packed = pallas_fused.pack_requests_traced(
-                slots[0], rank[0], is_last[0], em[0], tol[0], q[0],
-                valid[0],
-            )
-            st, outs, nexp = pallas_fused.fused_window(
-                state[0], packed, now,
-                with_degen=with_degen, compact=compact,
-            )
-            allowed_kb = ((outs & 1) != 0) if cur else (outs[:, 0, :] != 0)
-            denied_kb = valid[0] & ~allowed_kb
-            n_allowed = jnp.sum(allowed_kb.astype(jnp.int64))
-            n_valid = jnp.sum(valid[0].astype(jnp.int64))
-            counters = lax.psum(
-                jnp.stack(
-                    [n_allowed, n_valid - n_allowed, jnp.sum(nexp)]
-                ),
-                AXIS,
-            )
-            if not T:
-                return st[None], outs[None], counters
-            tcounts = lax.psum(
-                self._tenant_fold(
-                    tenant[0][0].reshape(-1),
-                    allowed_kb.reshape(-1),
-                    denied_kb.reshape(-1),
-                ),
-                AXIS,
-            )
-            return st[None], outs[None], counters, tcounts
 
         def local(state, slots, rank, is_last, em, tol, q, valid, now,
                   *tenant):
@@ -460,13 +397,10 @@ class ShardedBucketTable(HwmMarksMixin):
             in_specs.append(P(AXIS, None, None))
             out_specs.append(P())
         mapped = _shard_map(
-            local_fused if fused else local,
+            local,
             mesh=self.mesh,
             in_specs=tuple(in_specs),
             out_specs=tuple(out_specs),
-            # No shard_map replication rule exists for pallas_call; the
-            # fused body's outputs follow the XLA body's specs exactly.
-            **({"check_vma": False} if fused else {}),
         )
         fn = jax.jit(mapped, donate_argnums=(0,))
         self._step_cache[key] = fn
@@ -474,8 +408,7 @@ class ShardedBucketTable(HwmMarksMixin):
 
     def compile_launch(self, depth: int, batch: int, *, with_degen, compact):
         """Compile, without running, the scan step check_many launches
-        for `depth` sub-batches of `batch` lanes per shard (the boot
-        gate of pallas_fused.require_compiles)."""
+        for `depth` sub-batches of `batch` lanes per shard."""
         lanes = (self.n_shards, depth, batch)
 
         def lane(dtype):

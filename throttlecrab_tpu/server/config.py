@@ -88,14 +88,6 @@ _SPEC = [
      "Route keys by their tenant/namespace hash instead of the full "
      "key, making each tenant's keys shard-local (keys without a "
      "delimiter still spread by full-key hash)"),
-    ("pallas_fused", "THROTTLECRAB_PALLAS_FUSED", False, bool,
-     "Route decision windows through the fused Pallas kernel "
-     "(tpu/pallas_fused.py): the entire per-window GCRA decision — "
-     "unpack, row gather, closed forms, pack, scatter — in ONE device "
-     "launch, width-polymorphic (coexists with insight) and "
-     "mesh-composable.  Off (default) keeps the composed-XLA kernels — "
-     "the kill switch; off-TPU the fused kernel runs in interpret "
-     "mode: bit-exact but slow, for tests only"),
     ("profile_dir", "THROTTLECRAB_PROFILE_DIR", "", str,
      "Directory for an xprof trace of the process's first 50 launches, "
      "on any transport (empty: off)"),
@@ -324,7 +316,6 @@ class Config:
     tenant_delim: str = ":"
     tenant_quota: float = 0.0
     tenant_affinity: bool = False
-    pallas_fused: bool = False
     profile_dir: str = ""
     front_deny_cache: int = 65536
     front_max_pending: int = 100_000

@@ -18,37 +18,6 @@ log = logging.getLogger("throttlecrab.store")
 
 def create_limiter(config):
     """Build the device limiter the engine will drive."""
-    if hasattr(config, "pallas_fused"):
-        # The fused-kernel switch is read from the environment at every
-        # dispatch (kernel.pallas_fused_enabled); write the RESOLVED
-        # config value back in BOTH directions — config already folded
-        # CLI > env > default, and a one-way write would let a stale
-        # "1" from an earlier limiter in this process defeat a later
-        # config's kill switch.
-        import os
-
-        os.environ["THROTTLECRAB_PALLAS_FUSED"] = (
-            "1" if config.pallas_fused else "0"
-        )
-    limiter = _build_limiter(config)
-    if getattr(config, "pallas_fused", False):
-        # The knob must compile the served launches at boot: it never
-        # falls back to XLA or to interpret mode.
-        from ..tpu import pallas_fused
-
-        pallas_fused.require_compiles(
-            limiter.table,
-            _pow2(config.max_scan_depth),
-            _pow2(max(config.batch_size, limiter.MIN_PAD)),
-        )
-    return limiter
-
-
-def _pow2(n: int) -> int:
-    return 1 << (max(n, 1) - 1).bit_length()
-
-
-def _build_limiter(config):
     if config.shards > 1:
         from ..parallel.sharded import ShardedTpuRateLimiter, make_mesh
         from ..parallel.tenants import TenantRegistry
@@ -204,10 +173,10 @@ def create_insight(config, metrics, limiter, front):
     dev = getattr(limiter, "inner", limiter)
     table = getattr(dev, "table", None)
     if table is None or not getattr(table, "insight", False):
-        # Loud, not silent (mirrors the Pallas-downgrade warning): the
-        # operator asked for insight but this limiter cannot carry the
-        # widened analytics rows, so /stats, the deny-cache prewarm and
-        # the admission feedback loop are all dropped for this boot.
+        # Loud, not silent: the operator asked for insight but this
+        # limiter cannot carry the widened analytics rows, so /stats,
+        # the deny-cache prewarm and the admission feedback loop are all
+        # dropped for this boot.
         log.warning(
             "insight tier requested (THROTTLECRAB_INSIGHT=1) but the "
             "%s limiter's table does not carry the insight "

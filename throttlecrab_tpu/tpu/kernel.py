@@ -59,14 +59,10 @@ stacked [K, B] inputs, each
 sub-batch with its own server timestamp) and fetches one stacked [K, 4, B]
 output.  Single-batch `gcra_batch` is the same body without the scan.
 
-Within one launch the body still compiles to 5+ composed XLA ops per
-sub-batch (unpack, gather, closed forms, pack, scatter), each
-materializing intermediates to HBM; `pallas_fused.py`
-(THROTTLECRAB_PALLAS_FUSED=1, dispatched by BucketTable/
-ShardedBucketTable) fuses the whole window into one Pallas kernel with
-the i64 math decomposed into i32 hi/lo pairs.  This module remains the
-default path, the kill switch, and the bit-exactness oracle the fused
-kernel is pinned against.
+Within one launch the body compiles to 5+ composed XLA ops per
+sub-batch (unpack, gather, closed forms, pack, scatter).  These
+composed-XLA programs are the only decision path: BucketTable and
+ShardedBucketTable call them directly.
 """
 
 from __future__ import annotations
@@ -98,21 +94,6 @@ _U32 = (1 << 32) - 1
 PACK_WIDTH = 9
 PACK_FLAG_IS_LAST = 1
 PACK_FLAG_VALID = 2
-
-
-def pallas_fused_enabled() -> bool:
-    """Whether decision windows route through the fused Pallas kernel
-    (pallas_fused.py; THROTTLECRAB_PALLAS_FUSED).  The canonical parse,
-    living here so the kill-switch check never imports the
-    jax.experimental.pallas stack: with the knob unset (or any falsy
-    spelling) the default composed-XLA path stays fully isolated from
-    the fused module.  Truthy spellings match config._env_bool exactly
-    — the _SPEC-registered flag and this env read must never disagree
-    about whether the kill switch is engaged."""
-    import os
-
-    value = os.environ.get("THROTTLECRAB_PALLAS_FUSED", "")
-    return value.lower() in ("1", "true", "yes", "on")
 
 
 def pack_state(tat, expiry):

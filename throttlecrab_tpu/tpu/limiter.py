@@ -476,9 +476,7 @@ class TpuRateLimiter(ScalarCompatMixin):
     """Batched GCRA over a device bucket table + host keymap."""
 
     # Batches are padded to a power of two of at least MIN_PAD lanes:
-    # few distinct jit-cache shapes as traffic varies, AND at least the
-    # fused Pallas kernel's DMA ring depth (pallas_fused.RING == 16) so
-    # its pipelines never run shorter than their in-flight window.
+    # few distinct jit-cache shapes as traffic varies.
     MIN_PAD = 16
 
     def __init__(

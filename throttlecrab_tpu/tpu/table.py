@@ -101,21 +101,6 @@ def tats_cur_safe(tats) -> bool:
     )
 
 
-def _fused_enabled() -> bool:
-    """Route decision windows through the fused Pallas kernel
-    (pallas_fused.py; THROTTLECRAB_PALLAS_FUSED=1).  Read per dispatch
-    — the fused wrappers and the composed-XLA twins are separate jit
-    entry points, so the flag flips between launches without retracing
-    tricks and unset preserves byte-identical current behavior.  The
-    check itself must not import pallas_fused: with the kill switch
-    engaged the default path stays isolated from the experimental
-    pallas stack (kernel.pallas_fused_enabled is the canonical parse).
-    """
-    from .kernel import pallas_fused_enabled
-
-    return pallas_fused_enabled()
-
-
 class StaleIdRowsError(RuntimeError):
     """Device-resident by-id parameter rows refer to slots the keymap has
     since remapped (sweep freed them or the table grew); re-run
@@ -168,6 +153,11 @@ class HwmMarksMixin:
             self.now_hwm = I64_MAX
         else:
             self.now_hwm = max(self.now_hwm, int(now_ns))
+
+
+# The (with_degen, compact) launch variants a served window takes: the
+# certified w32 tier, and the general tier any window can fall back to.
+SERVED_VARIANTS = ((False, "w32"), (True, True))
 
 
 class BucketTable(HwmMarksMixin):
@@ -233,10 +223,7 @@ class BucketTable(HwmMarksMixin):
         """Widen the state rows to kernel.INS_WIDTH (appending
         zero-initialized denied-hit counter columns), allocate the
         totals accumulator, and route decision launches through the
-        gcra_*_ins kernel twins.  Idempotent.  The fused decision
-        kernel (THROTTLECRAB_PALLAS_FUSED) is width-polymorphic — its
-        6-wide template folds the denied-hit counter into the same row
-        DMAs, so insight and the fused Pallas path coexist.
+        gcra_*_ins kernel twins.  Idempotent.
         """
         from .kernel import INS_WIDTH
 
@@ -344,24 +331,7 @@ class BucketTable(HwmMarksMixin):
             jnp.asarray(valid, bool),
             now_ns,
         )
-        if _fused_enabled():
-            from . import pallas_fused
-
-            if self.insight:
-                self.state, self.exp_acc, self.ins_counts, out = (
-                    pallas_fused.gcra_batch_fused_ins(
-                        self.state, self.exp_acc, self.ins_counts, *args,
-                        with_degen=with_degen, compact=compact,
-                    )
-                )
-            else:
-                self.state, self.exp_acc, out = (
-                    pallas_fused.gcra_batch_fused_acc(
-                        self.state, self.exp_acc, *args,
-                        with_degen=with_degen, compact=compact,
-                    )
-                )
-        elif self.insight:
+        if self.insight:
             self.state, self.exp_acc, self.ins_counts, out = (
                 gcra_batch_ins(
                     self.state, self.exp_acc, self.ins_counts, *args,
@@ -405,24 +375,7 @@ class BucketTable(HwmMarksMixin):
             jnp.asarray(valid, bool),
             jnp.asarray(now_ns, jnp.int64),
         )
-        if _fused_enabled():
-            from . import pallas_fused
-
-            if self.insight:
-                self.state, self.exp_acc, self.ins_counts, out = (
-                    pallas_fused.gcra_scan_fused_ins(
-                        self.state, self.exp_acc, self.ins_counts, *args,
-                        with_degen=with_degen, compact=compact,
-                    )
-                )
-            else:
-                self.state, self.exp_acc, out = (
-                    pallas_fused.gcra_scan_fused_acc(
-                        self.state, self.exp_acc, *args,
-                        with_degen=with_degen, compact=compact,
-                    )
-                )
-        elif self.insight:
+        if self.insight:
             self.state, self.exp_acc, self.ins_counts, out = (
                 gcra_scan_ins(
                     self.state, self.exp_acc, self.ins_counts, *args,
@@ -488,22 +441,13 @@ class BucketTable(HwmMarksMixin):
             carry = (self.state, self.exp_acc, self.ins_counts)
         else:
             carry = (self.state, self.exp_acc)
-        if _fused_enabled():
-            from . import pallas_fused
-
-            return (
-                pallas_fused.gcra_scan_packed_fused_ins
-                if self.insight
-                else pallas_fused.gcra_scan_packed_fused_acc
-            ), carry
         return (
             gcra_scan_packed_ins if self.insight else gcra_scan_packed_acc
         ), carry
 
     def compile_launch(self, depth: int, batch: int, *, with_degen, compact):
         """Compile, without running, the launch check_many_packed makes
-        for `depth` sub-batches of `batch` lanes (the boot gate of
-        pallas_fused.require_compiles)."""
+        for `depth` sub-batches of `batch` lanes."""
         from .kernel import PACK_WIDTH
 
         fn, carry = self._packed_launch()
