@@ -19,6 +19,18 @@
 // Python dict popped from its front (`next(iter(d))`) walks every hole
 // earlier pops left, ~12 us a call on the serving path.
 //
+// Layout.  On uniform traffic every row is a key the cache has long
+// forgotten: the filter creates its KeyState for the hold, the observe
+// records its write, and the oldest record's key is freed.  So KeyStates
+// live in one pool (a vector with a free list, reached by u32 index: the
+// vector moves as it grows, so links are indices, never pointers), keys
+// up to KEY_INLINE bytes sit inside their KeyState, and the key index is
+// open addressing over u32 pool indices with hash tags (linear probing,
+// backward-shift deletion, doubled at half load).  Once the pool and the
+// index reach the working set, a row allocates nothing.  The frame path
+// hands the filter's pool indices to the observe, which checks each
+// (live, same key bytes) before it trusts it.
+//
 // One mutex guards all state.  Every entry point takes it and releases
 // it before returning, and nothing here calls back into Python, so it is
 // a leaf lock; ctypes drops the GIL around each call.
@@ -28,7 +40,6 @@
 #include <cstdint>
 #include <cstring>
 #include <mutex>
-#include <string>
 #include <string_view>
 #include <unordered_map>
 #include <vector>
@@ -46,10 +57,15 @@ constexpr int64_t NSEC = 1000000000ll;
 // Cached-denial outcome of one row in the frame filter.
 constexpr uint8_t ROW_SERVED = 255;
 
-struct KeyState;
+// No KeyState: an empty index slot, the end of a list.
+constexpr uint32_t NIL = UINT32_MAX;
+// Keys up to this many bytes live inside their KeyState.
+constexpr size_t KEY_INLINE = 24;
+// Rows ahead whose index slot a window loop prefetches.
+constexpr int64_t AHEAD = 8;
 
 struct Entry {
-    KeyState* ks;
+    uint32_t ks;  // the key's pool index
     int64_t mb, cpp, per, q;  // the combo (with the key)
     int64_t tat, em, tol, inc, limit, expiry;
     Entry* prev = nullptr;  // entry FIFO
@@ -60,20 +76,29 @@ struct Entry {
 };
 
 struct KeyState {
-    std::string key;
-    int64_t inflight = 0;
+    uint32_t hash = 0;  // low half of the key's hash: home slot and tag
+    uint32_t klen = 0;
+    union {
+        char kin[KEY_INLINE];  // klen <= KEY_INLINE
+        char* kfar;            // else a buffer of its own
+    };
+    bool live = false;
     bool has_rec = false;
+    int64_t inflight = 0;
     int64_t rec_tat = 0, rec_tol = 0, rec_seq = 0;
-    KeyState* rprev = nullptr;  // record FIFO (by last write)
-    KeyState* rnext = nullptr;
+    uint32_t rprev = NIL;  // record FIFO (by last write)
+    uint32_t rnext = NIL;  // ... and, while free, the free list
     Entry* ehead = nullptr;
     Entry* etail = nullptr;
     uint64_t hit_stamp = 0;  // frame filter: distinct served keys
     int64_t hit_slot = 0;
+
+    const char* key() const { return klen <= KEY_INLINE ? kin : kfar; }
+    std::string_view key_view() const { return {key(), klen}; }
 };
 
 struct Combo {
-    const KeyState* ks;
+    uint32_t ks;
     int64_t mb, cpp, per, q;
     bool operator==(const Combo& o) const {
         return ks == o.ks && mb == o.mb && cpp == o.cpp && per == o.per &&
@@ -88,7 +113,7 @@ inline uint64_t mix(uint64_t h, uint64_t v) {
 
 struct ComboHash {
     size_t operator()(const Combo& c) const {
-        uint64_t h = reinterpret_cast<uintptr_t>(c.ks);
+        uint64_t h = c.ks;
         h = mix(h, static_cast<uint64_t>(c.mb));
         h = mix(h, static_cast<uint64_t>(c.cpp));
         h = mix(h, static_cast<uint64_t>(c.per));
@@ -97,16 +122,29 @@ struct ComboHash {
     }
 };
 
-struct KeyHash {
-    size_t operator()(std::string_view s) const {
-        uint64_t h = 1469598103934665603ull;  // FNV-1a, as the keymap
-        for (unsigned char c : s) {
-            h ^= c;
-            h *= 1099511628211ull;
-        }
-        return static_cast<size_t>(h);
+// Eight bytes a step, then a 64-bit finaliser (murmur3's fmix64), so
+// keys that differ in one digit (`gw:0000001`, `gw:0000002`) land far
+// apart in the low bits the index takes its slot from.
+inline uint64_t key_hash(std::string_view s) {
+    const char* p = s.data();
+    size_t n = s.size();
+    uint64_t h = 0x9e3779b97f4a7c15ull ^ n;
+    for (; n >= 8; p += 8, n -= 8) {
+        uint64_t w;
+        memcpy(&w, p, 8);
+        h = (h ^ w) * 0xff51afd7ed558ccdull;
+        h ^= h >> 29;
     }
-};
+    uint64_t w = 0;
+    memcpy(&w, p, n);
+    h = (h ^ w) * 0xc4ceb9fe1a85ec53ull;
+    h ^= h >> 33;
+    h *= 0xff51afd7ed558ccdull;
+    h ^= h >> 33;
+    h *= 0xc4ceb9fe1a85ec53ull;
+    h ^= h >> 33;
+    return h;
+}
 
 struct Hit {
     int64_t limit, remaining, reset, retry;
@@ -121,112 +159,236 @@ inline std::string_view row_key(const char* blob, const int64_t* off,
 // How a denied observation names its exact effective TAT.
 enum TatFrom { TAT_CUR, TAT_PLANES, TAT_NONE };
 
+// One slot of the key index: a pool index (NIL: empty) and its key's
+// hash tag.
+struct Slot {
+    uint32_t ks;
+    uint32_t tag;
+};
+
 struct DenyCache {
     std::mutex mu;
     int64_t capacity;
-    std::unordered_map<std::string_view, KeyState*, KeyHash> keys;
+    std::vector<KeyState> pool;
+    uint32_t free_head = NIL;
+    std::vector<Slot> index;  // a power of two, at most half full
+    size_t mask = 0;
+    size_t n_keys = 0;
     std::unordered_map<Combo, Entry*, ComboHash> entries;
     Entry* ehead = nullptr;
     Entry* etail = nullptr;
-    KeyState* rhead = nullptr;
-    KeyState* rtail = nullptr;
+    uint32_t rhead = NIL;
+    uint32_t rtail = NIL;
     int64_t n_records = 0;
     int64_t seq = 0;
     int64_t hits = 0;
     int64_t stale = 0;
+    int64_t handle_fallbacks = 0;
     uint64_t stamp = 0;
-    // Frame-filter scratch, reused across calls (guarded by mu).
+    // Window scratch, reused across calls (guarded by mu).
+    std::vector<uint32_t> row_hash;
     std::vector<uint8_t> hit_row;
     std::vector<int64_t> hit_vals;
-    std::vector<KeyState*> row_ks;
+    std::vector<uint32_t> row_ks;
 
     explicit DenyCache(int64_t cap) : capacity(cap) {
         const size_t pre = static_cast<size_t>(cap < (1 << 20) ? cap : 1 << 20);
-        keys.reserve(pre * 2);
+        size_t slots = 16;
+        while (slots < pre * 2) slots <<= 1;
+        index.assign(slots, Slot{NIL, 0});
+        mask = slots - 1;
+        pool.reserve(pre);
         entries.reserve(pre);
     }
 
     ~DenyCache() {
-        for (auto& kv : keys) {
-            KeyState* ks = kv.second;
-            for (Entry* e = ks->ehead; e != nullptr;) {
+        for (KeyState& s : pool) {
+            if (!s.live) continue;
+            for (Entry* e = s.ehead; e != nullptr;) {
                 Entry* nx = e->knext;
                 delete e;
                 e = nx;
             }
-            delete ks;
+            if (s.klen > KEY_INLINE) delete[] s.kfar;
         }
     }
 
     // -- keys ---------------------------------------------------------- //
 
-    KeyState* find(std::string_view k) {
-        auto it = keys.find(k);
-        return it == keys.end() ? nullptr : it->second;
+    bool key_is(uint32_t ks, std::string_view k) const {
+        const KeyState& s = pool[ks];
+        return s.klen == k.size() && memcmp(s.key(), k.data(), k.size()) == 0;
     }
 
-    KeyState* get(std::string_view k) {
-        KeyState* ks = find(k);
-        if (ks != nullptr) return ks;
-        ks = new KeyState;
-        ks->key.assign(k.data(), k.size());
-        keys.emplace(std::string_view(ks->key), ks);
-        return ks;
-    }
-
-    void maybe_free(KeyState* ks) {
-        if (ks->inflight == 0 && !ks->has_rec && ks->ehead == nullptr) {
-            keys.erase(std::string_view(ks->key));
-            delete ks;
+    // The key's pool index, or NIL with *slot the empty slot that ends
+    // its probe (where insert puts it, while the index is unchanged).
+    uint32_t probe(std::string_view k, uint32_t h, size_t* slot) const {
+        for (size_t b = h & mask;; b = (b + 1) & mask) {
+            const Slot s = index[b];
+            if (s.ks == NIL) {
+                *slot = b;
+                return NIL;
+            }
+            if (s.tag == h && key_is(s.ks, k)) return s.ks;
         }
     }
 
-    static void release_hold(KeyState* ks) {
-        if (ks->inflight > 0) ks->inflight--;
+    uint32_t find(std::string_view k) const {
+        size_t b;
+        return probe(k, static_cast<uint32_t>(key_hash(k)), &b);
+    }
+
+    // A new KeyState for `k` (absent), placed in index slot `slot`.
+    uint32_t insert(std::string_view k, uint32_t h, size_t slot) {
+        uint32_t ks = free_head;
+        if (ks != NIL) {
+            free_head = pool[ks].rnext;
+            pool[ks] = KeyState();
+        } else {
+            ks = static_cast<uint32_t>(pool.size());
+            pool.emplace_back();
+        }
+        KeyState& s = pool[ks];
+        s.live = true;
+        s.hash = h;
+        s.klen = static_cast<uint32_t>(k.size());
+        char* dst = s.kin;
+        if (k.size() > KEY_INLINE) dst = s.kfar = new char[k.size()];
+        memcpy(dst, k.data(), k.size());
+        index[slot] = Slot{ks, h};
+        if (++n_keys * 2 > index.size()) grow();
+        return ks;
+    }
+
+    uint32_t get(std::string_view k) {
+        const uint32_t h = static_cast<uint32_t>(key_hash(k));
+        size_t b;
+        const uint32_t ks = probe(k, h, &b);
+        return ks != NIL ? ks : insert(k, h, b);
+    }
+
+    void grow() {
+        std::vector<Slot> old(index.size() * 2, Slot{NIL, 0});
+        old.swap(index);
+        mask = index.size() - 1;
+        for (const Slot s : old) {
+            if (s.ks == NIL) continue;
+            size_t b = s.tag & mask;
+            while (index[b].ks != NIL) b = (b + 1) & mask;
+            index[b] = s;
+        }
+    }
+
+    // Take `ks` out of the index: backward-shift deletion, so a probe
+    // never needs a tombstone to walk past.
+    void unindex(uint32_t ks) {
+        size_t i = pool[ks].hash & mask;
+        while (index[i].ks != ks) i = (i + 1) & mask;
+        for (size_t j = (i + 1) & mask; index[j].ks != NIL;
+             j = (j + 1) & mask) {
+            // The slot at j may fill the hole at i unless its home lies
+            // after i (cyclically, in (i, j]).
+            const size_t home = index[j].tag & mask;
+            if (((j - home) & mask) >= ((j - i) & mask)) {
+                index[i] = index[j];
+                i = j;
+            }
+        }
+        index[i] = Slot{NIL, 0};
+        n_keys--;
+    }
+
+    void maybe_free(uint32_t ks) {
+        KeyState& s = pool[ks];
+        if (s.inflight == 0 && !s.has_rec && s.ehead == nullptr) {
+            unindex(ks);
+            if (s.klen > KEY_INLINE) delete[] s.kfar;
+            s.live = false;
+            s.rnext = free_head;
+            free_head = ks;
+        }
+    }
+
+    void release_hold(uint32_t ks) {
+        if (pool[ks].inflight > 0) pool[ks].inflight--;
+    }
+
+    // A row's KeyState through the handle the frame filter gave it,
+    // when that still names the row's key; else by probing (counted).
+    uint32_t by_handle(int64_t ks, std::string_view k, uint32_t* h,
+                       size_t* slot) {
+        if (0 <= ks && ks < static_cast<int64_t>(pool.size()) &&
+            pool[ks].live && key_is(static_cast<uint32_t>(ks), k))
+            return static_cast<uint32_t>(ks);
+        handle_fallbacks++;
+        *h = static_cast<uint32_t>(key_hash(k));
+        return probe(k, *h, slot);
+    }
+
+    // Hash every row's key into row_hash (one pass, so the row loop can
+    // prefetch index slots ahead of itself).
+    const uint32_t* hash_rows(const char* blob, const int64_t* off,
+                              int64_t n) {
+        row_hash.resize(static_cast<size_t>(n));
+        for (int64_t i = 0; i < n; i++)
+            row_hash[i] = static_cast<uint32_t>(key_hash(row_key(blob, off, i)));
+        return row_hash.data();
+    }
+
+    void prefetch_slot(uint32_t h) const {
+        __builtin_prefetch(&index[h & mask]);
     }
 
     // -- write records ------------------------------------------------- //
 
-    void rec_unlink(KeyState* ks) {
-        (ks->rprev ? ks->rprev->rnext : rhead) = ks->rnext;
-        (ks->rnext ? ks->rnext->rprev : rtail) = ks->rprev;
-        ks->rprev = ks->rnext = nullptr;
-        ks->has_rec = false;
+    void rec_unlink(uint32_t ks) {
+        KeyState& s = pool[ks];
+        (s.rprev != NIL ? pool[s.rprev].rnext : rhead) = s.rnext;
+        (s.rnext != NIL ? pool[s.rnext].rprev : rtail) = s.rprev;
+        s.rprev = s.rnext = NIL;
+        s.has_rec = false;
         n_records--;
     }
 
-    void rec_pop(KeyState* ks) {
-        if (ks->has_rec) rec_unlink(ks);
+    void rec_pop(uint32_t ks) {
+        if (pool[ks].has_rec) rec_unlink(ks);
     }
 
     // Pop-then-append: a refreshed key moves to the back, so eviction
     // tracks last-write age and a hot key is never the first evicted.
-    void rec_push(KeyState* ks, int64_t tat, int64_t tol, int64_t s) {
-        if (ks->has_rec) rec_unlink(ks);
-        ks->rec_tat = tat;
-        ks->rec_tol = tol;
-        ks->rec_seq = s;
-        ks->rprev = rtail;
-        ks->rnext = nullptr;
-        (rtail ? rtail->rnext : rhead) = ks;
+    void rec_push(uint32_t ks, int64_t tat, int64_t tol, int64_t s) {
+        if (pool[ks].has_rec) rec_unlink(ks);
+        KeyState& r = pool[ks];
+        r.rec_tat = tat;
+        r.rec_tol = tol;
+        r.rec_seq = s;
+        r.rprev = rtail;
+        r.rnext = NIL;
+        (rtail != NIL ? pool[rtail].rnext : rhead) = ks;
         rtail = ks;
-        ks->has_rec = true;
+        r.has_rec = true;
         n_records++;
     }
 
     // Evict the oldest record; `keep` is the key the caller holds (never
     // the head while capacity >= 1, since it was just appended).
-    void rec_evict_head(KeyState* keep) {
-        KeyState* h = rhead;
+    void rec_evict_head(uint32_t keep) {
+        const uint32_t h = rhead;
         rec_unlink(h);
+        if (rhead != NIL) {
+            // The next eviction's index slot, and the one after's key.
+            const KeyState& nx = pool[rhead];
+            prefetch_slot(nx.hash);
+            if (nx.rnext != NIL) __builtin_prefetch(&pool[nx.rnext]);
+        }
         if (h != keep) maybe_free(h);
     }
 
     // -- cached denials ------------------------------------------------ //
 
-    Entry* find_entry(KeyState* ks, int64_t mb, int64_t cpp, int64_t per,
+    Entry* find_entry(uint32_t ks, int64_t mb, int64_t cpp, int64_t per,
                       int64_t q) {
-        if (ks->ehead == nullptr) return nullptr;
+        if (pool[ks].ehead == nullptr) return nullptr;
         auto it = entries.find(Combo{ks, mb, cpp, per, q});
         return it == entries.end() ? nullptr : it->second;
     }
@@ -246,17 +408,17 @@ struct DenyCache {
 
     // Remove one entry (its key state stays; callers maybe_free it).
     void drop_entry(Entry* e) {
-        KeyState* ks = e->ks;
-        entries.erase(Combo{ks, e->mb, e->cpp, e->per, e->q});
+        KeyState& s = pool[e->ks];
+        entries.erase(Combo{e->ks, e->mb, e->cpp, e->per, e->q});
         fifo_unlink(e);
-        (e->kprev ? e->kprev->knext : ks->ehead) = e->knext;
-        (e->knext ? e->knext->kprev : ks->etail) = e->kprev;
+        (e->kprev ? e->kprev->knext : s.ehead) = e->knext;
+        (e->knext ? e->knext->kprev : s.etail) = e->kprev;
         delete e;
     }
 
     // An allowed decision mutated the bucket: every cached denial dies.
-    void invalidate(KeyState* ks) {
-        while (ks->ehead != nullptr) drop_entry(ks->ehead);
+    void invalidate(uint32_t ks) {
+        while (pool[ks].ehead != nullptr) drop_entry(pool[ks].ehead);
     }
 
     // The exact denial `e` proves at `now`, or false once `now` has left
@@ -286,7 +448,7 @@ struct DenyCache {
     int64_t lookup_rows(const char* blob, const int64_t* off, int64_t n,
                         const int64_t* params, int64_t now, bool mark,
                         uint8_t* hit_out, int64_t* vals_out,
-                        KeyState** row_ks_out, int64_t* stale_out) {
+                        uint32_t* row_ks_out, int64_t* stale_out) {
         int64_t n_hits = 0;
         int64_t n_stale = 0;
         if (now < 0) {
@@ -294,10 +456,10 @@ struct DenyCache {
             // not reproducible here, so every row misses.
             for (int64_t i = 0; i < n; i++) {
                 hit_out[i] = 0;
-                KeyState* ks = nullptr;
+                uint32_t ks = NIL;
                 if (mark) {
                     ks = get(row_key(blob, off, i));
-                    ks->inflight++;
+                    pool[ks].inflight++;
                 }
                 if (row_ks_out) row_ks_out[i] = ks;
             }
@@ -305,17 +467,20 @@ struct DenyCache {
             return 0;
         }
         const uint64_t st = ++stamp;
+        const uint32_t* hs = hash_rows(blob, off, n);
         for (int64_t i = 0; i < n; i++) {
+            if (i + AHEAD < n) prefetch_slot(hs[i + AHEAD]);
             const std::string_view k = row_key(blob, off, i);
             const int64_t* p = params + i * 4;
-            KeyState* ks = find(k);
+            size_t slot = 0;
+            uint32_t ks = probe(k, hs[i], &slot);
             Entry* e =
-                ks != nullptr ? find_entry(ks, p[0], p[1], p[2], p[3]) : nullptr;
+                ks != NIL ? find_entry(ks, p[0], p[1], p[2], p[3]) : nullptr;
             Hit h;
             bool served = false;
             if (e != nullptr && e->served == st) {
                 served = serve(e, now, &h);
-            } else if (e != nullptr && ks->inflight == 0) {
+            } else if (e != nullptr && pool[ks].inflight == 0) {
                 if (serve(e, now, &h)) {
                     e->served = st;
                     served = true;
@@ -337,11 +502,11 @@ struct DenyCache {
             }
             hit_out[i] = 0;
             if (mark) {
-                if (ks == nullptr) ks = get(k);
-                ks->inflight++;
-            } else if (ks != nullptr) {
+                if (ks == NIL) ks = insert(k, hs[i], slot);
+                pool[ks].inflight++;
+            } else if (ks != NIL) {
                 maybe_free(ks);
-                ks = nullptr;
+                ks = NIL;
             }
             if (row_ks_out) row_ks_out[i] = ks;
         }
@@ -355,13 +520,13 @@ struct DenyCache {
 
     // A denial certifies an entry only when the key's last allowed write
     // was observed exactly and the denial saw that same TAT.
-    void observe_denied(KeyState* ks, int64_t mb, int64_t cpp, int64_t per,
+    void observe_denied(uint32_t ks, int64_t mb, int64_t cpp, int64_t per,
                         int64_t q, int64_t now, TatFrom from, int64_t cur,
                         int64_t reset, int64_t retry) {
         // q=0 denials are no-ops; q > i32::MAX could push `remaining`
         // past where the wire tiers saturate and the ns planes don't.
         if (q < 1 || q > I32_MAX) return;
-        if (!ks->has_rec) return;
+        if (!pool[ks].has_rec) return;
         if (!gcra_params_valid(mb, cpp, per)) return;
         int64_t em, tol;
         gcra_derive(mb, cpp, per, &em, &tol);
@@ -382,27 +547,28 @@ struct DenyCache {
         } else {
             return;
         }
-        if (tat != ks->rec_tat) return;  // an unobserved write intervened
-        const int64_t rec_tol = ks->rec_tol;
+        if (tat != pool[ks].rec_tat) return;  // an unobserved write intervened
+        const int64_t rec_tol = pool[ks].rec_tol;
         if (!(0 <= tat && tat < BOUND) || rec_tol >= BOUND) return;
         if (now >= tat + inc - tol) return;  // inconsistent with a denial
         Entry* e = find_entry(ks, mb, cpp, per, q);
         if (e == nullptr && static_cast<int64_t>(entries.size()) >= capacity &&
             ehead != nullptr) {
-            KeyState* victim = ehead->ks;
+            const uint32_t victim = ehead->ks;
             drop_entry(ehead);
             if (victim != ks) maybe_free(victim);
         }
         if (e == nullptr) {
+            KeyState& s = pool[ks];
             e = new Entry;
             e->ks = ks;
             e->mb = mb;
             e->cpp = cpp;
             e->per = per;
             e->q = q;
-            e->kprev = ks->etail;
-            (ks->etail ? ks->etail->knext : ks->ehead) = e;
-            ks->etail = e;
+            e->kprev = s.etail;
+            (s.etail ? s.etail->knext : s.ehead) = e;
+            s.etail = e;
             entries.emplace(Combo{ks, mb, cpp, per, q}, e);
         } else {
             fifo_unlink(e);
@@ -418,46 +584,84 @@ struct DenyCache {
 
     // One decided window, in arrival order: the bulk twin of observe +
     // end_inflight.  `cur` may be null (no row carries its exact TAT);
-    // `has_cur` may be null (every row does, when `cur` is given).
+    // `has_cur` may be null (every row does, when `cur` is given);
+    // `handles` may be null (every row's key is probed).
     void observe_rows(const char* blob, const int64_t* off, int64_t n,
                       const int64_t* params, const uint8_t* allowed,
                       const int64_t* cur, const uint8_t* has_cur, int64_t now,
-                      int64_t s) {
+                      int64_t s, const int64_t* handles) {
         const bool now_ok = 0 <= now && now < BOUND;
+        const uint32_t* hs = handles ? nullptr : hash_rows(blob, off, n);
         for (int64_t i = 0; i < n; i++) {
+            if (i + AHEAD < n) {
+                if (hs != nullptr) {
+                    prefetch_slot(hs[i + AHEAD]);
+                } else if (0 <= handles[i + AHEAD] &&
+                           handles[i + AHEAD] <
+                               static_cast<int64_t>(pool.size())) {
+                    __builtin_prefetch(&pool[handles[i + AHEAD]]);
+                }
+            }
             const std::string_view k = row_key(blob, off, i);
             const int64_t* p = params + i * 4;
             const bool hc = cur != nullptr && (has_cur == nullptr || has_cur[i]);
             const int64_t c = hc ? cur[i] : 0;
-            KeyState* ks = find(k);
+            uint32_t h = hs != nullptr ? hs[i] : 0;
+            size_t slot = 0;
+            uint32_t ks = hs != nullptr ? probe(k, h, &slot)
+                                        : by_handle(handles[i], k, &h, &slot);
             if (allowed[i]) {
                 // The one mutating outcome: cached denials die.
-                if (ks != nullptr) invalidate(ks);
-                if (ks != nullptr && ks->has_rec && s < ks->rec_seq) {
+                if (ks != NIL) invalidate(ks);
+                if (ks != NIL && pool[ks].has_rec && s < pool[ks].rec_seq) {
                     // stale cross-transport observation
                 } else if (p[3] < 1 || !hc || !now_ok) {
                     // Unquantified / uncertified write: poison.
-                    if (ks != nullptr) rec_pop(ks);
+                    if (ks != NIL) rec_pop(ks);
                 } else if (gcra_params_valid(p[0], p[1], p[2])) {
                     int64_t em, tol;
                     gcra_derive(p[0], p[1], p[2], &em, &tol);
                     if (0 < em && em < BOUND && 0 <= tol && tol < BOUND &&
                         0 <= c && c < BOUND) {
-                        if (ks == nullptr) ks = get(k);
+                        if (ks == NIL) ks = insert(k, h, slot);
                         rec_push(ks, c, tol, s);
                         if (n_records > capacity) rec_evict_head(ks);
-                    } else if (ks != nullptr) {
+                    } else if (ks != NIL) {
                         rec_pop(ks);
                     }
                 }
-            } else if (hc && ks != nullptr) {
+            } else if (hc && ks != NIL) {
                 observe_denied(ks, p[0], p[1], p[2], p[3], now, TAT_CUR, c, 0,
                                0);
             }
-            if (ks != nullptr) {
+            if (ks != NIL) {
                 release_hold(ks);
                 maybe_free(ks);
             }
+        }
+    }
+
+    // Rows that leave flight without an observation: release each row's
+    // hold and, after a failed launch (`fail`), drop the key's cached
+    // denials and write record.  `handles` as in observe_rows.
+    void release_rows(const char* blob, const int64_t* off, int64_t n,
+                      bool fail, const int64_t* handles) {
+        const uint32_t* hs = handles ? nullptr : hash_rows(blob, off, n);
+        for (int64_t i = 0; i < n; i++) {
+            if (hs != nullptr && i + AHEAD < n) prefetch_slot(hs[i + AHEAD]);
+            const std::string_view k = row_key(blob, off, i);
+            uint32_t h = hs != nullptr ? hs[i] : 0;
+            size_t slot = 0;
+            const uint32_t ks = hs != nullptr
+                                    ? probe(k, h, &slot)
+                                    : by_handle(handles[i], k, &h, &slot);
+            if (ks == NIL) continue;
+            release_hold(ks);
+            if (fail) {
+                invalidate(ks);
+                rec_pop(ks);
+            }
+            maybe_free(ks);
         }
     }
 
@@ -466,17 +670,17 @@ struct DenyCache {
                      int64_t q, int64_t now, bool allowed, int64_t s,
                      bool has_cur, int64_t cur, bool has_reset, int64_t reset,
                      bool has_retry, int64_t retry) {
-        KeyState* ks = find(k);
+        uint32_t ks = find(k);
         if (!allowed) {
-            if (ks == nullptr) return;  // no write record: can't certify
+            if (ks == NIL) return;  // no write record: can't certify
             TatFrom from = has_cur ? TAT_CUR
                            : (has_reset && has_retry) ? TAT_PLANES
                                                       : TAT_NONE;
             observe_denied(ks, mb, cpp, per, q, now, from, cur, reset, retry);
             return;
         }
-        if (ks != nullptr) invalidate(ks);
-        if (ks != nullptr && ks->has_rec && s < ks->rec_seq) {
+        if (ks != NIL) invalidate(ks);
+        if (ks != NIL && pool[ks].has_rec && s < pool[ks].rec_seq) {
             maybe_free(ks);
             return;  // stale cross-transport observation; record is newer
         }
@@ -487,7 +691,7 @@ struct DenyCache {
             // given backend; an unquantified write poisons the record.
             poison = true;
         } else if (!gcra_params_valid(mb, cpp, per)) {
-            if (ks != nullptr) maybe_free(ks);
+            if (ks != NIL) maybe_free(ks);
             return;
         } else {
             int64_t em;
@@ -506,15 +710,15 @@ struct DenyCache {
             if (!poison && !(0 <= tat && tat < BOUND)) poison = true;
         }
         if (poison) {
-            if (ks != nullptr) {
+            if (ks != NIL) {
                 rec_pop(ks);
                 maybe_free(ks);
             }
             return;
         }
-        if (ks == nullptr) ks = get(k);
+        if (ks == NIL) ks = get(k);
         rec_push(ks, tat, tol, s);
-        while (n_records > capacity && rhead != nullptr && rhead != ks)
+        while (n_records > capacity && rhead != NIL && rhead != ks)
             rec_evict_head(ks);
     }
 
@@ -525,18 +729,18 @@ struct DenyCache {
         for (Entry* e = ehead; e != nullptr;) {
             Entry* nx = e->next;
             if (e->expiry <= now) {
-                KeyState* ks = e->ks;
+                const uint32_t ks = e->ks;
                 // The next entry may belong to the same key: only free
                 // the key once it holds no entry the walk can still see.
                 drop_entry(e);
                 dead++;
-                if (ks->ehead == nullptr) maybe_free(ks);
+                if (pool[ks].ehead == nullptr) maybe_free(ks);
             }
             e = nx;
         }
-        for (KeyState* ks = rhead; ks != nullptr;) {
-            KeyState* nx = ks->rnext;
-            if (ks->rec_tat + ks->rec_tol <= now) {
+        for (uint32_t ks = rhead; ks != NIL;) {
+            const uint32_t nx = pool[ks].rnext;
+            if (pool[ks].rec_tat + pool[ks].rec_tol <= now) {
                 rec_unlink(ks);
                 maybe_free(ks);
             }
@@ -547,16 +751,12 @@ struct DenyCache {
     }
 
     void clear() {
-        for (auto it = keys.begin(); it != keys.end();) {
-            KeyState* ks = it->second;
+        for (size_t i = 0; i < pool.size(); i++) {
+            const uint32_t ks = static_cast<uint32_t>(i);
+            if (!pool[ks].live) continue;
             invalidate(ks);
-            if (ks->has_rec) rec_unlink(ks);
-            if (ks->inflight == 0) {
-                it = keys.erase(it);
-                delete ks;
-            } else {
-                ++it;
-            }
+            rec_pop(ks);
+            maybe_free(ks);
         }
     }
 };
@@ -581,7 +781,8 @@ int64_t dc_capacity(void* h) {
     return C(h)->capacity;
 }
 
-// out[4]: entries, records, hits, stale evictions.
+// out[5]: entries, records, hits, stale evictions, handle fallbacks
+// (frame rows whose handle no longer named their key, so were probed).
 void dc_counters(void* h, int64_t* out) {
     DenyCache* c = C(h);
     std::lock_guard<std::mutex> g(c->mu);
@@ -589,6 +790,7 @@ void dc_counters(void* h, int64_t* out) {
     out[1] = c->n_records;
     out[2] = c->hits;
     out[3] = c->stale;
+    out[4] = c->handle_fallbacks;
 }
 
 // Dispatch-order stamp: one per launch window, taken before dispatch.
@@ -603,10 +805,13 @@ void dc_inflight(void* h, const char* key, int64_t len, int64_t delta) {
     std::lock_guard<std::mutex> g(c->mu);
     const std::string_view k(key, static_cast<size_t>(len));
     if (delta > 0) {
-        c->get(k)->inflight++;
-    } else if (KeyState* ks = c->find(k)) {
-        DenyCache::release_hold(ks);
-        c->maybe_free(ks);
+        c->pool[c->get(k)].inflight++;
+    } else {
+        const uint32_t ks = c->find(k);
+        if (ks != NIL) {
+            c->release_hold(ks);
+            c->maybe_free(ks);
+        }
     }
 }
 
@@ -618,10 +823,10 @@ int64_t dc_lookup(void* h, const char* key, int64_t len, int64_t mb,
     if (now < 0) return 0;
     DenyCache* c = C(h);
     std::lock_guard<std::mutex> g(c->mu);
-    KeyState* ks = c->find(std::string_view(key, static_cast<size_t>(len)));
-    if (ks == nullptr) return 0;
+    const uint32_t ks = c->find(std::string_view(key, static_cast<size_t>(len)));
+    if (ks == NIL) return 0;
     Entry* e = c->find_entry(ks, mb, cpp, per, q);
-    if (e == nullptr || ks->inflight > 0) return 0;
+    if (e == nullptr || c->pool[ks].inflight > 0) return 0;
     Hit hit;
     if (!DenyCache::serve(e, now, &hit)) {
         c->drop_entry(e);
@@ -650,14 +855,17 @@ int64_t dc_lookup_window(void* h, const char* blob, const int64_t* off,
                           nullptr, stale_out);
 }
 
-// A decided window, in arrival order (see DenyCache::observe_rows).
+// A decided window, in arrival order (see DenyCache::observe_rows);
+// `handles` (nullable) are dc_filter_frame's miss_ks for these rows.
 void dc_observe_window(void* h, const char* blob, const int64_t* off,
                        int64_t n, const int64_t* params,
                        const uint8_t* allowed, const int64_t* cur,
-                       const uint8_t* has_cur, int64_t now, int64_t seq) {
+                       const uint8_t* has_cur, int64_t now, int64_t seq,
+                       const int64_t* handles) {
     DenyCache* c = C(h);
     std::lock_guard<std::mutex> g(c->mu);
-    c->observe_rows(blob, off, n, params, allowed, cur, has_cur, now, seq);
+    c->observe_rows(blob, off, n, params, allowed, cur, has_cur, now, seq,
+                    handles);
 }
 
 // flags: bit0 has_cur, bit1 has_reset, bit2 has_retry.
@@ -677,28 +885,17 @@ void dc_release_window(void* h, const char* blob, const int64_t* off,
                        int64_t n) {
     DenyCache* c = C(h);
     std::lock_guard<std::mutex> g(c->mu);
-    for (int64_t i = 0; i < n; i++) {
-        if (KeyState* ks = c->find(row_key(blob, off, i))) {
-            DenyCache::release_hold(ks);
-            c->maybe_free(ks);
-        }
-    }
+    c->release_rows(blob, off, n, false, nullptr);
 }
 
 // A launch failed after its writes may have committed: release each
 // row's hold and drop the key's cached denials and write record.
+// `handles` as in dc_observe_window.
 void dc_fail_window(void* h, const char* blob, const int64_t* off,
-                    int64_t n) {
+                    int64_t n, const int64_t* handles) {
     DenyCache* c = C(h);
     std::lock_guard<std::mutex> g(c->mu);
-    for (int64_t i = 0; i < n; i++) {
-        if (KeyState* ks = c->find(row_key(blob, off, i))) {
-            DenyCache::release_hold(ks);
-            c->invalidate(ks);
-            c->rec_pop(ks);
-            c->maybe_free(ks);
-        }
-    }
+    c->release_rows(blob, off, n, true, handles);
 }
 
 // Move each key's write record and cached denials (in the key's own
@@ -709,14 +906,15 @@ int64_t dc_prewarm(void* h, const char* blob, const int64_t* off,
     std::lock_guard<std::mutex> g(c->mu);
     int64_t moved = 0;
     for (int64_t i = 0; i < n; i++) {
-        KeyState* ks = c->find(row_key(blob, off, i));
-        if (ks == nullptr) continue;
+        const uint32_t ks = c->find(row_key(blob, off, i));
+        if (ks == NIL) continue;
         bool touched = false;
-        if (ks->has_rec) {
-            c->rec_push(ks, ks->rec_tat, ks->rec_tol, ks->rec_seq);
+        const KeyState& s = c->pool[ks];
+        if (s.has_rec) {
+            c->rec_push(ks, s.rec_tat, s.rec_tol, s.rec_seq);
             touched = true;
         }
-        for (Entry* e = ks->ehead; e != nullptr; e = e->knext) {
+        for (Entry* e = s.ehead; e != nullptr; e = e->knext) {
             c->fifo_unlink(e);
             c->fifo_append(e);
             touched = true;
@@ -742,11 +940,11 @@ void dc_clear(void* h) {
 int64_t dc_record(void* h, const char* key, int64_t len, int64_t* out) {
     DenyCache* c = C(h);
     std::lock_guard<std::mutex> g(c->mu);
-    KeyState* ks = c->find(std::string_view(key, static_cast<size_t>(len)));
-    if (ks == nullptr || !ks->has_rec) return 0;
-    out[0] = ks->rec_tat;
-    out[1] = ks->rec_tol;
-    out[2] = ks->rec_seq;
+    const uint32_t ks = c->find(std::string_view(key, static_cast<size_t>(len)));
+    if (ks == NIL || !c->pool[ks].has_rec) return 0;
+    out[0] = c->pool[ks].rec_tat;
+    out[1] = c->pool[ks].rec_tol;
+    out[2] = c->pool[ks].rec_seq;
     return 1;
 }
 
@@ -761,19 +959,21 @@ int64_t dc_export(void* h, int64_t what, char* blob, int64_t bytes_cap,
     DenyCache* c = C(h);
     std::lock_guard<std::mutex> g(c->mu);
     int64_t rows = 0, bytes = 0;
-    auto emit = [&](const KeyState* ks, const int64_t* v, int width) {
+    auto emit = [&](uint32_t ks, const int64_t* v, int width) {
+        const std::string_view k = c->pool[ks].key_view();
         if (blob != nullptr && rows < rows_cap &&
-            bytes + static_cast<int64_t>(ks->key.size()) <= bytes_cap) {
+            bytes + static_cast<int64_t>(k.size()) <= bytes_cap) {
             off[rows] = bytes;
-            memcpy(blob + bytes, ks->key.data(), ks->key.size());
+            memcpy(blob + bytes, k.data(), k.size());
             for (int j = 0; j < width; j++) vals[rows * width + j] = v[j];
         }
         rows++;
-        bytes += static_cast<int64_t>(ks->key.size());
+        bytes += static_cast<int64_t>(k.size());
     };
     if (what == 0) {
-        for (const KeyState* ks = c->rhead; ks != nullptr; ks = ks->rnext) {
-            const int64_t v[3] = {ks->rec_tat, ks->rec_tol, ks->rec_seq};
+        for (uint32_t ks = c->rhead; ks != NIL; ks = c->pool[ks].rnext) {
+            const KeyState& s = c->pool[ks];
+            const int64_t v[3] = {s.rec_tat, s.rec_tol, s.rec_seq};
             emit(ks, v, 3);
         }
     } else if (what == 1) {
@@ -783,10 +983,11 @@ int64_t dc_export(void* h, int64_t what, char* blob, int64_t bytes_cap,
             emit(e->ks, v, 10);
         }
     } else {
-        for (const auto& kv : c->keys) {
-            if (kv.second->inflight == 0) continue;
-            const int64_t v[1] = {kv.second->inflight};
-            emit(kv.second, v, 1);
+        for (size_t i = 0; i < c->pool.size(); i++) {
+            const KeyState& s = c->pool[i];
+            if (!s.live || s.inflight == 0) continue;
+            const int64_t v[1] = {s.inflight};
+            emit(static_cast<uint32_t>(i), v, 1);
         }
     }
     *rows_out = rows;
@@ -808,11 +1009,13 @@ int64_t dc_export(void* h, int64_t what, char* blob, int64_t bytes_cap,
 // row whose class admission refuses (admit bit0: consuming rows, bit1:
 // quantity-0 peeks) answers `st_overloaded`; the rest compact into the
 // miss frame (miss_blob, miss_off[m+1], miss_params[m, 4], miss_idx[m])
-// the device decides.  Rows that answer without the device release the
-// hold the lookup took.  Served keys are listed once each: hit_first[j]
-// is the first row of the j-th distinct served key, hit_count[j] its
-// rows.  counts[6]: served rows, stale evictions, shed peeks, shed
-// consuming rows, expired rows, distinct served keys.  Returns m.
+// the device decides, and miss_ks[m] names the key each holds (the
+// handles dc_observe_window and dc_fail_window take back).  Rows that
+// answer without the device release the hold the lookup took.  Served
+// keys are listed once each: hit_first[j] is the first row of the j-th
+// distinct served key, hit_count[j] its rows.  counts[6]: served rows,
+// stale evictions, shed peeks, shed consuming rows, expired rows,
+// distinct served keys.  Returns m.
 int64_t dc_filter_frame(void* h, const char* blob, const int64_t* off,
                         int64_t n, const int64_t* params,
                         const int64_t* budgets, int64_t now, int64_t admit,
@@ -820,13 +1023,14 @@ int64_t dc_filter_frame(void* h, const char* blob, const int64_t* off,
                         uint8_t* status, int64_t* vals, int64_t* miss_idx,
                         char* miss_blob, int64_t* miss_off,
                         int64_t* miss_params, int64_t* hit_first,
-                        int64_t* hit_count, int64_t* counts) {
+                        int64_t* hit_count, int64_t* counts,
+                        int64_t* miss_ks) {
     DenyCache* c = h != nullptr ? C(h) : nullptr;
     std::unique_lock<std::mutex> g;
     int64_t n_hits = 0, n_stale = 0, n_hit_keys = 0;
     uint8_t* hit = nullptr;
     int64_t* hv = nullptr;
-    KeyState** rk = nullptr;
+    uint32_t* rk = nullptr;
     uint64_t st = 0;
     if (c != nullptr) {
         g = std::unique_lock<std::mutex>(c->mu);
@@ -849,15 +1053,15 @@ int64_t dc_filter_frame(void* h, const char* blob, const int64_t* off,
         v[0] = v[1] = v[2] = v[3] = v[4] = 0;
         const bool served = hit != nullptr && hit[i];
         if (served) {
-            KeyState* ks = rk[i];
-            if (ks->hit_stamp != st) {
-                ks->hit_stamp = st;
-                ks->hit_slot = n_hit_keys;
+            KeyState& ks = c->pool[rk[i]];
+            if (ks.hit_stamp != st) {
+                ks.hit_stamp = st;
+                ks.hit_slot = n_hit_keys;
                 hit_first[n_hit_keys] = i;
                 hit_count[n_hit_keys] = 0;
                 n_hit_keys++;
             }
-            hit_count[ks->hit_slot]++;
+            hit_count[ks.hit_slot]++;
         }
         bool answered = true;
         if (budgets[i] < 0) {
@@ -881,7 +1085,7 @@ int64_t dc_filter_frame(void* h, const char* blob, const int64_t* off,
             if (!served && c != nullptr) {
                 // The lookup marked this miss in flight; it will never
                 // be observed, so free the hold.
-                DenyCache::release_hold(rk[i]);
+                c->release_hold(rk[i]);
                 c->maybe_free(rk[i]);
             }
             continue;
@@ -892,6 +1096,7 @@ int64_t dc_filter_frame(void* h, const char* blob, const int64_t* off,
         miss_off[m + 1] = miss_off[m] + len;
         memcpy(miss_params + m * 4, params + i * 4, 4 * sizeof(int64_t));
         miss_idx[m] = i;
+        if (c != nullptr) miss_ks[m] = rk[i];
         m++;
     }
     counts[0] = n_hits;

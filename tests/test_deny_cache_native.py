@@ -24,6 +24,7 @@ from throttlecrab_tpu.front import (
     AdmissionController,
     DenyCache,
     FrontTier,
+    filter_frame,
 )
 from throttlecrab_tpu.front.deny_cache import ROW_SERVED
 from throttlecrab_tpu.native import deny_available, toolchain_available
@@ -439,3 +440,223 @@ def test_deadline_plan_without_front_tier():
     assert (blob, offsets.tolist()) == (b"accc", [0, 1, 4])
     assert params.tolist() == [[3, 60, 60, 1], [3, 60, 60, 0]]
     assert plan["miss_idx"].tolist() == [0, 2]
+
+
+# --------------------------------------------------------------------- #
+# The frame path at scale, and the handles it carries.
+# --------------------------------------------------------------------- #
+
+HOT = [b"hot%03d" % j for j in range(40)]
+
+
+def _scale_key(i):
+    """Distinct key i: mostly 10 bytes, and now and then the empty key,
+    a non-UTF-8 one, one of 23-25 bytes (either side of the length kept
+    inside a key's state) or a long one of up to 1,000 bytes."""
+    if i == 0:
+        return b""
+    r = i % 53
+    if r == 1:
+        return b"\xff\xfe%d" % i
+    if r == 2:
+        return (b"%d:" % i).ljust(23 + i % 3, b"x")
+    if r == 3:
+        return (b"L%d:" % i).ljust(26 + (i * 7919) % 975, b"z")
+    return b"k%09d" % i
+
+
+def _frame_of(keys, params):
+    offsets = np.zeros(len(keys) + 1, np.int64)
+    np.cumsum([len(k) for k in keys], out=offsets[1:])
+    return b"".join(keys), offsets, np.asarray(params, np.int64)
+
+
+def _filter_both(front, o, keys, params, budgets, now):
+    """One frame through FrontTier.filter_frame and, row for row, the
+    oracle's window lookup plus the release of rows that answer without
+    the device; the served rows must agree."""
+    blob, offsets, p = _frame_of(keys, params)
+    f = front.filter_frame(blob, offsets, p, np.asarray(budgets, np.int64),
+                           now, 0, STATUS_DEADLINE, STATUS_OVERLOADED)
+    rows, n_hits = o.lookup_window(keys, *p.T.tolist(), now, True)
+    o.release_window([
+        k for k, r, b in zip(keys, rows, budgets) if r is None and b < 0
+    ])
+    assert f.n_hits == n_hits
+    for i, r in enumerate(rows):
+        if budgets[i] < 0:
+            assert f.status[i] == STATUS_DEADLINE
+        elif r is None:
+            assert f.status[i] == 0
+        else:
+            assert f.status[i] == ROW_SERVED
+            assert f.vals[i].tolist() == [0, r[0], r[1], r[2] // NS,
+                                          r[3] // NS]
+    assert f.miss_idx.tolist() == [
+        i for i, (r, b) in enumerate(zip(rows, budgets))
+        if r is None and b >= 0
+    ]
+    return f
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_frame_path_matches_oracle_at_scale(seed):
+    """Frames of 3,000 rows over more than 50,000 distinct keys at
+    capacity 1,024, through filter_frame and observe_frame / fail_frame
+    with the filter's handles: the key index grows, deletions shift long
+    probe chains and pooled states recycle, and after every frame the
+    records, entries, holds and counters equal the oracle's."""
+    rng = random.Random(seed)
+    o = OracleCache(1024)
+    front = FrontTier(DenyCache(1024), None, bytes_keys=True)
+    c = front.deny_cache
+    now = T0
+    next_id = 0
+    recent = []
+    seen = set()
+    for frame in range(30):
+        now += rng.randrange(NS // 8, NS // 2)
+        keys, params, budgets = [], [], []
+        for _ in range(3000):
+            u = rng.random()
+            if u < 0.25:
+                key = rng.choice(HOT)
+                p = (3, 60, 60, rng.choice([1, 1, 1, 2]))
+            else:
+                if u < 0.35 and recent:
+                    key = rng.choice(recent)
+                else:
+                    key = _scale_key(next_id)
+                    next_id += 1
+                    recent.append(key)
+                p = rng.choice([(3, 60, 60, 1), (3, 60, 60, 1),
+                                (5, 10, 1, 1), (5, 10, 1, 0),
+                                (0, 10, 60, 1)])
+            keys.append(key)
+            params.append(p)
+            budgets.append(-1 if rng.random() < 0.03 else 0)
+        recent = recent[-5000:]
+        seen.update(keys)
+        f = _filter_both(front, o, keys, params, budgets, now)
+        miss = f.miss_idx.tolist()
+        seq = front.next_seq()
+        assert o.next_seq() == seq
+        if frame % 9 == 4:
+            # The launch failed after its writes may have committed.
+            front.observe_frame(f.miss_frame, None, now, seq, f.miss_ks)
+            o.fail_window([keys[i] for i in miss])
+        elif miss:
+            with_cur = rng.random() < 0.8
+            status, allowed, cur, rows = [], [], [], []
+            for i in miss:
+                key, (mb, cpp, per, q) = keys[i], params[i]
+                ok = rng.random() >= 0.02
+                a = rng.random() < (0.15 if key in HOT else 0.8)
+                rec = o._records.get(key)
+                t = (rec[0] if rec is not None and not a
+                     else now + rng.randrange(0, 3 * NS))
+                status.append(0 if ok else 3)
+                allowed.append(int(a))
+                cur.append(t)
+                rows.append((key, mb, cpp, per, q, ok and a,
+                             t if ok and with_cur else None))
+            res = SimpleNamespace(
+                status=np.array(status, np.uint8),
+                allowed=np.array(allowed, np.uint8),
+                cur_ns=np.array(cur, np.int64) if with_cur else None,
+            )
+            front.observe_frame(f.miss_frame, res, now, seq, f.miss_ks)
+            o.observe_window(rows, now, seq)
+        if frame % 8 == 7:
+            t = now - rng.randrange(0, 2 * NS)
+            assert o.on_sweep(t) == c.on_sweep(t)
+        assert oracle_state(o) == native_state(c), f"frame {frame}"
+    assert len(seen) >= 50_000
+    assert c.hits > 0 and 0 < len(c.records()) <= 1024
+    assert c.handle_fallbacks == 0
+
+
+@pytest.mark.parametrize("how", ["end_inflight", "release_window",
+                                 "clear", "clear_release"])
+@pytest.mark.parametrize("fail", [False, True])
+def test_stale_handles_fall_back_to_a_lookup(how, fail):
+    """A hold released out of band between filter_frame and
+    observe_frame / fail_frame frees its key, whose pooled state may
+    then hold another key: observing with the stale handles must leave
+    the oracle's state, and the fallback counter counts those rows.
+    clear() alone keeps held keys, so their handles stay good."""
+    o = OracleCache(64)
+    front = FrontTier(DenyCache(64), None, bytes_keys=True)
+    c = front.deny_cache
+    long_key = b"L" * 300
+    for cache in (o, c):
+        # A recorded key and a cached denial: both outlive a release.
+        cache.observe(b"rec", 3, 60, 60, 1, T0, True, seq=1,
+                      cur_ns=T0 + 2 * NS)
+        _prime(cache, b"den")
+    keys = [b"rec", b"free1", long_key, b"stay", b"den", b"free1"]
+    params = [(3, 60, 60, 1)] * 3 + [(3, 60, 60, 1), (3, 60, 60, 2),
+                                     (5, 10, 1, 1)]
+    now = T0 + 2
+    f = _filter_both(front, o, keys, params, [0] * 6, now)
+    assert f.miss_idx.tolist() == [0, 1, 2, 3, 4, 5]
+    released = [b"free1", long_key, b"free1"]
+    for cache in (o, c):
+        if how == "end_inflight":
+            for k in released:
+                cache.end_inflight(k)
+        elif how == "release_window":
+            cache.release_window(released)
+        else:
+            cache.clear()
+            if how == "clear_release":
+                cache.release_window(released)
+        # A new key may take a freed state's place.
+        cache.begin_inflight(b"newcomer")
+    seq = front.next_seq()
+    o.next_seq()
+    if fail:
+        front.observe_frame(f.miss_frame, None, now, seq, f.miss_ks)
+        o.fail_window(keys)
+    else:
+        cur = [T0 + 3 * NS] * 4 + [T0 + 2 * NS, T0 + 4 * NS]
+        allowed = [1, 1, 0, 1, 0, 1]
+        res = SimpleNamespace(status=np.zeros(6, np.uint8),
+                              allowed=np.array(allowed, np.uint8),
+                              cur_ns=np.array(cur, np.int64))
+        front.observe_frame(f.miss_frame, res, now, seq, f.miss_ks)
+        o.observe_window([
+            (k, *p, bool(a), t)
+            for k, p, a, t in zip(keys, params, allowed, cur)
+        ], now, seq)
+    assert oracle_state(o) == native_state(c)
+    assert c.inflight() == {b"newcomer": 1}
+    # Both free1 rows and the long key's row lost their key's state;
+    # clear() alone released nothing.
+    assert c.handle_fallbacks == (0 if how == "clear" else 3)
+
+
+def test_frame_handles_skip_a_second_lookup():
+    """The ordinary frame path: handles from filter_frame name every
+    miss row's key, so observe_frame and fail_frame never fall back, and
+    a frame filtered without a cache carries none."""
+    front = FrontTier(DenyCache(8), None, bytes_keys=True)
+    keys = [b"a", b"", b"\xff" * 40, b"a"]
+    blob, offsets, p = _frame_of(keys, [(3, 60, 60, 1)] * 4)
+    budgets = np.zeros(4, np.int64)
+    f = front.filter_frame(blob, offsets, p, budgets, T0, 0,
+                           STATUS_DEADLINE, STATUS_OVERLOADED)
+    assert f.miss_ks.tolist()[0] == f.miss_ks.tolist()[3]
+    assert len(set(f.miss_ks.tolist())) == 3
+    with pytest.raises(ValueError):
+        front.observe_frame(f.miss_frame, None, T0, 1, f.miss_ks[:3])
+    assert front.deny_cache.inflight() == {b"a": 2, b"": 1,
+                                           b"\xff" * 40: 1}
+    front.observe_frame(f.miss_frame, None, T0, front.next_seq(),
+                        f.miss_ks)
+    assert front.deny_cache.inflight() == {}
+    assert front.deny_cache.handle_fallbacks == 0
+    assert front.stats()["handle_fallbacks"] == 0
+    g = filter_frame(None, blob, offsets, p, budgets, T0,
+                     STATUS_DEADLINE, STATUS_OVERLOADED)
+    assert g.miss_ks is None and g.miss_idx.tolist() == [0, 1, 2, 3]

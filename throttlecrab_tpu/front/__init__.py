@@ -199,19 +199,20 @@ class FrontTier:
             self.insight.record_front_denied(keys, f.hit_count.tolist())
         return f
 
-    def observe_frame(self, frame, res, now_ns, seq) -> None:
+    def observe_frame(self, frame, res, now_ns, seq, ks=None) -> None:
         """Feed a device-decided miss frame's results (res None: the
         launch failed after its writes may have committed) to the cache
         and release its keys' holds: the frame twin of observe_window /
-        fail_window."""
+        fail_window.  `ks` is filter_frame's `miss_ks` for the frame
+        (None: every key is looked up)."""
         if self.deny_cache is None or frame is None:
             return
         if res is None:
-            self.deny_cache.fail_frame(frame)
+            self.deny_cache.fail_frame(frame, ks)
             return
         self.deny_cache.observe_frame(
             frame, res.status, res.allowed, getattr(res, "cur_ns", None),
-            now_ns, seq,
+            now_ns, seq, ks,
         )
 
     def fail_window(self, keys) -> None:
@@ -281,7 +282,8 @@ class FrontTier:
 
     def stats(self) -> dict:
         """Gauge snapshot for the metrics exporter."""
-        out = {"deny_cache_size": 0}
+        out = {"deny_cache_size": 0, "handle_fallbacks": 0}
         if self.deny_cache is not None:
             out["deny_cache_size"] = len(self.deny_cache)
+            out["handle_fallbacks"] = self.deny_cache.handle_fallbacks
         return out

@@ -46,7 +46,10 @@ function the keymap's batch prep uses (native/gcra_params.h), and the
 arithmetic is held to the plain Python implementation
 (tests/deny_cache_oracle.py) by a differential test, not by the i64
 lint.  Eviction is FIFO in O(1) on intrusive lists: write records by
-last write, cached denials by insertion.
+last write, cached denials by insertion.  Per-key state sits in a pool
+behind an open-addressing index, and the frame path carries each miss
+row's pool index (its handle) from filter_frame to observe_frame, which
+checks it against the row's key before it trusts it.
 
 Keys: bytes are stored as they are; str keys (a python keymap's
 identity) as their UTF-8 `surrogateescape` bytes, the exact inverse of
@@ -115,6 +118,16 @@ def _lib():
 
 def _ptr(a: Optional[np.ndarray]):
     return None if a is None else a.ctypes.data
+
+
+def _handles(ks, n: int) -> Optional[np.ndarray]:
+    """A miss frame's handles as i64[n] (None stays None)."""
+    if ks is None:
+        return None
+    ks = np.ascontiguousarray(ks, np.int64)
+    if ks.shape != (n,):
+        raise ValueError(f"{ks.shape} handles for a frame of {n} rows")
+    return ks
 
 
 def _key_bytes(key) -> Optional[bytes]:
@@ -186,6 +199,7 @@ class FrameFilter(NamedTuple):
     shed_consume: int
     hit_first: np.ndarray   # first row of each distinct served key
     hit_count: np.ndarray   # its served rows
+    miss_ks: Optional[np.ndarray]  # i64[m] handles (None without a cache)
 
 
 def filter_frame(cache, blob, offsets, params, budgets, now_ns,
@@ -198,7 +212,8 @@ def filter_frame(cache, blob, offsets, params, budgets, now_ns,
     native call: expired rows answer `st_deadline`, cached denials are
     served, rows of a refused class answer `st_overloaded`, and the rest
     compact into the miss frame the device decides.  Every miss row's
-    key holds an in-flight hold until observe_frame/fail_frame."""
+    key holds an in-flight hold until observe_frame/fail_frame, which
+    take `miss_ks` back so they need not look the keys up again."""
     lib = _lib()
     n = len(offsets) - 1
     offsets = np.ascontiguousarray(offsets, np.int64)
@@ -207,12 +222,13 @@ def filter_frame(cache, blob, offsets, params, budgets, now_ns,
     # Two workspaces carved into the outputs: each array's address
     # costs a ctypes round trip (~1 us), which a frame of tens of rows
     # would otherwise pay a dozen times.
-    ws = np.empty(13 * n + 7, np.int64)
+    ws = np.empty(14 * n + 7, np.int64)
     vals = ws[: 5 * n].reshape(n, 5)
     miss_params = ws[5 * n: 9 * n].reshape(n, 4)
     miss_idx, hit_first, hit_count = ws[9 * n: 12 * n].reshape(3, n)
     miss_off = ws[12 * n: 13 * n + 1]
-    counts = ws[13 * n + 1:]
+    counts = ws[13 * n + 1: 13 * n + 7]
+    miss_ks = ws[13 * n + 7:]
     bs = np.empty(n + len(blob) + 1, np.uint8)
     status, miss_blob = bs[:n], bs[n:]
     w, b = ws.ctypes.data, bs.ctypes.data
@@ -222,7 +238,7 @@ def filter_frame(cache, blob, offsets, params, budgets, now_ns,
         int(bool(admit_consume)) | (int(bool(admit_peek)) << 1),
         st_deadline, st_overloaded, b, w, w + 8 * 9 * n, b + n,
         w + 8 * 12 * n, w + 8 * 5 * n, w + 8 * 10 * n, w + 8 * 11 * n,
-        w + 8 * (13 * n + 1),
+        w + 8 * (13 * n + 1), w + 8 * (13 * n + 7),
     )
     if m == n:
         miss_frame = (blob, offsets, params)
@@ -239,6 +255,7 @@ def filter_frame(cache, blob, offsets, params, budgets, now_ns,
         status, vals, miss_idx[:m], miss_frame, int(counts[0]),
         int(counts[1]), int(counts[2]), int(counts[3]),
         hit_first[:n_hit_keys], hit_count[:n_hit_keys],
+        miss_ks[:m] if cache is not None else None,
     )
 
 
@@ -274,7 +291,7 @@ class DenyCache:
         self._lib.dc_set_capacity(self._h, int(value))
 
     def _counters(self) -> np.ndarray:
-        out = np.zeros(4, np.int64)
+        out = np.zeros(5, np.int64)
         self._lib.dc_counters(self._h, _ptr(out))
         return out
 
@@ -291,6 +308,13 @@ class DenyCache:
         """Entries dropped because their proven window or their bucket's
         TTL lapsed."""
         return int(self._counters()[3])
+
+    @property
+    def handle_fallbacks(self) -> int:
+        """Frame rows whose filter_frame handle no longer named their
+        key when observed or failed (its hold was released out of band),
+        so the key was looked up instead; 0 on the serving path."""
+        return int(self._counters()[4])
 
     def next_seq(self) -> int:
         """Dispatch-order stamp: call once per launch window, *before*
@@ -412,21 +436,23 @@ class DenyCache:
             # still invalidates and poisons, denied is a no-op.
             params[bad] = 0
         self._observe(blob, offsets, n, params, allowed_u8, cur_i64,
-                      has_cur, now_ns, seq)
+                      has_cur, now_ns, seq, None)
 
     def _observe(self, blob, offsets, n, params, allowed, cur, has_cur,
-                 now_ns, seq) -> None:
+                 now_ns, seq, ks) -> None:
         self._lib.dc_observe_window(
             self._h, blob, _ptr(offsets), n, _ptr(params), _ptr(allowed),
-            _ptr(cur), _ptr(has_cur), _clamp(now_ns), _clamp(seq),
+            _ptr(cur), _ptr(has_cur), _clamp(now_ns), _clamp(seq), _ptr(ks),
         )
 
     def observe_frame(self, frame, status, allowed, cur_ns, now_ns,
-                      seq) -> None:
+                      seq, ks=None) -> None:
         """observe_window for a device-decided miss frame (blob,
         offsets, params) and its result planes: a non-OK row never
         reached the table, so it only releases its hold; without a
-        cur plane allowed rows invalidate and denials cannot certify."""
+        cur plane allowed rows invalidate and denials cannot certify.
+        `ks` is the frame's FrameFilter.miss_ks, or None to look every
+        key up."""
         blob, offsets, params = frame
         n = len(offsets) - 1
         ok = np.asarray(status) == 0
@@ -438,17 +464,17 @@ class DenyCache:
         self._observe(
             blob, np.ascontiguousarray(offsets, np.int64), n,
             np.ascontiguousarray(params, np.int64), allowed_u8, cur,
-            has_cur, now_ns, seq,
+            has_cur, now_ns, seq, _handles(ks, n),
         )
 
-    def fail_frame(self, frame) -> None:
+    def fail_frame(self, frame, ks=None) -> None:
         """fail_window for a miss frame whose launch failed after its
-        writes may have committed."""
+        writes may have committed (`ks` as in observe_frame)."""
         blob, offsets, _params = frame
-        self._lib.dc_fail_window(
-            self._h, blob, _ptr(np.ascontiguousarray(offsets, np.int64)),
-            len(offsets) - 1,
-        )
+        offsets = np.ascontiguousarray(offsets, np.int64)
+        n = len(offsets) - 1
+        ks = _handles(ks, n)
+        self._lib.dc_fail_window(self._h, blob, _ptr(offsets), n, _ptr(ks))
 
     def release_window(self, keys) -> None:
         """Release in-flight holds for rows that never reached a launch
@@ -470,7 +496,9 @@ class DenyCache:
         certify exactness any longer."""
         blob, offsets, rows = _frame(keys)
         if rows:
-            self._lib.dc_fail_window(self._h, blob, _ptr(offsets), len(rows))
+            self._lib.dc_fail_window(
+                self._h, blob, _ptr(offsets), len(rows), None
+            )
 
     # ------------------------------------------------------------------ #
 

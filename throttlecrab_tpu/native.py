@@ -302,13 +302,15 @@ def _build_deny() -> Optional[ctypes.CDLL]:
         "dc_inflight": (None, [h, s, i64, i64]),
         "dc_lookup": (i64, [h, s, i64, i64, i64, i64, i64, i64, p]),
         "dc_lookup_window": (i64, [h, s, p, i64, p, i64, i64, p, p, p]),
-        "dc_observe_window": (None, [h, s, p, i64, p, p, p, p, i64, i64]),
+        "dc_observe_window": (
+            None, [h, s, p, i64, p, p, p, p, i64, i64, p],
+        ),
         "dc_observe": (
             None, [h, s, i64, i64, i64, i64, i64, i64, i64, i64, i64,
                    i64, i64, i64],
         ),
         "dc_release_window": (None, [h, s, p, i64]),
-        "dc_fail_window": (None, [h, s, p, i64]),
+        "dc_fail_window": (None, [h, s, p, i64, p]),
         "dc_prewarm": (i64, [h, s, p, i64]),
         "dc_sweep": (i64, [h, i64]),
         "dc_clear": (None, [h]),
@@ -316,7 +318,7 @@ def _build_deny() -> Optional[ctypes.CDLL]:
         "dc_export": (i64, [h, i64, p, i64, p, p, i64, p, p]),
         "dc_filter_frame": (
             i64, [h, s, p, i64, p, p, i64, i64, i64, i64, p, p, p, p, p,
-                  p, p, p, p],
+                  p, p, p, p, p],
         ),
     }
     for name, (res, args) in sigs.items():

@@ -57,6 +57,7 @@ METRIC_NAMES = (
     "throttlecrab_tpu_front_shed",
     "throttlecrab_tpu_front_stale_evictions",
     "throttlecrab_tpu_front_deny_cache_size",
+    "throttlecrab_tpu_front_handle_fallbacks",
     "throttlecrab_tpu_engine_state",
     "throttlecrab_tpu_supervisor_retries",
     "throttlecrab_tpu_supervisor_degrades",
@@ -384,8 +385,8 @@ class Metrics:
         self._engine_state = provider
 
     def set_front_stats_provider(self, provider) -> None:
-        """`provider()` -> {"deny_cache_size": n}; exported as gauges
-        (FrontTier.stats)."""
+        """`provider()` -> {"deny_cache_size": n, "handle_fallbacks":
+        n}; exported as a gauge and a counter (FrontTier.stats)."""
         self._front_stats = provider
 
     def set_insight_stats_provider(self, provider) -> None:
@@ -589,6 +590,13 @@ class Metrics:
             "Live deny-cache entries",
             "gauge",
             front_stats.get("deny_cache_size", 0),
+        )
+        metric(
+            "throttlecrab_tpu_front_handle_fallbacks",
+            "Frame rows whose deny-cache handle no longer named their "
+            "key, so the key was looked up again",
+            "counter",
+            front_stats.get("handle_fallbacks", 0),
         )
         # Failure-domain supervision (server/supervisor.py).
         from .supervisor import STATE_GAUGE

@@ -359,6 +359,7 @@ class NativeRedisTransport:
             "hit_vals": f.vals,
             "miss_idx": f.miss_idx,
             "miss_frame": f.miss_frame,
+            "miss_ks": f.miss_ks,
         }
 
     def _merge_plan(self, plan, res):
@@ -395,7 +396,9 @@ class NativeRedisTransport:
         the native twin of engine._observe_window.  After a post-launch
         failure (res None) the writes may have committed, so the keys'
         cached denials and write records go with their holds."""
-        self.front.observe_frame(plan["miss_frame"], res, now_ns, seq)
+        self.front.observe_frame(
+            plan["miss_frame"], res, now_ns, seq, plan["miss_ks"]
+        )
 
     def _decide_frames(self, frames, now_ns):
         """Decide a window of (blob, offsets, params) frames on the
